@@ -1,8 +1,8 @@
 """FC03 — the byte-identity contract of device/columnar encode routes.
 
 Every accelerated route in this tree is only allowed to exist because a
-scalar oracle produces the *same bytes* at lower throughput (BASELINE.md
-seals the format surface; the breaker and every degradation path rely on
+scalar oracle produces the *same bytes* at lower throughput (BASELINE.json
+names the format surface; the breaker and every degradation path rely on
 the swap being invisible).  That contract has two halves, and both must
 be declared where the kernel lives so the checker — and the next reader
 — can verify them:

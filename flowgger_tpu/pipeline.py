@@ -639,6 +639,19 @@ class Pipeline:
         # Input ended (EOF on stdin, etc.): drain before exiting rather
         # than killing the daemon consumers mid-write.
         self._drain(threads)
+        if self.input_format in _TPU_FORMATS:
+            # a compile the watchdog declined may still be running on
+            # its worker thread: wait for it (bounded) so the process
+            # never exits with a thread inside XLA.  The signal path
+            # needs no such wait — it leaves through os._exit.
+            from .tpu.device_common import join_compile_workers
+
+            alive = join_compile_workers()
+            if alive:
+                import sys
+
+                print(f"drain: {alive} kernel compile(s) still running "
+                      "at exit", file=sys.stderr)
 
 
 def start(config_file: str):

@@ -114,14 +114,12 @@ _ABSENT = object()
 def _snapshot_cache_config() -> Dict:
     """The current values of the persistent-cache knobs
     enable_compile_cache overwrites (``device_common.CACHE_KNOBS`` is
-    the single source; absent knobs skipped — names vary across jax
-    versions)."""
+    the single source)."""
     import jax
 
     from .device_common import CACHE_KNOBS
 
-    return {k: v for k in CACHE_KNOBS
-            if (v := getattr(jax.config, k, _ABSENT)) is not _ABSENT}
+    return {k: getattr(jax.config, k) for k in CACHE_KNOBS}
 
 
 def _restore_cache_config(snapshot: Optional[Dict]) -> None:
@@ -130,19 +128,12 @@ def _restore_cache_config(snapshot: Optional[Dict]) -> None:
     the one restore dance shared by ``_unpoint_auto_cache`` and
     ``warm_artifacts``."""
     import jax
+    from jax._src import compilation_cache as _cc
 
     for k, v in (snapshot
                  or {"jax_compilation_cache_dir": None}).items():
-        try:
-            jax.config.update(k, v)
-        except Exception:  # noqa: BLE001 - knob names vary across jax versions
-            pass
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 - private API; harmless if gone
-        pass
+        jax.config.update(k, v)
+    _cc.reset_cache()
 
 
 def _metrics():
@@ -629,13 +620,15 @@ def setup_aot(config, max_len: Optional[int] = None,
     explicit_cache = config.lookup_str(
         "input.tpu_compile_cache_dir",
         "input.tpu_compile_cache_dir must be a string (directory)", None)
-    if not explicit_cache and store.has_warm_cache():
-        # only a dir the builder actually warmed (kabi subdir present)
-        # is worth pointing the persistent cache at; artifact dirs can
-        # live on read-only mounts, so a failed install (EROFS, perms)
-        # declines to stock cache behavior instead of crashing the boot
-        from .device_common import enable_compile_cache
+    from .device_common import cache_placed_outside, enable_compile_cache
 
+    if (not explicit_cache and not cache_placed_outside()
+            and store.has_warm_cache()):
+        # only a dir the builder actually warmed (kabi subdir present)
+        # is worth pointing the persistent cache at, and never over a
+        # place the environment chose; artifact dirs can live on
+        # read-only mounts, so a failed install (EROFS, perms)
+        # declines to stock cache behavior instead of crashing the boot
         displaced = _snapshot_cache_config()
         try:
             enable_compile_cache(store.xla_cache_dir)
@@ -1366,6 +1359,16 @@ def warm_artifacts(out_dir: str, keys=None, quiet: bool = False,
     marker = _warm_marker_path(out_dir, platform)
     if os.path.exists(marker):
         os.unlink(marker)
+    from .device_common import CACHE_DIR_ENV, cache_placed_outside
+
+    if cache_placed_outside():
+        # the environment placed the cache: the warm pass may not point
+        # it at the artifact dir, and a marker over entries that landed
+        # elsewhere would lie
+        print(f"aot warm: {CACHE_DIR_ENV} is set, so the cache cannot be "
+              f"pointed at {out_dir}; unset it to warm an artifact dir",
+              file=sys.stderr)
+        return 0
     # the warm loop must point the process-global persistent cache at
     # the artifact dir — and must put it back: an in-process caller
     # (library use, build-then-serve) would otherwise keep writing
@@ -1463,90 +1466,6 @@ def validate_artifacts(out_dir: str, quiet: bool = False) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# legacy single-kernel Pallas relay flow (tools/pallas_aot.py now
-# delegates here; the artifact and verbs are unchanged)
-
-_PALLAS_ART = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))),
-    "tools", "pallas_rfc5424_tpu.jaxexport")
-_PALLAS_SHAPE = (4096, 256, 2, 6)  # N, L, MAX_SD, MAX_PAIRS
-
-
-def pallas_export(art: str = _PALLAS_ART) -> str:
-    import functools
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    import jax.numpy as jnp
-    from jax import export as jexport
-
-    from . import rfc5424 as R
-
-    n, length, max_sd, max_pairs = _PALLAS_SHAPE
-    fn = functools.partial(R.decode_rfc5424_pallas, max_sd=max_sd,
-                           max_pairs=max_pairs)
-    b = jnp.zeros((n, length), jnp.uint8)
-    ln = jnp.zeros((n,), jnp.int32)
-    blob = jexport.export(jax.jit(fn), platforms=["tpu"])(b, ln).serialize()
-    with open(art, "wb") as f:
-        f.write(blob)
-    print(f"exported {len(blob)} bytes -> {art}")
-    return art
-
-
-def pallas_run(art: str = _PALLAS_ART) -> int:
-    import numpy as np
-
-    import jax
-
-    cache = os.environ.get("FLOWGGER_JAX_CACHE",
-                           os.path.expanduser("~/.cache/flowgger_jax"))
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    print("devices:", jax.devices())
-    import jax.numpy as jnp
-    from jax import export as jexport
-
-    from . import rfc5424 as R
-
-    n, length, max_sd, max_pairs = _PALLAS_SHAPE
-    with open(art, "rb") as f:
-        exp = jexport.deserialize(f.read())
-    lines = [
-        b'<13>1 2023-09-20T12:35:45.123Z host app 123 MSGID '
-        b'[ex@32473 k="v" a="b"] hello world',
-        b'<34>1 2003-10-11T22:14:15.003Z mymachine.example.com su - '
-        b'ID47 - su root failed',
-    ] * (n // 2)
-    batch = np.zeros((n, length), np.uint8)
-    lens = np.zeros((n,), np.int32)
-    for i, s in enumerate(lines[:n]):
-        batch[i, :len(s)] = np.frombuffer(s, np.uint8)
-        lens[i] = len(s)
-    # the rewritten kernel returns the decode channel dict (the old
-    # _PALLAS_SHAPE-era artifact was a flat tuple); exp.call restores
-    # the output pytree, so compare per key
-    out = exp.call(jnp.asarray(batch), jnp.asarray(lens))
-    ref = R.decode_rfc5424_jit(jnp.asarray(batch), jnp.asarray(lens),
-                               max_sd=max_sd, max_pairs=max_pairs)
-    keys = list(R._KEYS_1D) + list(R._KEYS_SD) + list(R._KEYS_PAIR)
-    bad = 0
-    for k in keys:
-        r = np.asarray(ref[k]).astype(np.int64)
-        o2 = np.asarray(out[k]).astype(np.int64)
-        if o2.ndim == 2 and o2.shape[1] == 1:
-            o2 = o2[:, 0]
-        if not (o2 == r.reshape(o2.shape)).all():
-            bad += 1
-            print(f"MISMATCH {k}")
-    print("PALLAS AOT DIFFERENTIAL:", "FAIL" if bad else "OK",
-          f"({len(keys)} channels)")
-    return 1 if bad else 0
-
-
-# ---------------------------------------------------------------------------
 # CLI
 
 def _csv(s: str) -> Tuple[str, ...]:
@@ -1587,10 +1506,6 @@ def main(argv=None) -> int:
                        help="deserialize + hash-verify every entry")
     v.add_argument("dir")
 
-    p = sub.add_parser("pallas",
-                       help="legacy single-kernel Pallas relay flow")
-    p.add_argument("mode", choices=("export", "run"))
-
     args = ap.parse_args(argv)
     if args.verb == "build":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -1604,13 +1519,8 @@ def main(argv=None) -> int:
                         max_len=args.max_len, warm=args.warm,
                         warm_timeout_s=args.warm_timeout_s)
         return 0
-    if args.verb == "validate":
-        validate_artifacts(args.dir)
-        return 0
-    if args.mode == "export":
-        pallas_export()
-        return 0
-    return pallas_run()
+    validate_artifacts(args.dir)
+    return 0
 
 
 if __name__ == "__main__":

@@ -42,9 +42,6 @@ class BatchHandler(Handler):
     def __init__(self, tx, decoder, encoder, config: Optional[Config] = None,
                  fmt: str = "rfc5424", start_timer: bool = True,
                  merger=_NO_MERGER, supervisor=None):
-        from . import apply_platform_env
-
-        apply_platform_env()
         self.tx = tx
         self.encoder = encoder
         self.fmt = fmt
@@ -226,11 +223,19 @@ class BatchHandler(Handler):
 
         setup_aot(cfg, max_len=self.max_len,
                   grid=_pack_aot.active_bucket_grid())
-        # persistent compile cache (input.tpu_compile_cache_dir): wire
-        # before any kernel dispatch so every compile below lands in it
-        from .device_common import setup_compile_cache
+        # persistent compile cache: wire before any kernel dispatch so
+        # every compile below lands in it
+        from .device_common import (cache_placed_outside,
+                                    enable_compile_cache)
 
-        self._compile_cache_dir = setup_compile_cache(cfg)
+        cache_key = cfg.lookup_str(
+            "input.tpu_compile_cache_dir",
+            "input.tpu_compile_cache_dir must be a string (directory)",
+            None)
+        enable_compile_cache(cache_key)
+        # someone named the cache's place (the key, or the environment):
+        # the production signal the prewarm default keys on
+        self._cache_placed = cache_placed_outside() or bool(cache_key)
         self._prewarm_cfg = cfg.lookup_bool(
             "input.tpu_prewarm", "input.tpu_prewarm must be a boolean",
             None)
@@ -345,15 +350,14 @@ class BatchHandler(Handler):
                 "block route is disabled, auto format, or a sharded "
                 "mesh owns the format); using the host splitters",
                 file=sys.stderr)
-        # Pallas structural kernels (tpu/pallas_kernels.py): single-VMEM
-        # framing→decode passes replacing the jnp scatter ladder and the
-        # repeated [N,L] screen passes.  "auto" engages the compiled
-        # kernels whenever the block route runs on a non-CPU backend;
-        # "on" additionally engages interpret-mode kernels on the CPU
-        # backend (tests/benches — interpret Pallas is *slower* than
-        # jnp, so auto never picks it there); "off" pins the jnp tiers.
-        # Declines ride the framing ladder shape (3 strikes → cooldown)
-        # and fall back to the jnp tier — never dropping data.
+        # Pallas structural kernels (tpu/pallas_kernels.py).  The chip's
+        # compiler (Mosaic) refuses all six of them today
+        # (tests/test_chip_compile.py holds the verdicts), so "auto"
+        # resolves to the jnp tiers on every backend.  "on" is the
+        # explicit opt-in: interpret-mode kernels on the CPU backend
+        # (the differential tests), compiled kernels elsewhere — and
+        # there a kernel the compiler refuses is a start-up error that
+        # names the kernel, never a decline, a cooldown and a retry.
         from . import pallas_kernels as _pallas_mod
 
         pallas_mode = cfg.lookup_str(
@@ -366,7 +370,7 @@ class BatchHandler(Handler):
         pallas_ok = (self._block_mode and self.fmt != "auto"
                      and self._kernel_fn is not None
                      and self._block_route_ok())
-        if pallas_mode == "off" or not pallas_ok:
+        if pallas_mode != "on" or not pallas_ok:
             _pallas_mod.set_mode("off")
             if pallas_mode == "on" and self._block_mode:
                 print(
@@ -379,21 +383,25 @@ class BatchHandler(Handler):
             import jax
 
             if jax.default_backend() == "cpu":
-                _pallas_mod.set_mode(
-                    "interpret" if pallas_mode == "on" else "off")
+                _pallas_mod.set_mode("interpret")
             else:
+                from . import pack as _pack_mod
+
+                _pallas_mod.require_compiles(
+                    fmt, _pack_mod.bucket_rows(self.batch_size),
+                    self.max_len, framing=framing_engaged)
                 _pallas_mod.set_mode("compiled")
         self._pallas_mode = pallas_mode
         # background kernel prewarm: compile the configured format's
         # decode (+ engaged device-encode) kernels for the shape-bucket
         # grid now, so the first real batch of each steady-state shape
         # never eats a cold compile or a watchdog decline.  Default: on
-        # exactly when a persistent compile cache is configured (the
-        # production signal); input.tpu_prewarm forces either way.
+        # exactly when the persistent compile cache was given a place
+        # (the production signal); input.tpu_prewarm forces either way.
         # auto format skips (its per-class legs compile lazily per mix).
         prewarm = self._prewarm_cfg
         if prewarm is None:
-            prewarm = self._compile_cache_dir is not None
+            prewarm = self._cache_placed
         if (prewarm and self._block_mode and fmt != "auto"
                 and self._kernel_fn is not None and self._block_route_ok()):
             from . import pack as _pack_mod

@@ -17,6 +17,7 @@ TPU shape of that fusion, for every format pair that rides it.
 
 from __future__ import annotations
 
+import atexit
 import os
 import sys
 import threading
@@ -39,16 +40,25 @@ _U8 = jnp.uint8
 # containers).  The fast path is optional — a compile must never stall
 # the stream — so the first call of each kernel phase runs under a
 # wall-clock deadline: on timeout the compile keeps warming the jit
-# cache in a daemon thread while every batch meanwhile declines to the
+# cache in a worker thread while every batch meanwhile declines to the
 # host block-encode path (same bytes), and once the background compile
-# lands the device tier engages normally.
+# lands the device tier engages normally.  The workers belong to this
+# module: ``join_compile_workers`` (called at pipeline drain) waits for
+# them, so the interpreter never finalises with a thread inside XLA.
 COMPILE_TIMEOUT_ENV = "FLOWGGER_COMPILE_TIMEOUT_MS"
 COMPILE_TIMEOUT_MS_DEFAULT = 15_000
+# how long a drain waits for compiles still in flight before it gives
+# up on them (they stay daemon threads, so a wedged compile can delay
+# an exit but never block it)
+COMPILE_JOIN_TIMEOUT_S = 120.0
 
 _compile_slots: Dict[str, threading.Event] = {}
 _compile_ready = set()  # names that have completed once: call inline
 _compile_lock = threading.Lock()
 _compile_warned = set()
+# live compile worker threads (guarded by _compile_lock); each worker
+# removes itself when its compile lands
+_compile_workers: set = set()
 # cumulative decline count, independent of the (resettable) metrics
 # registry — tests/conftest.py reads it to turn a watchdog-declined
 # differential test into an informative xfail
@@ -103,11 +113,35 @@ def _count_decline() -> None:
         _decline_total += 1
 
 
+def join_compile_workers(timeout_s: float = COMPILE_JOIN_TIMEOUT_S) -> int:
+    """Wait (bounded) for every compile worker still in flight; returns
+    how many were still alive at the deadline.  The pipeline calls this
+    at drain, after the handlers closed, so a worker that outlived its
+    watchdog-declined caller has landed before the process exits."""
+    import time as _time
+
+    deadline = _time.monotonic() + timeout_s
+    while True:
+        with _compile_lock:
+            workers = [t for t in _compile_workers if t.is_alive()]
+        if not workers:
+            return 0
+        left = deadline - _time.monotonic()
+        if left <= 0:
+            return len(workers)
+        workers[0].join(left)
+
+
+# processes that never reach a pipeline drain (tools, tests) still must
+# not finalise the interpreter around a live compile
+atexit.register(join_compile_workers)
+
+
 def guarded_compile_call(name: str, fn, *args, timeout_s=None):
     """Run a (potentially compiling) jit call with a deadline.
 
     Raises CompileTimeout when the call exceeds the deadline — the call
-    finishes in a background daemon thread so the jit cache still warms
+    finishes in a background worker thread so the jit cache still warms
     — or instantly while that background run is still going.  A value
     of ``FLOWGGER_COMPILE_TIMEOUT_MS=0`` disables the watchdog.
     ``timeout_s`` overrides the deadline for this call (the fused-route
@@ -170,10 +204,17 @@ def guarded_compile_call(name: str, fn, *args, timeout_s=None):
                 _compile_ready.add(name)
         finally:
             done.set()
+            with _compile_lock:
+                _compile_workers.discard(threading.current_thread())
 
-    # flowcheck: disable=FC10 -- the compile worker must outlive its (watchdog-declined) caller so the compile lands for the next call; the done event + single-flight semaphore own its lifecycle, and joining it is exactly the stall the watchdog exists to prevent
-    threading.Thread(target=run, daemon=True,
-                     name=f"xla-compile:{name}").start()
+    # the worker must outlive its (watchdog-declined) caller so the
+    # compile lands for the next call: the caller never joins it, the
+    # module does (join_compile_workers, at pipeline drain)
+    worker = threading.Thread(target=run, daemon=True,
+                              name=f"xla-compile:{name}")
+    with _compile_lock:
+        _compile_workers.add(worker)
+    worker.start()
     if busy is not None:
         # another kernel's compile holds the single-flight semaphore
         # RIGHT NOW, so this one cannot even start XLA work before the
@@ -215,31 +256,46 @@ def guarded_compile_call(name: str, fn, *args, timeout_s=None):
     return box["result"]
 
 # -- persistent compile cache + prewarm --------------------------------------
-# A fresh (rows, max_len) shape costs a full XLA compile — >60s for the
-# encode kernels on constrained hosts, which the watchdog converts into
-# host-path declines: the device tier spends its first minutes per shape
-# losing the route-economics race it should win.  Two fixes compose:
-# the persistent compilation cache (``input.tpu_compile_cache_dir``)
-# makes every compile a once-per-machine cost, and the background
+# A fresh (rows, max_len) shape costs a full XLA compile — about 11 s
+# per fused program for a v5e — which the watchdog converts into
+# host-path declines.  Two fixes compose: JAX's persistent compilation
+# cache makes every compile a once-per-machine cost, and the background
 # prewarm compiles the configured format's kernels for the shape-bucket
 # grid at startup so the first real batch hits a warm jit cache.  Cache
 # traffic is observable as ``compile_cache_hits``/``compile_cache_
 # misses`` counters (a second cold process of the same config should
 # report zero misses for the prewarmed kernels).
+#
+# Where the cache lives is decided in ONE function, enable_compile_cache,
+# for the pipeline, bench.py and chip_smoke.py alike:
+# ``JAX_COMPILATION_CACHE_DIR`` wins and is used as it is (JAX reads it
+# itself; no code here sets another directory); else a directory a
+# caller names (``input.tpu_compile_cache_dir``, an AOT store's
+# xla-cache); else the one already in force; else DEFAULT_CACHE_DIR
+# inside the checkout — on an accelerator backend.  On the CPU backend
+# nobody asked and nothing is switched on: XLA:CPU logs two
+# multi-kilobyte error lines for every entry it loads (its
+# machine-feature check trips on the compiler's own pseudo-features)
+# and a CPU compile of these kernels takes seconds.
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, git-ignored, inside the checkout: the path is part of the
+# cache key, so one built from a pid, a temporary name or the time
+# would never hit
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _cache_state_lock = threading.Lock()
-_cache_dir_installed = None
 _cache_listener_installed = False
 
-# Kernel ABI revision folded into the persistent-cache directory layout.
-# JAX's cache key covers the traced computation, NOT our kernel-level
-# contracts: a signature/layout change (the PR 4 ``_encode_kernel``
-# elide rework silently invalidated every cached encode entry) leaves
-# stale entries of the OLD kernels poisoning the dir forever and makes
-# "second cold process compiles nothing" silently false after an
-# upgrade.  Bump this whenever a kernel signature, segment layout, or
-# channel contract changes; old revisions keep their own subdirectory
-# and die with ordinary cache cleanup.
+# Kernel ABI revision folded into the layout of every directory this
+# code chooses itself.  JAX's cache key covers the traced computation,
+# NOT our kernel-level contracts: a signature/layout change leaves
+# stale entries of the OLD kernels in the dir forever.  Bump this
+# whenever a kernel signature, segment layout, or channel contract
+# changes; old revisions keep their own subdirectory and die with
+# ordinary cache cleanup.  (The AOT manifest pins the same number.)
 KERNEL_ABI = 9
 
 
@@ -256,7 +312,6 @@ def _install_cache_listener() -> None:
     from ..utils.metrics import registry as _reg
 
     def _on_event(event, **_kw):
-        # event names are stable-ish across jax versions; match the leaf
         if event.endswith("/cache_hits"):
             _reg.inc("compile_cache_hits")
         elif event.endswith("/cache_misses"):
@@ -277,51 +332,77 @@ CACHE_KNOBS = (("jax_compilation_cache_dir",)
                + tuple(k for k, _ in CACHE_KNOB_SETTINGS))
 
 
-def enable_compile_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` and
-    start counting hits/misses.  Thresholds are dropped to zero so even
-    the small decode kernels persist — on hosts where the big encode
-    compiles never finish inside the watchdog, the cheap kernels are
-    exactly the ones worth never recompiling.
+def cache_placed_outside() -> bool:
+    """``JAX_COMPILATION_CACHE_DIR`` is set: the operator placed the
+    cache, and no code of this repo may point it anywhere else."""
+    return bool(os.environ.get(CACHE_DIR_ENV))
 
-    The configured directory is versioned by ``KERNEL_ABI``
-    (``<dir>/kabi-<N>``): entries compiled against an older kernel ABI
-    can neither be loaded by mistake nor mask a needed recompile."""
-    cache_dir = os.path.join(os.path.expanduser(cache_dir),
-                             f"kabi-{KERNEL_ABI}")
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for knob, val in CACHE_KNOB_SETTINGS:
+
+def enable_compile_cache(cache_dir: Optional[str] = None
+                         ) -> Optional[str]:
+    """Switch JAX's persistent compilation cache on and start counting
+    hits/misses; returns the directory in use.  Placement, in order:
+    ``JAX_COMPILATION_CACHE_DIR`` as it is (``cache_dir`` is then
+    ignored and ``jax_compilation_cache_dir`` is never updated here),
+    else ``cache_dir``, else the directory already in force (an AOT
+    store's warmed xla-cache, an earlier handler's key: the default
+    displaces nothing), else — off the CPU backend — ``DEFAULT_CACHE_
+    DIR``.  A directory chosen here is versioned by ``KERNEL_ABI``
+    (``<dir>/kabi-<N>``).  Unplaced on the CPU backend, or where the
+    default cannot be created (a read-only install), nothing is
+    switched on and None comes back; a named directory that cannot be
+    created raises.  Thresholds are dropped to zero so even the small
+    decode kernels persist."""
+    from jax._src import compilation_cache as _cc
+
+    changed = False
+    if cache_placed_outside():
+        cache_dir = os.environ[CACHE_DIR_ENV]
+    elif cache_dir is None and jax.config.jax_compilation_cache_dir:
+        cache_dir = jax.config.jax_compilation_cache_dir
+    elif cache_dir is None and jax.default_backend() == "cpu":
+        return None
+    else:
+        named = cache_dir is not None
+        cache_dir = os.path.join(
+            os.path.expanduser(cache_dir or DEFAULT_CACHE_DIR),
+            f"kabi-{KERNEL_ABI}")
         try:
+            os.makedirs(cache_dir, exist_ok=True)
+        except OSError as e:
+            if named:
+                raise
+            print(f"compile cache: cannot create {cache_dir} "
+                  f"({type(e).__name__}: {e}); running without a "
+                  f"persistent cache (set {CACHE_DIR_ENV} or "
+                  "input.tpu_compile_cache_dir to place one)",
+                  file=sys.stderr)
+            return None
+        if jax.config.jax_compilation_cache_dir != cache_dir:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            changed = True
+    for knob, val in CACHE_KNOB_SETTINGS:
+        if getattr(jax.config, knob) != val:
             jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - knob names vary across jax versions
-            pass
-    try:
+            changed = True
+    if changed:
         # jax latches the use-the-cache decision at the first compile;
         # a process that already compiled something (tests, a handler
         # built before the config was read) must reset that memo or the
-        # new cache dir is silently ignored
-        from jax._src import compilation_cache as _cc
-
+        # new settings are silently ignored
         _cc.reset_cache()
-    except Exception:  # noqa: BLE001 - private API; harmless if gone
-        pass
     _install_cache_listener()
-    with _cache_state_lock:
-        global _cache_dir_installed
-        _cache_dir_installed = cache_dir
     return cache_dir
 
 
-def setup_compile_cache(config):
-    """Wire ``input.tpu_compile_cache_dir`` (no key = no cache, the
-    stock JAX behavior).  Returns the directory when installed."""
-    cache_dir = config.lookup_str(
+def setup_compile_cache(config) -> Optional[str]:
+    """The pipeline's cache wiring: ``input.tpu_compile_cache_dir`` names
+    the directory unless the environment already placed it; with
+    neither, the in-checkout default (none on the CPU backend).
+    Returns the directory in use."""
+    return enable_compile_cache(config.lookup_str(
         "input.tpu_compile_cache_dir",
-        "input.tpu_compile_cache_dir must be a string (directory)", None)
-    if not cache_dir:
-        return None
-    return enable_compile_cache(cache_dir)
+        "input.tpu_compile_cache_dir must be a string (directory)", None))
 
 
 def _zero_packed(rows: int, max_len: int):
@@ -954,10 +1035,9 @@ def fetch_encode_driver(kernel, out, batch_dev, lens_dev, packed, encoder,
     # device (closures are rebuilt per batch; the jit cache underneath
     # is not; lane dispatch compiles one executable per device, so each
     # lane's compile needs its own watchdog slot)
-    try:
-        _dev = ",".join(sorted(str(d) for d in batch_dev.devices()))
-    except Exception:  # noqa: BLE001 - tracers/older arrays have no .devices()
-        _dev = "default"
+    _devs = getattr(batch_dev, "devices", None)  # host arrays have none
+    _dev = (",".join(sorted(str(d) for d in _devs())) if _devs
+            else "default")
     kname = (f"{kname_prefix or getattr(kernel, '__module__', 'device')}:"
              f"{tuple(batch_dev.shape)}:{_dev}")
 
@@ -1110,8 +1190,8 @@ def fetch_encode_driver(kernel, out, batch_dev, lens_dev, packed, encoder,
         # maxw quantizes up to 128 so the slice program count stays
         # bounded, and the slice itself runs under the compile watchdog
         # (a data-dependent shape is a fresh XLA program; on a hung
-        # remote compile the plain full-matrix transfer below cannot
-        # stall — it is a pure copy of an existing buffer)
+        # compile the plain full-matrix transfer below cannot stall —
+        # it is a pure copy of an existing buffer)
         maxw = min(OW, -(-max(int(gated[:n].max()), 1) // 128) * 128)
         try:
             trimmed = _guarded(

@@ -280,7 +280,9 @@ def _pallas_spans_probe(framing: str, region_dev, rlen, B: int,
                         ncap: int, statics: dict, dev_label: str):
     """Try the single-VMEM Pallas spans kernel; None = declined or
     disengaged (the caller falls to the jnp scatter ladder).  Declines
-    ride the framing cooldown ladder under their own namespace."""
+    ride the framing cooldown ladder under their own namespace; in
+    compiled mode only a watchdog timeout declines, anything else
+    raises (``pallas_kernels.must_raise``)."""
     from . import aot as _aot
     from . import pallas_kernels as _pallas
 
@@ -308,6 +310,8 @@ def _pallas_spans_probe(framing: str, region_dev, rlen, B: int,
         out = _watchdogged(
             f"pallas/{framing}:{B}x{ncap}:{dev_label}", stage_a_pallas)
     except Exception as e:  # noqa: BLE001 - decline to the jnp tier, never lose data
+        if _pallas.must_raise(e):
+            raise
         note_decline(pstate)
         _metrics.inc("pallas_declines")
         _events.emit("framing", "pallas_decline", route=framing,
@@ -346,6 +350,8 @@ def _pallas_gather_probe(region_dev, starts_dev, lens_dev, B: int,
             f"pallas/gather:{B}x{rows}x{max_len}:{dev_label}",
             stage_b_pallas)
     except Exception as e:  # noqa: BLE001 - decline to the jnp tier, never lose data
+        if _pallas.must_raise(e):
+            raise
         note_decline(pstate)
         _metrics.inc("pallas_declines")
         _events.emit("framing", "pallas_decline", route="gather",
@@ -389,10 +395,7 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
         buf[:nbytes] = np.frombuffer(region, dtype=np.uint8)
     region_dev = _device_put2(buf, device)
     rlen = _device_put2(np.int32(nbytes), device)
-    try:
-        dev_label = ",".join(sorted(str(d) for d in region_dev.devices()))
-    except Exception:  # noqa: BLE001 - older arrays lack .devices()
-        dev_label = "default"
+    dev_label = ",".join(sorted(str(d) for d in region_dev.devices()))
 
     from . import aot as _aot
 
@@ -416,10 +419,11 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
             return out
         return kfn()
 
-    # Pallas tier first: the single-VMEM spans kernel collapses the
-    # pointer-doubling scatter ladder to one region read; a decline
-    # (lowering failure, watchdog) rides its own cooldown ladder and
-    # falls straight to the jnp tier below — same bytes, same output.
+    # Pallas tier first, where input.tpu_pallas = "on" engaged it: the
+    # single-VMEM spans kernel collapses the pointer-doubling scatter
+    # ladder to one region read; a decline rides its own cooldown
+    # ladder and falls straight to the jnp tier below — same bytes,
+    # same output.
     out = _pallas_spans_probe(framing, region_dev, rlen, B, ncap,
                               statics, dev_label)
     slot = f"framing/{framing}:{B}x{ncap}:{dev_label}"

@@ -32,15 +32,16 @@ of that idea:
 ``RouteEconomics``
     The device-encode tier is gated by *applicability* (route_ok) and
     *health* (decline hysteresis), but never by *profitability*: on a
-    backend where the kernels execute slowly (CPU fallback, a wedged
-    relay), the device tier can cost more wall time than the host block
+    backend where the kernels execute slowly (the CPU backend), the
+    device tier can cost more wall time than the host block
     encode it replaces while every probe still "succeeds".  This tracker
     keeps an EWMA of measured seconds/row for both paths and routes
     batches to the cheaper one, re-probing the loser periodically
     (``input.tpu_encode_probe_every``) so a recovered device wins back
-    the traffic.  On a real TPU the device tier wins the comparison and
-    nothing changes; on this container's CPU backend the host path wins
-    ~8x and the executor becomes host-stage-bound, which is the point.
+    the traffic.  On the CPU backend the host path wins ~8x and the
+    executor becomes host-stage-bound, which is the point; which path
+    wins on a chip is recorded in PERF.md (on the first v5e run the
+    host block encoder won at full batches).
 
 ``LaneSet``
     N per-device lanes, each an ``InflightWindow`` with its own fetcher
@@ -83,7 +84,7 @@ ECON_ALPHA = 0.4
 # a device tier at or under this measured seconds/row is performing at
 # accelerator levels — no host path can beat it, so the comparison
 # sample (one host-routed batch) is never paid.  Only a device tier
-# slower than ~100K rows/s (CPU fallback, wedged relay) triggers the
+# slower than ~100K rows/s (the CPU backend) triggers the
 # host probe at all.
 DEVICE_OK_SPR = 1e-5
 

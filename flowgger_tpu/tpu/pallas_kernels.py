@@ -29,24 +29,31 @@ span/index outputs are written back:
   dense [rows, max_len] batch is an internal value that never
   materializes as a program output.
 
-``interpret=True`` runs every kernel in the Pallas interpreter so this
-CPU container differential-tests them byte-for-byte against the scalar
-oracles; on a real TPU the same bodies lower through Mosaic (inputs
-are widened u8→i32 *outside* the kernels — this jax's Mosaic can't
-load u8 refs; the widen is one elementwise pass, still collapsing the
-jnp tier's dozens).  Region-sized kernels run as one VMEM block, so
-the tier self-gates at ``PALLAS_MAX_REGION`` bytes and larger regions
-stay on the jnp tier.
+``interpret=True`` runs every kernel in the Pallas interpreter, which
+is how the CPU differential tests hold them byte-for-byte to the scalar
+oracles.  **The chip's compiler refuses all six kernels today**
+(jax 0.9.0 / libtpu 0.0.34, compiled for a described v5e in
+``tests/test_chip_compile.py``): the span and gather kernels for the
+1-element dynamic lane load in ``_read1`` ("cannot statically prove
+that index in dimension 1 is a multiple of 128"), the two decode
+kernels for an ``arith.trunci`` from i8 to i1 ("Unsupported target
+bitwidth for truncation").  Repair or deletion is ROADMAP D2; until
+then the tier is opt-in only.  Region-sized kernels run as one VMEM
+block, so the tier self-gates at ``PALLAS_MAX_REGION`` bytes and larger
+regions stay on the jnp tier.
 
-Decline ladder: the tier rides the existing machinery — framing-side
-probes run under the compile watchdog (slot ``pallas/<kind>``) inside
-``framing.device_frame_region`` and fall back to the *jnp* span
-kernels (then host) on any decline; the decode tier
-(``decode_tier``) declines to the format's ``decode_*_jit`` after
-``DECLINE_LIMIT`` failures and cools down like the framing tier.
 Engagement is the ``input.tpu_pallas = auto|on|off`` key resolved by
-the batch handler into :func:`set_mode` ("compiled" on accelerator
-backends, "interpret" for ``on`` on the CPU backend, "off" otherwise).
+the batch handler into :func:`set_mode`: ``auto`` and ``off`` are
+"off" on every backend; ``on`` is "interpret" on the CPU backend and
+"compiled" elsewhere, where :func:`require_compiles` first compiles the
+handler's kernels and turns a refusal into a start-up error that names
+the kernel.  An engaged tier still rides the decline machinery for what
+can happen later — framing-side probes run under the compile watchdog
+(slot ``pallas/<kind>``) inside ``framing.device_frame_region`` and
+fall back to the *jnp* span kernels on a watchdog timeout, the decode
+tier (``decode_tier``) likewise — but in compiled mode any other
+failure of a kernel is raised to the caller (the breaker counts it),
+never declined, cooled down and retried.
 """
 
 from __future__ import annotations
@@ -133,12 +140,69 @@ def framing_engaged(region_bytes: int) -> bool:
 
 def fused_leg_mode() -> str:
     """The pallas mode a fused decode→encode program's rfc5424 leg
-    traces with: ``compiled`` on accelerators, else ``off`` — interpret
-    mode inlined into a fused program explodes XLA CPU compile time
-    (the interpreter unrolls the kernel body into the already-large
-    encode graph), so CPU tests exercise the standalone fused entries
-    (``fused_frame_decode_*``) instead."""
+    traces with: ``compiled`` only where the tier itself is compiled
+    (an explicit ``on`` off the CPU backend), else ``off`` — so the
+    default fused program on every backend traces the jnp decode leg.
+    Interpret mode never rides a fused program: inlined there it
+    explodes XLA CPU compile time (the interpreter unrolls the kernel
+    body into the already-large encode graph), so CPU tests exercise
+    the standalone fused entries (``fused_frame_decode_*``) instead."""
     return "compiled" if _MODE["mode"] == "compiled" else "off"
+
+
+def must_raise(e: BaseException) -> bool:
+    """Whether a failed kernel call is an error and not a decline:
+    compiled kernels passed :func:`require_compiles` at start-up, so
+    only a watchdog timeout may still decline them."""
+    from .device_common import CompileTimeout
+
+    return _MODE["mode"] == "compiled" and not isinstance(e, CompileTimeout)
+
+
+def require_compiles(fmt: str, rows: int, max_len: int,
+                     framing: bool) -> None:
+    """Compile, for the default device, every kernel a handler of this
+    shape would dispatch in compiled mode; a refusal raises ConfigError
+    naming the kernel.  Called once at start-up for an explicit
+    ``input.tpu_pallas = "on"`` off the CPU backend."""
+    from ..config import ConfigError
+
+    def u8(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.uint8)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, _I32)
+
+    checks = []
+    if fmt == "rfc5424":
+        from .rfc5424 import decode_rfc5424_pallas
+
+        checks.append(("decode_rfc5424_pallas",
+                       jax.jit(decode_rfc5424_pallas),
+                       (u8(rows, max_len), i32(rows)), {}))
+    elif fmt == "jsonl":
+        checks.append(("decode_jsonl_pallas", decode_jsonl_pallas,
+                       (u8(rows, max_len), i32(rows)), {}))
+    if framing:
+        B = 1 << 16
+        checks += [
+            ("frame_sep_spans_pallas", frame_sep_spans_pallas,
+             (u8(B), i32()), {}),
+            ("frame_syslen_spans_pallas", frame_syslen_spans_pallas,
+             (u8(B), i32()), {}),
+            ("frame_gather_pallas", frame_gather_pallas,
+             (u8(B), i32(256), i32(256)), {"max_len": max_len}),
+        ]
+    for name, fn, args, kw in checks:
+        try:
+            fn.lower(*args, **kw).compile()
+        except Exception as e:  # noqa: BLE001 - any refusal is the start-up error
+            first = (str(e).strip().splitlines() or [""])[0]
+            raise ConfigError(
+                f'input.tpu_pallas = "on": the compiler refuses kernel '
+                f"{name} ({type(e).__name__}: {first}); set "
+                'input.tpu_pallas = "off" (or leave it out) to run the '
+                "jnp kernel tiers") from e
 
 
 # ---------------------------------------------------------------------------
@@ -583,7 +647,8 @@ def decode_tier(fmt: str, batch_dev, lens_dev,
     decline) — the caller falls to its ``decode_*_jit`` exactly like
     an AOT miss.  Failures ride the framing-style decline ladder:
     watchdogged first compile, DECLINE_LIMIT strikes then COOLDOWN
-    batches of jnp decode before the next probe."""
+    batches of jnp decode before the next probe (compiled mode raises
+    anything but a watchdog timeout — ``must_raise``)."""
     from ..obs import events as _events
     from ..utils.metrics import registry as _metrics
     from .device_common import guarded_compile_call
@@ -620,6 +685,8 @@ def decode_tier(fmt: str, batch_dev, lens_dev,
     try:
         out = guarded_compile_call(slot, run)
     except Exception as e:  # noqa: BLE001 - decline to the jnp tier, never lose the batch
+        if must_raise(e):
+            raise
         note_decline(state)
         _metrics.inc("pallas_declines")
         _events.emit("decode", "pallas_decline", route=fmt,

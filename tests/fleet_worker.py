@@ -27,8 +27,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 CHUNK = 8
 CHUNK_SLEEP_S = 0.25  # spreads 96 lines over ~3s: the kill lands mid-stream
 

@@ -675,8 +675,56 @@ def test_batchhandler_boots_against_artifacts(art_dir,
     assert out == b"".join(merger.frame(ln) for ln in LINES["rfc5424"])
 
 
+def test_batchhandler_boot_keeps_the_aot_cache_off_the_cpu_backend(
+        art_dir, tmp_path, monkeypatch, restore_jax_cache):
+    """setup_aot points the persistent cache at the artifact dir's
+    warmed xla-cache; the handler's own cache wiring runs right after
+    it, and off the CPU backend (where an unplaced cache defaults to
+    <checkout>/.jax_cache) must leave that directory in force — or an
+    input.tpu_aot_dir boot on a TPU recompiles everything it shipped."""
+    import jax
+
+    from flowgger_tpu.tpu import device_common
+
+    class CacheWiringOnATpu:
+        """device_common's view of jax alone: the store itself was
+        built for, and must keep loading on, the CPU backend."""
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def default_backend():
+            return "tpu"
+
+    monkeypatch.delenv(device_common.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setattr(device_common, "jax", CacheWiringOnATpu())
+    monkeypatch.setattr(device_common, "DEFAULT_CACHE_DIR",
+                        str(tmp_path / "default"))
+    jax.config.update("jax_compilation_cache_dir", None)
+    clone = str(tmp_path / "art")
+    shutil.copytree(art_dir, clone)
+    marker = aot._warm_marker_path(clone, "cpu")
+    os.makedirs(os.path.dirname(marker), exist_ok=True)
+    open(marker, "w").close()
+    cfg = Config.from_string(
+        f"[input]\ntpu_batch_size = {ROWS}\n"
+        f"tpu_max_line_len = {MAX_LEN}\n"
+        f'tpu_aot_dir = "{clone}"\n')
+    h = BatchHandler(queue.Queue(), RFC5424Decoder(cfg),
+                     PassthroughEncoder(cfg), cfg, fmt="rfc5424",
+                     start_timer=False, merger=LineMerger())
+    try:
+        assert aot.active_store() is not None
+        assert jax.config.jax_compilation_cache_dir == os.path.dirname(
+            marker)
+        assert not (tmp_path / "default").exists()
+    finally:
+        h.close()
+
+
 # ---------------------------------------------------------------------------
-# CLI + deprecated shim
+# CLI
 
 
 def test_aot_cli_build_and_validate(tmp_path):
@@ -686,15 +734,6 @@ def test_aot_cli_build_and_validate(tmp_path):
                      "--max-len", str(MAX_LEN),
                      "--framings", "line"]) == 0
     assert aot.main(["validate", out]) == 0
-
-
-def test_pallas_shim_delegates_and_rejects_unknown():
-    tool = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools", "pallas_aot.py")
-    r = subprocess.run([sys.executable, tool, "bogus"],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 2
-    assert "DEPRECATED" in r.stderr
 
 
 # ---------------------------------------------------------------------------

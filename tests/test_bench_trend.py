@@ -20,8 +20,9 @@ def test_repo_series_parses_clean():
     assert len(rows) >= 10
     assert bench_trend.check(rows) == []
     text = bench_trend.table(rows)
-    # the r01 raw capture, a bytes/row pair, and the r06 stub all land
-    assert "BENCH_r01.json" in text
+    # the first entry (r04: r01-r03 were deleted records of a retired
+    # set-up), a bytes/row pair, and the r06 stub all land
+    assert "BENCH_r04.json" in text
     assert "stub: backfilled in PR 10" in text
 
 
@@ -96,4 +97,4 @@ def test_json_mode_emits_rows():
     assert r.returncode == 0
     payload = json.loads(r.stdout)
     assert len(payload) >= 10
-    assert payload[0]["entry"] == "BENCH_r01.json"
+    assert payload[0]["entry"] == "BENCH_r04.json"

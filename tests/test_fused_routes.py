@@ -260,29 +260,117 @@ def test_route_economics_fused_arm():
     assert econ.snapshot()["fused_s_per_row"] is not None
 
 
-def test_kernel_abi_versions_cache_dir(tmp_path):
-    """setup_compile_cache folds the KERNEL_ABI rev into the directory
-    layout so kernel-signature changes can't poison or silently
-    invalidate old entries (the PR 4 _encode_kernel footgun)."""
+@pytest.fixture
+def cache_config_restored():
+    """Put the process-global persistent-cache config back after a test
+    that pointed it somewhere."""
+    from jax._src import compilation_cache as _cc
+
+    from flowgger_tpu.tpu.device_common import CACHE_KNOBS
+
+    saved = {k: getattr(jax.config, k) for k in CACHE_KNOBS}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    _cc.reset_cache()
+
+
+def test_kernel_abi_versions_cache_dir(tmp_path, monkeypatch,
+                                       cache_config_restored):
+    """setup_compile_cache folds the KERNEL_ABI rev into the layout of
+    every directory the code chooses itself, so kernel-signature
+    changes can't poison or silently invalidate old entries (the PR 4
+    _encode_kernel footgun)."""
     from flowgger_tpu.tpu import device_common
 
-    saved = {
-        k: jax.config._read(k)
-        for k in ("jax_compilation_cache_dir",)
-    }
-    try:
-        cfg = Config.from_string(
-            f'[input]\ntpu_compile_cache_dir = "{tmp_path}"\n')
-        installed = device_common.setup_compile_cache(cfg)
-        assert installed == os.path.join(
-            str(tmp_path), f"kabi-{device_common.KERNEL_ABI}")
-        assert os.path.isdir(installed)
-        # no key -> no cache install
+    monkeypatch.delenv(device_common.CACHE_DIR_ENV, raising=False)
+    cfg = Config.from_string(
+        f'[input]\ntpu_compile_cache_dir = "{tmp_path}"\n')
+    installed = device_common.setup_compile_cache(cfg)
+    assert installed == os.path.join(
+        str(tmp_path), f"kabi-{device_common.KERNEL_ABI}")
+    assert os.path.isdir(installed)
+    assert jax.config.jax_compilation_cache_dir == installed
+
+
+def test_default_cache_is_one_fixed_dir_in_the_checkout(
+        monkeypatch, cache_config_restored):
+    """No key, no environment: off the CPU backend the cache goes to
+    one fixed, git-ignored path inside the checkout — the same for
+    every run, because the path is part of the cache key.  On the CPU
+    backend nothing is switched on."""
+    from flowgger_tpu.tpu import device_common
+
+    monkeypatch.delenv(device_common.CACHE_DIR_ENV, raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert device_common.setup_compile_cache(Config.from_string("")) is None
+    assert jax.config.jax_compilation_cache_dir is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache",
+                        f"kabi-{device_common.KERNEL_ABI}")
+    for _ in range(2):
         assert device_common.setup_compile_cache(
-            Config.from_string("")) is None
-    finally:
-        for k, v in saved.items():
-            jax.config.update(k, v)
+            Config.from_string("")) == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_default_cache_displaces_nothing_and_survives_a_readonly_install(
+        tmp_path, monkeypatch, cache_config_restored, capsys):
+    """Off the CPU backend the in-checkout default is only for a process
+    nobody placed a cache for: a directory already in force (an AOT
+    store's warmed xla-cache, an earlier handler's key) stays, and a
+    checkout the default cannot be created in boots without a cache
+    instead of crashing.  A directory someone named still raises."""
+    from flowgger_tpu.tpu import device_common
+
+    monkeypatch.delenv(device_common.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_compilation_cache_dir", None)
+    blocker = tmp_path / "a-file"
+    blocker.write_text("")
+    monkeypatch.setattr(device_common, "DEFAULT_CACHE_DIR",
+                        str(blocker / ".jax_cache"))
+    assert device_common.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+    assert "cannot create" in capsys.readouterr().err
+    with pytest.raises(OSError):
+        device_common.enable_compile_cache(str(blocker / "named"))
+    named = device_common.enable_compile_cache(str(tmp_path / "named"))
+    assert jax.config.jax_compilation_cache_dir == named
+    for _ in range(2):
+        assert device_common.enable_compile_cache() == named
+        assert device_common.setup_compile_cache(
+            Config.from_string("")) == named
+    assert jax.config.jax_compilation_cache_dir == named
+
+
+def test_cache_placed_from_outside_is_used_as_it_is(
+        tmp_path, monkeypatch, cache_config_restored):
+    """JAX_COMPILATION_CACHE_DIR wins over every other source, gets no
+    kabi suffix, and jax_compilation_cache_dir is never updated in code
+    (JAX read the variable itself when it was imported)."""
+    from flowgger_tpu.tpu import device_common
+
+    outside = str(tmp_path / "placed")
+    monkeypatch.setenv(device_common.CACHE_DIR_ENV, outside)
+    before = jax.config.jax_compilation_cache_dir
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    cfg = Config.from_string(
+        f'[input]\ntpu_compile_cache_dir = "{tmp_path / "key"}"\n')
+    assert device_common.setup_compile_cache(cfg) == outside
+    assert device_common.enable_compile_cache(
+        str(tmp_path / "aot" / "xla-cache")) == outside
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "key").exists()
+    assert not (tmp_path / "aot").exists()
 
 
 @pytest.mark.requires_device_encode_compile

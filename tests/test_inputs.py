@@ -285,11 +285,17 @@ file_path = "{out}"
              for i in range(50)]
     with socket.create_connection(("127.0.0.1", pipeline.input.bound_port)) as s:
         s.sendall("".join(ln + "\n" for ln in lines).encode())
+    def records():
+        return out.read_bytes().count(b"\x00") if out.exists() else 0
+
+    # the first batch carries a cold kernel compile, whose length is the
+    # host's (and, under xdist, its neighbours') business: the 15 s wall
+    # deadline starts once a batch has left the handler
+    cold = time.time() + 300
+    while records() < 1 and time.time() < cold:
+        time.sleep(0.05)
     deadline = time.time() + 15
-    while time.time() < deadline:
-        data = out.read_bytes() if out.exists() else b""
-        if data.count(b"\x00") >= 50:
-            break
+    while records() < 50 and time.time() < deadline:
         time.sleep(0.05)
     msgs = [m for m in out.read_bytes().split(b"\x00") if m]
     assert len(msgs) == 50

@@ -149,7 +149,7 @@ def test_economics_prefers_device_when_it_wins():
 def test_economics_healthy_device_never_pays_host_probe():
     """A device tier measuring at accelerator speed keeps all traffic:
     the one-batch host comparison only happens when the device is
-    measurably slow (CPU fallback, wedged relay)."""
+    measurably slow (the CPU backend)."""
     e = RouteEconomics(probe_every=10)
     assert e.allow_device()
     e.observe("device", 1_000_000, 1.0)  # 1us/row: accelerator-fast

@@ -385,9 +385,10 @@ def test_pallas_mode_off_never_calls_kernels(monkeypatch):
 
 
 def test_fused_leg_mode_never_interpret():
-    # interpret-mode pallas inlined into a fused decode→encode program
-    # explodes XLA CPU compile time; the fused leg engages only on real
-    # accelerators ("compiled")
+    # the fused decode→encode programs trace the Pallas decode leg only
+    # where the tier itself is compiled (an explicit "on" off the CPU
+    # backend); interpret mode never rides a fused program, and the
+    # default ("auto" -> off) traces the jnp leg on every backend
     try:
         PK.set_mode("interpret")
         assert PK.fused_leg_mode() == "off"
@@ -395,6 +396,86 @@ def test_fused_leg_mode_never_interpret():
         assert PK.fused_leg_mode() == "compiled"
         PK.set_mode("off")
         assert PK.fused_leg_mode() == "off"
+    finally:
+        PK.set_mode("off")
+
+
+def _handler(pallas_line=""):
+    return BatchHandler(
+        queue.Queue(), RFC5424Decoder(), LTSVEncoder(CFG),
+        Config.from_string("[input]\n" + pallas_line),
+        fmt="rfc5424", start_timer=False, merger=None)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_pallas_auto_is_off_on_every_backend(monkeypatch, backend):
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    PK.set_mode("interpret")  # a stale mode must not survive the handler
+    try:
+        for line in ("", 'tpu_pallas = "auto"\n', 'tpu_pallas = "off"\n'):
+            h = _handler(line)
+            assert PK.mode() == "off", (backend, line)
+            assert PK.fused_leg_mode() == "off"
+            h.close()
+    finally:
+        PK.set_mode("off")
+
+
+def test_pallas_on_resolves_by_backend(monkeypatch):
+    import jax
+
+    try:
+        h = _handler('tpu_pallas = "on"\n')
+        assert PK.mode() == "interpret"  # CPU backend: the interpreter
+        h.close()
+        # off the CPU backend "on" means compiled kernels, once the
+        # start-up compile check passed
+        checked = []
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(PK, "require_compiles",
+                            lambda *a, **k: checked.append((a, k)))
+        h = _handler('tpu_pallas = "on"\n')
+        assert PK.mode() == "compiled"
+        assert PK.fused_leg_mode() == "compiled"
+        assert checked and checked[0][0][0] == "rfc5424"
+        h.close()
+    finally:
+        PK.set_mode("off")
+
+
+def test_pallas_on_refusal_is_a_startup_error(monkeypatch):
+    # the real check, on a backend whose compiler refuses the kernels
+    # (here the CPU backend refuses any non-interpret pallas_call, as
+    # Mosaic refuses these six on the chip): the handler does not come
+    # up, the error names the kernel, and nothing is left engaged
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with pytest.raises(ConfigError, match="decode_rfc5424_pallas"):
+            _handler('tpu_pallas = "on"\n')
+        assert PK.mode() == "off"
+    finally:
+        PK.set_mode("off")
+
+
+def test_compiled_mode_raises_where_interpret_declines(monkeypatch):
+    # a kernel failure after start-up: interpret mode declines to the
+    # jnp tier (the ladder tests above); compiled mode raises it to the
+    # caller, where the breaker counts it — never a quiet retry loop
+    def boom(*a, **k):
+        raise RuntimeError("induced lowering failure")
+
+    monkeypatch.setattr(R, "decode_rfc5424_pallas", boom)
+    bat = np.zeros((8, 64), np.uint8)
+    lens = np.zeros(8, np.int32)
+    try:
+        PK.set_mode("compiled")
+        with pytest.raises(RuntimeError, match="induced"):
+            PK.decode_tier("rfc5424", bat, lens)
+        assert registry.get("pallas_declines") == 0
     finally:
         PK.set_mode("off")
 

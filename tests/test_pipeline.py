@@ -115,13 +115,31 @@ def _start_tcp_tpu_pipeline(out_path, extra_input=""):
     return p
 
 
+def _await_records(out_path, want, wall_s, cold_s=300):
+    """Wait for ``want`` NUL-framed records in the sink.  The wall
+    deadline starts once the first batch has come out of the handler:
+    before that the wait is bounded only by ``cold_s``, because the
+    first batch carries a cold kernel compile whose length is the
+    host's (and its neighbours') business, not the served path's."""
+    import time
+
+    def count():
+        return out_path.read_bytes().count(b"\0") if out_path.exists() else 0
+
+    deadline = time.time() + cold_s
+    while count() < 1:
+        assert time.time() < deadline, "no batch ever left the handler"
+        time.sleep(0.05)
+    deadline = time.time() + wall_s
+    while count() < want and time.time() < deadline:
+        time.sleep(0.05)
+
+
 def test_tpu_handler_shared_across_connections(tmp_path):
     """Every connection of a *_tpu pipeline shares ONE batch handler so
     batches aggregate across connections; scalar pipelines keep
     per-connection handlers."""
     import socket
-    import threading
-    import time
 
     from flowgger_tpu.pipeline import Pipeline
 
@@ -132,15 +150,12 @@ def test_tpu_handler_shared_across_connections(tmp_path):
              for _ in range(3)]
     for i, c in enumerate(conns):
         c.sendall((line % i + "\n").encode())
-    deadline = time.time() + 10
-    while time.time() < deadline:
-        if out_path.exists() and out_path.read_bytes().count(b"\0") >= 3:
-            break
-        time.sleep(0.05)
+    _await_records(out_path, 3, wall_s=10)
     for c in conns:
         c.close()
     assert len(p._handlers) == 1  # one shared BatchHandler
     data = out_path.read_bytes()
+    assert data.count(b"\0") == 3
     for i in range(3):
         assert (f"via conn {i}".encode()) in data
 
@@ -160,9 +175,6 @@ def test_shared_handler_concurrent_connections_no_loss(tmp_path):
     serialization, pipelined flushes)."""
     import socket
     import threading
-    import time
-
-    from flowgger_tpu.pipeline import Pipeline
 
     out_path = tmp_path / "stress.out"
     p = _start_tcp_tpu_pipeline(
@@ -184,13 +196,40 @@ def test_shared_handler_concurrent_connections_no_loss(tmp_path):
     for th in threads:
         th.join()
     want = n_conns * per_conn
-    deadline = time.time() + 20
-    while time.time() < deadline:
-        if out_path.exists() and out_path.read_bytes().count(b"\0") >= want:
-            break
-        time.sleep(0.05)
+    _await_records(out_path, want, wall_s=20)
     data = out_path.read_bytes()
     assert data.count(b"\0") == want
+    assert len(p._handlers) == 1
     for c in range(n_conns):
         for i in range(0, per_conn, 37):
             assert f"c{c}-m{i}".encode() in data
+
+
+def test_cli_run_caches_where_the_environment_says(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` places the persistent compile cache
+    from outside: a ``python -m flowgger_tpu`` run writes its entries
+    into that directory as it is — no sub-directory of the program's
+    making, nothing in the checkout's own default."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cache = tmp_path / "placed-cache"
+    out = tmp_path / "out.gelf"
+    cfg = tmp_path / "cfg.toml"
+    cfg.write_text(
+        '[input]\ntype = "stdin"\nformat = "rfc5424_tpu"\n'
+        '[output]\ntype = "file"\nformat = "gelf"\n'
+        f'file_path = "{out}"\n')
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(cache), "PYTHONPATH": repo}
+    r = subprocess.run(
+        [sys.executable, "-m", "flowgger_tpu", str(cfg)],
+        input=f"{LINE}\n{LINE}\n{LINE}\n".encode(), env=env, cwd=repo,
+        capture_output=True, timeout=300)
+    assert r.returncode == 0, r.stderr.decode()[-2000:]
+    assert out.read_bytes().count(b"\0") == 3
+    entries = os.listdir(cache)
+    assert entries, "the run cached nothing where it was told to"
+    assert not any(e.startswith("kabi-") for e in entries)

@@ -224,6 +224,7 @@ class Harness:
         env = {k: v for k, v in os.environ.items()
                if k not in ("FLOWGGER_FAULTS", "FLOWGGER_PARTITION_PEER")}
         env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"  # host-plane workers, never the chip
         with open(log, "ab") as logfd:
             proc = subprocess.Popen(
                 [sys.executable, os.path.abspath(__file__), "--worker",
@@ -704,7 +705,9 @@ def durability_main(args) -> int:
     report = {"metric": "durability_chaos", "ok": False, "phases": []}
     env = dict(os.environ)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the drills exercise the host planes: their processes are pinned
+    # to the CPU whatever the caller exports, so none asks for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     t_run = time.monotonic()
 
     def log(msg):
@@ -865,7 +868,7 @@ def control_main(args) -> int:
     host while it can still serve.
     """
     sys.path.insert(0, _REPO)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # a host-plane drill, never the chip
 
     from flowgger_tpu import tenancy
     from flowgger_tpu.config import Config

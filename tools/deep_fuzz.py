@@ -69,7 +69,7 @@ else:
     # full wait before declining — 50ms keeps the aggregate negligible;
     # the background compiles keep warming either way)
     os.environ.setdefault("FLOWGGER_FUSED_COMPILE_TIMEOUT_MS", "50")
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from flowgger_tpu.config import Config
 from flowgger_tpu.block import EncodedBlock
