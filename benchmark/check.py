@@ -1,0 +1,158 @@
+"""What decides ``correct``: the sink against what was sent.
+
+Every line the generator wrote is known from its log (pool line, due
+time), so the plain reference can say what record each must have become,
+or that it must be dropped.  After the window and the drain, in CPU-only
+children (``refchunk.py``): the pool goes through the reference once
+(which lines a collector must keep), every record of the sink is
+fingerprinted, and a sample of the generator's writes, drawn from the
+seed and about ``SAMPLE_LINES`` lines in all, is rebuilt with its due
+times and goes through the reference line by line.  Compared, each with
+the limit 0:
+
+``missing``        well-formed lines written whose due time is on no
+                   record of the sink (a line lost); every line
+``unexpected``     records of the sink whose due time no written line
+                   explains: a duplicate, a junk line kept, an altered
+                   timestamp, a record cut short at the file's end;
+                   every record
+``out_of_order``   places where the sink's order departs from the
+                   order sent; every record
+``bytes_differ``   well-formed lines of the sample for which the sink
+                   holds no record that is byte for byte the reference's
+
+The sample keeps the comparison shorter than the window (the reference
+does some 60,000 lines a second a core, a drain run writes 5 million):
+a fault in one record of every fourth block still meets the sample a
+dozen times in a run.
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import numpy as np
+
+from . import stats, traffic
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+# a sixth of the lines go through the reference, every record of the
+# sink is fingerprinted: about the same work
+CORES = max(3, (os.cpu_count() or 4) - 1)
+SINK_CHILDREN = CORES // 2
+REF_CHILDREN = CORES - SINK_CHILDREN
+SAMPLE_LINES = 1_000_000
+LIMITS = {"missing": 0, "unexpected": 0, "out_of_order": 0,
+          "bytes_differ": 0}
+
+
+def in_children(work, jobs):
+    """Run ``refchunk.py`` once per job, all at once, each in a process
+    that cannot see the chip; returns what each wrote."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    procs = []
+    for k, job in enumerate(jobs):
+        out = os.path.join(work, f"chunk_{k}.npz")
+        procs.append((out, subprocess.Popen(
+            [sys.executable, os.path.join(HERE, "refchunk.py"),
+             *map(str, job), out], stdin=subprocess.DEVNULL, env=env)))
+    failed = [p.args for _out, p in procs if p.wait(timeout=300)]
+    if failed:
+        raise RuntimeError(f"the comparison's children failed: {failed}")
+    return [np.load(out) for out, _p in procs]
+
+
+def even_cuts(weights, parts):
+    """Indices that cut ``weights`` into ``parts`` runs of about equal
+    sum."""
+    total = np.cumsum(weights)
+    marks = np.searchsorted(total, total[-1] * np.arange(1, parts) / parts)
+    return [0, *(int(m) + 1 for m in marks), len(weights)]
+
+
+def written(log):
+    """One entry per line written: (pool line, due time), in the order
+    of the log."""
+    n = log[:, 2]
+    row = np.repeat(np.arange(len(log)), n)
+    j = np.arange(int(n.sum())) - np.repeat(np.cumsum(n) - n, n)
+    return log[row, 1] + j, log[row, 3] + j % traffic.SPREAD_US
+
+
+class Sink:
+    """The sink reader's three columns: each record's ``"timestamp"``
+    (the line's due time), the instant its bytes were seen, the offset
+    of its terminator."""
+
+    def __init__(self, path, cols):
+        self.path = path
+        self.ts, self.seen, self.end = cols["ts"], cols["seen"], cols["end"]
+        self.rest = int(cols["rest"])
+        self.sorted_ts = np.sort(self.ts)
+
+
+def sample_rows(log, seed):
+    """Which writes of the log go through the reference line by line:
+    each with the same chance, from the seed, about ``SAMPLE_LINES``
+    lines in all."""
+    total = int(log[:, 2].sum()) if len(log) else 0
+    if total <= SAMPLE_LINES:
+        return np.ones(len(log), bool)
+    return np.random.default_rng(seed).random(len(log)) < SAMPLE_LINES / total
+
+
+def compare(work, sink, log, window, seed):
+    """Returns the numbers compared, and what the metrics are made of:
+    ``attempted`` well-formed lines written in the whole run,
+    ``sampled`` of them compared byte for byte, and of the well-formed
+    lines due in the window the mean ``line_bytes`` and
+    ``record_bytes``."""
+    off = np.load(os.path.join(work, "pool.npz"))["line_off"]
+    n_pool = len(off) - 1
+    cuts = np.linspace(0, n_pool, REF_CHILDREN + 1).astype(int)
+    jobs = [("pool", work, a, b) for a, b in zip(cuts[:-1], cuts[1:])]
+    n_pool_jobs = len(jobs)
+    picked = log[sample_rows(log, seed)]
+    if len(picked):
+        cuts = even_cuts(picked[:, 2], REF_CHILDREN)
+        for k, (a, b) in enumerate(zip(cuts[:-1], cuts[1:])):
+            rows = os.path.join(work, f"rows_{k}.npy")
+            np.save(rows, picked[a:b])
+            jobs.append(("expect", work, rows, "-"))
+    n_expect = len(jobs)
+    if len(sink.end):
+        cuts = even_cuts(np.ones(len(sink.end)), SINK_CHILDREN)
+        starts = np.concatenate(([0], sink.end + 1))
+        jobs += [("sink", sink.path, starts[a], starts[b])
+                 for a, b in zip(cuts[:-1], cuts[1:])]
+    parts = in_children(work, jobs)
+
+    def cat(key, some, dtype):
+        return (np.concatenate([p[key] for p in some]) if some
+                else np.zeros(0, dtype))
+
+    pool_size = cat("size", parts[:n_pool_jobs], np.int64)
+    want_fp = cat("fp", parts[n_pool_jobs:n_expect], np.uint64)
+    have_fp = cat("fp", parts[n_expect:], np.uint64)
+    line, due = written(log)
+    keep = pool_size[line] > 0
+    exp_line, exp_due = line[keep], due[keep]
+    at, unexpected = stats.match(np.sort(exp_due, kind="stable"),
+                                 sink.sorted_ts)
+    want_fp = want_fp[want_fp > 0]
+    at_fp, _ = stats.match(np.sort(want_fp), np.sort(have_fp))
+    got = {"missing": int((at < 0).sum()),
+           "unexpected": unexpected + (1 if sink.rest else 0),
+           "out_of_order": int((np.diff(sink.ts) <= 0).sum()),
+           "bytes_differ": int((at_fp < 0).sum())}
+    t0, t1 = window
+    cand = exp_line[(exp_due >= t0) & (exp_due < t1)]
+    return got, {"attempted": int(keep.sum()), "sampled": len(want_fp),
+                 "line_bytes": float((off[1:] - off[:-1] - 1)[cand].mean())
+                 if len(cand) else None,
+                 # the pool's record, placeholder timestamp and all: the
+                 # stamped one is a byte or two longer or shorter
+                 "record_bytes": float(pool_size[cand].mean())
+                 if len(cand) else None}

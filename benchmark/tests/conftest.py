@@ -1,0 +1,10 @@
+"""Run with ``python -m pytest benchmark/tests -q`` from the repo's root.
+Not part of tier-1, which collects ``tests/`` only."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

@@ -1,0 +1,89 @@
+"""Whole runs on the CPU (``--rehearse``: the harness's look for a chip
+skipped, everything else as on the chip, at a small size): a sound run
+is correct and leaves nothing behind, and a run with the timed path
+broken underneath (``faults.py``) comes out ``correct: false``, the
+control first.  Slow: some 25 s a run.
+
+The faults a cell of this system can have: an answer altered where it
+is produced (``alter``), part of a batch left out (``half``, ``drop``),
+an answer given twice (``dup``), the order of one stream broken
+(``swap``).  It has no training state and no exchange between chips.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+WORK = os.path.join(ROOT, "benchmark", "work")
+MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
+
+
+def run(cell, *more, seed=2**31 + 77):
+    before = set(os.listdir(WORK)) if os.path.isdir(WORK) else set()
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", cell, "--seed", str(seed), "--seconds", "2",
+         "--rehearse", *more],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    after = set(os.listdir(WORK)) if os.path.isdir(WORK) else set()
+    assert after <= before, "work files left behind"
+    return json.loads(p.stdout.splitlines()[-1]), p
+
+
+CELLS = ["backfill.drain"]
+
+
+@pytest.mark.parametrize("trace", ["0", "1"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_sound_run_is_correct(cell, trace):
+    result, p = run(cell, "--trace", trace)
+    assert list(result)[:5] == ["correct", "attempted", "failed", "metrics",
+                                "device"]
+    assert list(result)[-1] == "compared"
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 10_000
+    assert result["device"]["platform"] == "cpu"     # named, never a TPU
+    assert all(v == 0 and lim == 0 for v, lim in result["compared"].values())
+    last = p.stderr.strip().splitlines()[-1]
+    assert last.startswith("compared (") and "limit 0" in last
+    with open(MANIFEST) as f:
+        bench = json.load(f)
+    kind = "per_layer" if trace == "1" else "end_to_end"
+    mine = {m["name"] for m in bench[kind]
+            if "workloads" not in m or cell in m["workloads"]}
+    got = set(result["metrics"])
+    # no device plane on the CPU: its two readers find nothing to read
+    # and the harness leaves them out
+    assert got <= mine
+    assert mine - got <= {n for n in mine if n.startswith(
+        ("device.idle_share", "kernels.hbm_roofline"))}
+
+
+FAULTS = {"backfill.drain": ["coarse_ts", "alter", "drop", "half", "dup",
+                             "swap"]}
+
+
+@pytest.mark.parametrize("cell, fault", [(c, f) for c in CELLS
+                                         for f in FAULTS[c]])
+def test_a_broken_run_is_not_correct(cell, fault):
+    result, _ = run(cell, "--trace", "0", "--break", fault)
+    assert result["correct"] is False
+    assert any(v > lim for v, lim in result["compared"].values())
+
+
+def test_without_a_tpu_there_is_no_result():
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "backfill.drain", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], capture_output=True, text=True, timeout=300,
+        cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode != 0
+    assert not [l for l in p.stdout.splitlines() if l.startswith("{")]
+    assert "needs a TPU" in p.stderr
