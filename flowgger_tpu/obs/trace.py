@@ -11,6 +11,25 @@ to a sink), where ``tools/trace_dump.py`` and the health server's
 ``GET /trace`` leg render it as Chrome trace-event JSON
 (Perfetto/chrome://tracing loadable).
 
+Inside a stage, the boundaries the stage's wall hides are **sub-spans**
+(:meth:`Tracer.sub`, a context manager around the work itself): the
+ingest thread blocked on a full window (``window_wait`` under
+``submit``), the batch's host-to-device copy (``h2d`` under
+``decode``), the wait for the device program (``device_wait``) and
+each device-to-host copy (``d2h``, one per channel) under ``fetch``,
+and a program's first call (``compile``, no parent: it runs on the
+compile watchdog's worker).  They go to the batch record's ``sub``
+list, each with its ``parent``, so ``spans`` holds the stages and
+nothing else.
+
+One clock with the profiler: while tracing is on, every stage and
+sub-span also holds a ``jax.profiler.TraceAnnotation`` named
+``flowgger.<stage>`` (``batch=<bid>``) open for its interval, so an
+xprof capture (``[metrics] jax_profile_dir``, SIGUSR2, ``POST
+/profile``) shows the host stages on the profiler's own timeline next
+to the device's ops.  With ``trace = "off"`` no annotation is opened
+and a capture shows the device and JAX's own host events only.
+
 Config (``[metrics]``)::
 
     trace = "off"          # "off" | "ring" | "jsonl"
@@ -21,10 +40,11 @@ Config (``[metrics]``)::
 
 Cost model: ``tracer.active`` is a plain attribute — when tracing is
 off every instrumentation site is one attribute read and a
-predicted-false branch (the ``bench.py --smoke`` obs section gates
-this at < 1% of per-chunk e2e cost).  When on, a span append is one
-lock + one list append; the ring is a ``deque(maxlen=...)`` so memory
-is bounded regardless of uptime.
+predicted-false branch (the benchmark's ``--trace 0`` runs, which the
+driver times, are the gate on that cost), and ``jax.profiler`` is not
+imported.  When on, a span append is one lock + one list append, a
+sub-span two clock reads and an annotation besides; the ring is a
+``deque(maxlen=...)`` so memory is bounded regardless of uptime.
 
 The stage timeline is wall-clock-anchored once per process
 (``perf_counter`` ↔ ``time.time`` epoch pair) so Chrome trace ``ts``
@@ -37,6 +57,7 @@ routable host's ring into one document with per-host process lanes.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import threading
@@ -58,6 +79,44 @@ STAGES = ("frame", "pack", "submit", "decode", "fetch", "encode",
           "sequence", "emit")
 
 
+# what :meth:`Tracer.sub` hands out while tracing is off
+_NO_SUB = contextlib.nullcontext()
+
+
+class _Sub:
+    """One sub-span being measured: entered where the work starts,
+    left where it ends, on the thread that does it."""
+
+    __slots__ = ("_tracer", "_ann", "bid", "fields", "t0")
+
+    def __init__(self, tracer, bid, fields):
+        self._tracer, self.bid, self.fields = tracer, bid, fields
+
+    def __enter__(self):
+        self._ann = self._tracer._annotate(
+            self.fields["stage"], self.bid, self.fields.get("note"))
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._record_sub(self.bid, self.fields, self.t0, t1)
+        return False
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported when tracing is first
+    switched on; None where JAX is not installed (the tracer then keeps
+    its own records and the profiler sees nothing of them)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class Tracer:
     """Process-wide batch-span recorder (module singleton ``tracer``)."""
 
@@ -74,6 +133,10 @@ class Tracer:
         self._dropped_open = 0
         self._sink = JsonlSink("trace")
         self._rank: Optional[int] = None
+        # per thread: the batch it works on (``bid``) and the stage
+        # annotation it holds open (``ann``, ``stage``)
+        self._tls = threading.local()
+        self._annotation = None
         # perf_counter -> wall anchor, fixed at construction: chrome ts
         # microseconds are absolute wall time
         self._epoch_wall = time.time()
@@ -96,6 +159,8 @@ class Tracer:
             self._dropped_open = 0
         self._sink.open(path if mode == JSONL else None,
                         max_mb=max_mb, keep=keep)
+        if mode != OFF and self._annotation is None:
+            self._annotation = _annotation_class()
         # flipped last: a site observing active=True sees a configured
         # tracer
         self.active = mode != OFF
@@ -126,11 +191,82 @@ class Tracer:
                 self._open.pop(next(iter(self._open)))
                 self._dropped_open += 1
             rec = {"bid": bid, "route": route, "t0": t0,
-                   "rows": 0, "spans": []}
+                   "rows": 0, "spans": [], "sub": []}
             if self._rank is not None:
                 rec["rank"] = self._rank
             self._open[bid] = rec
+        self.bind(bid)
         return bid
+
+    def bind(self, bid: Optional[int]) -> None:
+        """This thread works on batch ``bid`` from here on (None: on
+        none).  ``begin`` binds the thread that minted the batch; the
+        lane fetcher binds itself when it pops one.  The sites below
+        the handler (the window, the upload, the fetch) have no batch
+        ID in their signatures and ask :meth:`bound`."""
+        self._close_stage()
+        self._tls.bid = bid
+
+    def bound(self) -> Optional[int]:
+        return getattr(self._tls, "bid", None)
+
+    def enter(self, stage: str) -> None:
+        """The bound batch's ``stage`` starts on this thread now: hold
+        a profiler annotation open until :meth:`span` records the stage
+        (or the thread enters its next one).  Called beside the clock
+        read that ``span`` is later given as ``t0``."""
+        if not self.active:
+            return
+        self._close_stage()
+        bid = self.bound()
+        if bid is not None:
+            self._tls.ann = self._annotate(stage, bid)
+            self._tls.stage = stage
+
+    def _annotate(self, stage: str, bid: Optional[int],
+                  note: Optional[str] = None):
+        if self._annotation is None:
+            return None
+        kw = {"note": note} if note else {}
+        ann = self._annotation(f"flowgger.{stage}", batch=bid, **kw)
+        ann.__enter__()
+        return ann
+
+    def _close_stage(self, stage: Optional[str] = None) -> None:
+        tls = self._tls
+        ann = getattr(tls, "ann", None)
+        if ann is not None and stage in (None, tls.stage):
+            tls.ann = None
+            ann.__exit__(None, None, None)
+
+    def sub(self, bid: Optional[int], stage: str, parent: Optional[str],
+            rows: Optional[int] = None, nbytes: Optional[int] = None,
+            note: Optional[str] = None):
+        """Context manager around one sub-span of ``parent``: the work
+        inside is timed, annotated for the profiler, and appended to
+        batch ``bid``'s ``sub`` list.  ``bid`` None (work that belongs
+        to no batch: a compile on its worker thread) keeps the
+        annotation and records nothing."""
+        if not self.active:
+            return _NO_SUB
+        fields = {"stage": stage, "parent": parent}
+        if rows is not None:
+            fields["rows"] = int(rows)
+        if nbytes is not None:
+            fields["bytes"] = int(nbytes)
+        if note:
+            fields["note"] = note
+        return _Sub(self, bid, fields)
+
+    def _record_sub(self, bid, fields, t0: float, t1: float) -> None:
+        if bid is None:
+            return
+        tname = threading.current_thread().name
+        with self._lock:
+            rec = self._open.get(bid)
+            if rec is not None:
+                rec["sub"].append(
+                    dict(fields, t0=t0, t1=t1, thread=tname))
 
     def span(self, bid: Optional[int], stage: str, t0: float, t1: float,
              rows: Optional[int] = None, nbytes: Optional[int] = None,
@@ -140,6 +276,7 @@ class Tracer:
         stage metrics, so tracing never adds clock reads of its own."""
         if bid is None or not self.active:
             return
+        self._close_stage(stage)
         tname = threading.current_thread().name
         with self._lock:
             rec = self._open.get(bid)
@@ -160,6 +297,8 @@ class Tracer:
         the JSONL sink when configured)."""
         if bid is None:
             return
+        if self.bound() == bid:
+            self.bind(None)
         with self._lock:
             rec = self._open.pop(bid, None)
             if rec is None:
@@ -223,20 +362,24 @@ def chrome_events(traces: List[dict], epoch_wall: Optional[float] = None,
 
     for rec in traces:
         bid = rec.get("bid")
-        for sp in rec.get("spans", ()):
-            args = {"batch": bid}
-            for key in ("rows", "bytes", "note"):
-                if key in sp:
-                    args[key] = sp[key]
-            if rec.get("route"):
-                args["route"] = rec["route"]
-            events.append({
-                "name": sp["stage"], "ph": "X", "cat": "batch",
-                "ts": us(sp["t0"]),
-                "dur": round(max(0.0, sp["t1"] - sp["t0"]) * 1e6, 3),
-                "pid": pid, "tid": tid_for(sp.get("thread", "?")),
-                "args": args,
-            })
+        # a stage first, then the sub-spans inside it: same thread,
+        # contained in time, so a viewer nests them under their parent
+        for cat, spans in (("batch", rec.get("spans", ())),
+                           ("sub", rec.get("sub", ()))):
+            for sp in spans:
+                args = {"batch": bid}
+                for key in ("parent", "rows", "bytes", "note"):
+                    if sp.get(key) is not None:
+                        args[key] = sp[key]
+                if rec.get("route"):
+                    args["route"] = rec["route"]
+                events.append({
+                    "name": sp["stage"], "ph": "X", "cat": cat,
+                    "ts": us(sp["t0"]),
+                    "dur": round(max(0.0, sp["t1"] - sp["t0"]) * 1e6, 3),
+                    "pid": pid, "tid": tid_for(sp.get("thread", "?")),
+                    "args": args,
+                })
     return events
 
 

@@ -710,6 +710,7 @@ class BatchHandler(Handler):
 
         bid = _tracer.begin(self.fmt)
         tp0 = _time.perf_counter()
+        _tracer.enter("pack")
         packed = pack.pack_region_2d(
             region, self.max_len, sep=sep[0],
             strip_cr=self.ingest_strip_cr)
@@ -731,6 +732,7 @@ class BatchHandler(Handler):
 
         bid = _tracer.begin(self.fmt)
         tp0 = _time.perf_counter()
+        _tracer.enter("pack")
         packed = pack.pack_spans_2d(span_chunks, span_sets, self.max_len)
         if bid is not None:
             _tracer.span(bid, "pack", tp0, _time.perf_counter(),
@@ -800,6 +802,7 @@ class BatchHandler(Handler):
             lane = self._window.next_lane()
             bid = _tracer.begin(self.fmt)
             t0 = _time.perf_counter()
+            _tracer.enter("frame")
             try:
                 _faults.maybe_raise("device_decode")
                 packed, _consumed, _err = _framing.device_frame_region(
@@ -827,6 +830,7 @@ class BatchHandler(Handler):
 
         bid = _tracer.begin(self.fmt)
         t0 = _time.perf_counter()
+        _tracer.enter("pack")
         packed = pack.pack_region_2d(framed, self.max_len, sep=sep[0],
                                      strip_cr=sess.framing == "line")
         t1 = _time.perf_counter()
@@ -847,6 +851,7 @@ class BatchHandler(Handler):
             lane = self._window.next_lane()
             bid = _tracer.begin(self.fmt)
             t0 = _time.perf_counter()
+            _tracer.enter("frame")
             try:
                 _faults.maybe_raise("device_decode")
                 packed, consumed, err = _framing.device_frame_region(
@@ -906,6 +911,7 @@ class BatchHandler(Handler):
             from . import pack
 
             bid = _tracer.begin(self.fmt)
+            _tracer.enter("pack")
             packed = pack.pack_spans_2d([region[:consumed]],
                                         [(starts, lens)], self.max_len)
             t1 = _time.perf_counter()
@@ -944,6 +950,7 @@ class BatchHandler(Handler):
         synchronously.  ``trace`` is the flight-recorder batch ID
         (None when tracing is off).  ``ack`` is a durability replay
         acknowledgment (see _guarded_dispatch)."""
+        _count_rows(packed)
         if self._fast_encode:
             self._emit_fast(packed, deferred, runs, lane, trace, ack)
             return
@@ -980,11 +987,13 @@ class BatchHandler(Handler):
 
                 bid = _tracer.begin(self.fmt)
                 tp0 = _time.perf_counter()
+                _tracer.enter("pack")
                 packed = pack.pack_lines_2d(lines, self.max_len)
                 if bid is not None:
                     _tracer.span(bid, "pack", tp0, _time.perf_counter(),
                                  rows=int(packed[5]))
                 deferred = [False]
+                _count_rows(packed)
                 self._emit_fast(packed, deferred, runs, trace=bid)
                 if not deferred[0]:
                     # emitted synchronously: close the trace here (a
@@ -1332,11 +1341,16 @@ class BatchHandler(Handler):
             if len(self._lane_devices) > 1:
                 _metrics.inc(f"lane{lane}_rows", int(packed[5]))
             ctx = (trace, self._flush_t0, ack)
+            if _tracer.active:
+                # a replayed batch carries no trace: the thread must
+                # not stay bound to the one it minted last
+                _tracer.bind(trace)
             if self.fmt == "auto":
                 # the auto merger submits its per-class kernels at fetch
                 # time, on the lane's fetcher thread (default device:
                 # the per-class legs share one jit cache)
                 ts0 = _time.perf_counter()
+                _tracer.enter("submit")
                 self._window.submit(lane, (None, packed, runs, ctx))
                 if trace is not None:
                     _tracer.span(trace, "submit", ts0,
@@ -1358,6 +1372,7 @@ class BatchHandler(Handler):
                     # thread, where a compile-watchdog wait can never
                     # stall ingest
                     td0 = _time.perf_counter()
+                    _tracer.enter("decode")
                     handle = fused_routes.submit(
                         route, packed, self._lane_devices[lane])
                     ts0 = _time.perf_counter()
@@ -1365,6 +1380,7 @@ class BatchHandler(Handler):
                         _tracer.span(trace, "decode", td0, ts0,
                                      rows=int(packed[5]),
                                      note=f"fused:{route.name} commit")
+                        _tracer.enter("submit")
                     self._window.submit(lane, (handle, packed, runs,
                                                ctx))
                     if trace is not None:
@@ -1372,6 +1388,7 @@ class BatchHandler(Handler):
                                      _time.perf_counter())
                     return
             td0 = _time.perf_counter()
+            _tracer.enter("decode")
             handle = block_submit(
                 self.fmt, packed, self._sharded_for(self.fmt),
                 self._lane_devices[lane])
@@ -1379,6 +1396,7 @@ class BatchHandler(Handler):
             if trace is not None:
                 _tracer.span(trace, "decode", td0, ts0,
                              rows=int(packed[5]), note="split dispatch")
+                _tracer.enter("submit")
             self._window.submit(lane, (handle, packed, runs, ctx))
             if trace is not None:
                 _tracer.span(trace, "submit", ts0, _time.perf_counter())
@@ -1424,6 +1442,8 @@ class BatchHandler(Handler):
         import time as _time
 
         t0 = _time.perf_counter()
+        if _tracer.active:
+            _tracer.bind(bid)
         stats: dict = {}
         econ = self._econs[lane % len(self._econs)]
         try:
@@ -1447,7 +1467,12 @@ class BatchHandler(Handler):
         # ahead of emission is cross-lane scheduling, not route cost
         compute_s = _time.perf_counter() - t0 - stats.get("declined_s", 0.0)
         path = stats.get("path")
+        if path is not None:
+            # the fetcher's busy seconds by route: the host path sets
+            # the pace, the other two are the economics' probes
+            _metrics.add_seconds(f"route_pop_seconds_{path}", compute_s)
         t_done = _time.perf_counter()
+        _tracer.enter("sequence")
 
         def finish():
             t_emit0 = _time.perf_counter()
@@ -1455,6 +1480,7 @@ class BatchHandler(Handler):
                 # the gap between compute finishing and the turnstile
                 # opening is cross-lane scheduling: its own span
                 _tracer.span(bid, "sequence", t_done, t_emit0)
+                _tracer.enter("emit")
             try:
                 emit()
             except Exception as e:  # noqa: BLE001 - device degradation boundary
@@ -1519,6 +1545,8 @@ class BatchHandler(Handler):
         if econ is None:
             econ = self._econs[0]
         t0 = _time.perf_counter()
+        # the merged auto legs interleave fetch and encode: one stage
+        _tracer.enter("encode" if self.fmt == "auto" else "fetch")
         if self.fmt == "auto":
             from .autodetect import decode_auto_packed, encode_auto_gelf_blocks
 
@@ -1861,6 +1889,14 @@ class _RawSession:
             h.handle_bytes(carry)
 
 
+def _count_rows(packed) -> None:
+    """One dispatched batch's real rows against the bucket it was
+    padded to (tpu/pack.py bucket_rows): what crosses the link is the
+    bucket."""
+    _metrics.inc("batch_rows_real", int(packed[5]))
+    _metrics.inc("batch_rows_padded", int(packed[0].shape[0]))
+
+
 def block_submit(fmt, packed, sharded=None, device=None):
     """Dispatch one packed tuple's kernel asynchronously (JAX futures);
     pair with block_fetch_encode.  ``sharded`` (parallel.mesh.
@@ -1870,12 +1906,11 @@ def block_submit(fmt, packed, sharded=None, device=None):
     reuses the handle's device arrays — runs on the lane's chip."""
     batch, lens = packed[0], packed[1]
     if device is not None and sharded is None:
-        import jax
+        from .device_common import h2d
 
         # committed placement: the jit executes on the lane device and
         # jnp.asarray inside the submit fns is a no-op on these
-        batch = jax.device_put(batch, device)
-        lens = jax.device_put(lens, device)
+        batch, lens = h2d(batch, lens, device)
     if fmt == "rfc3164":
         from . import rfc3164
 
@@ -1968,6 +2003,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
                 t0 = _time.perf_counter()
         host_out = rfc3164.decode_rfc3164_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         _tap_columns(column_tap, host_out)
         from ..encoders.capnp import CapnpEncoder
         from ..encoders.ltsv import LTSVEncoder
@@ -2007,6 +2043,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
             t0 = _time.perf_counter()
         host_out = ltsv.decode_ltsv_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         _tap_columns(column_tap, host_out)
         from ..encoders.capnp import CapnpEncoder
         from ..encoders.ltsv import LTSVEncoder
@@ -2042,6 +2079,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
         # block path is the fast tier, so the fetch is unconditional
         host_out = jsonl.decode_jsonl_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         _tap_columns(column_tap, host_out)
         if type(encoder) is LTSVEncoder:
             res = encode_jsonl_block.encode_jsonl_ltsv_block(
@@ -2057,6 +2095,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
 
         host_out = dns.decode_dns_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         _tap_columns(column_tap, host_out)
         if type(encoder) is LTSVEncoder:
             res = encode_dns_block.encode_dns_ltsv_block(
@@ -2084,6 +2123,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
             t0 = _time.perf_counter()
         host_out = gelf.decode_gelf_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         from ..encoders.capnp import CapnpEncoder
 
         if type(encoder) is LTSVEncoder:
@@ -2133,6 +2173,7 @@ def block_fetch_encode(fmt, handle, packed, encoder, merger,
             t0 = _time.perf_counter()
         host_out = rfc5424.decode_rfc5424_fetch(handle)
         t1 = _time.perf_counter()
+        _tracer.enter("encode")
         _tap_columns(column_tap, host_out)
         res = _encode_block_from_host(host_out, packed, encoder, merger)
     if stats is not None and res is not None:

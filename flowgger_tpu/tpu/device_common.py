@@ -28,11 +28,57 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import tracer as _tracer
+from ..utils.metrics import registry as _metrics
 from .assemble import exclusive_cumsum
 from .materialize import compute_ts
 
 _I32 = jnp.int32
 _U8 = jnp.uint8
+
+
+# -- the link ----------------------------------------------------------------
+# Every batch crosses the host-device link twice, and both crossings go
+# through here so that they are counted where the bytes move (``h2d_bytes``
+# with the lines' own share of it, ``packed_line_bytes``; ``d2h_bytes`` and
+# ``d2h_calls``) and, while tracing is on, bounded as sub-spans of the
+# stage they lie in (obs/trace.py).
+
+def _put(batch, lens, device):
+    if device is not None:
+        return jax.device_put(batch, device), jax.device_put(lens, device)
+    return jnp.asarray(batch), jnp.asarray(lens)
+
+
+def h2d(batch, lens, device=None, parent="decode"):
+    """Upload one packed batch and its row lengths: committed to
+    ``device`` (lane dispatch), else onto the default device, uncommitted.
+    Arrays that are on a device already (device framing, a lane's
+    ``block_submit`` ahead of the format's own submit) pass through the
+    same calls uncounted: nothing crosses the link for them.  ``parent``
+    is the stage the caller is in (the rfc5424 rescue uploads its rows
+    from inside ``fetch``)."""
+    if not isinstance(batch, np.ndarray):
+        return _put(batch, lens, device)
+    nbytes = batch.nbytes + lens.nbytes
+    with _tracer.sub(_tracer.bound(), "h2d", parent, nbytes=nbytes):
+        on_device = _put(batch, lens, device)
+    _metrics.inc("h2d_bytes", nbytes)
+    # padding rows have length 0, so this is the real rows' bytes
+    _metrics.inc("packed_line_bytes", int(lens.sum()))
+    return on_device
+
+
+def d2h(arr, name=None):
+    """One blocking device-to-host copy (the first one after a dispatch
+    also waits for the program); ``name`` is the channel's."""
+    with _tracer.sub(_tracer.bound(), "d2h", "fetch",
+                     nbytes=getattr(arr, "nbytes", None), note=name):
+        host = np.asarray(arr)
+    _metrics.inc("d2h_calls")
+    _metrics.inc("d2h_bytes", host.nbytes)
+    return host
+
 
 # -- compile watchdog --------------------------------------------------------
 # The device-encode kernels are large; on some hosts/backends their XLA
@@ -193,7 +239,10 @@ def guarded_compile_call(name: str, fn, *args, timeout_s=None):
                 with _compile_lock:
                     active["name"] = name
                 try:
-                    box["result"] = fn(*args)
+                    # trace + compile or cache load + first run; on
+                    # this worker, so it belongs to no batch's thread
+                    with _tracer.sub(None, "compile", None, note=name):
+                        box["result"] = fn(*args)
                 finally:
                     with _compile_lock:
                         active.pop("name", None)
@@ -1012,7 +1061,7 @@ def fetch_encode_driver(kernel, out, batch_dev, lens_dev, packed, encoder,
     def _fetch(arr):
         nonlocal t_fetch
         t0 = _time.perf_counter()
-        h = np.asarray(arr)
+        h = d2h(arr)
         t_fetch += _time.perf_counter() - t0
         fetched[0] += h.nbytes
         return h

@@ -650,12 +650,9 @@ def submit(route: FusedRoute, packed, device=None) -> FusedHandle:
     runs here: the fused program dispatches on the lane fetcher thread
     (fetch_encode), where a compile-watchdog wait can never stall
     ingest."""
-    batch, lens = packed[0], packed[1]
-    if device is not None:
-        batch_dev = jax.device_put(batch, device)
-        lens_dev = jax.device_put(lens, device)
-    else:
-        batch_dev, lens_dev = jnp.asarray(batch), jnp.asarray(lens)
+    from .device_common import h2d
+
+    batch_dev, lens_dev = h2d(packed[0], packed[1], device)
     return FusedHandle(route, batch_dev, lens_dev, device)
 
 

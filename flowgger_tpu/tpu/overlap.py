@@ -72,6 +72,7 @@ import time
 from collections import deque
 from typing import Callable, Optional
 
+from ..obs.trace import tracer as _tracer
 from ..utils.metrics import registry as _metrics
 
 DEFAULT_INFLIGHT = 2
@@ -128,9 +129,14 @@ class InflightWindow:
         t0 = time.perf_counter()
         with self._lock:
             self._raise_pending_locked()
-            while len(self._queue) + (1 if self._popping else 0) >= self.depth:
-                self._nonfull.wait(timeout=0.5)
-                self._raise_pending_locked()
+            if self._full_locked():
+                # the ingest thread blocked on the fetcher: a sub-span
+                # of the caller's ``submit`` stage, the same seconds
+                # ``overlap_stall_seconds`` adds below
+                with _tracer.sub(_tracer.bound(), "window_wait", "submit"):
+                    while self._full_locked():
+                        self._nonfull.wait(timeout=0.5)
+                        self._raise_pending_locked()
             self._queue.append(entry)
             _metrics.set_gauge(self._gauge,
                                len(self._queue) + (1 if self._popping else 0))
@@ -138,6 +144,9 @@ class InflightWindow:
         stalled = time.perf_counter() - t0
         if stalled > 1e-4:
             _metrics.add_seconds("overlap_stall_seconds", stalled)
+
+    def _full_locked(self) -> bool:
+        return len(self._queue) + (1 if self._popping else 0) >= self.depth
 
     def fence(self) -> None:
         """Block until every submitted batch has been fetched and

@@ -932,8 +932,9 @@ def decode_rfc5424_submit(batch, lens, max_sd: int = DEFAULT_MAX_SD,
         out = sharded.fn(batch_dev, lens_dev)
     else:
         from .aot import decode_call
+        from .device_common import h2d
 
-        batch_dev, lens_dev = jnp.asarray(batch), jnp.asarray(lens)
+        batch_dev, lens_dev = h2d(batch, lens)
         # zero-JIT boot: a loaded AOT artifact replaces the trace+compile
         # (same channels, byte-identical by construction); None → jit
         out = decode_call("rfc5424", (batch_dev, lens_dev),
@@ -990,6 +991,20 @@ def rescue_refetch(host, batch, lens, rows_idx, field_keys, dispatch,
     return merged
 
 
+def _fetch_channels(out):
+    """One blocking copy per output channel of a decode program, the
+    first of them also the wait for the program."""
+    from ..obs.trace import tracer as _tracer
+    from .device_common import d2h
+
+    if _tracer.active:
+        # tracing only: tell the wait for the program from the first
+        # copy, which would otherwise hold both
+        with _tracer.sub(_tracer.bound(), "device_wait", "fetch"):
+            jax.block_until_ready(out)
+    return {k: d2h(v, k) for k, v in out.items()}
+
+
 def decode_rfc5424_fetch(handle):
     """Block on a submitted decode and return host numpy channels,
     re-dispatching pair-overflow rows (DEFAULT_MAX_PAIRS < pairs <=
@@ -998,17 +1013,20 @@ def decode_rfc5424_fetch(handle):
     come back widened to RESCUE_MAX_PAIRS when any row needed tier 2."""
     import numpy as np
 
+    from .device_common import h2d
+
     out, batch, lens, max_sd, impl = handle[:5]
-    host = {k: np.asarray(v) for k, v in out.items()}
+    host = _fetch_channels(out)
     pc = host["pair_count"]
     over = np.flatnonzero((pc > DEFAULT_MAX_PAIRS) & (pc <= RESCUE_MAX_PAIRS))
 
     def dispatch(sub_b, sub_l):
-        out2 = decode_rfc5424_jit(jnp.asarray(sub_b), jnp.asarray(sub_l),
+        # a second round over the link, inside the fetch stage
+        out2 = decode_rfc5424_jit(*h2d(sub_b, sub_l, parent="fetch"),
                                   max_sd=max_sd,
                                   max_pairs=RESCUE_MAX_PAIRS,
                                   extract_impl=impl)
-        return {k: np.asarray(v) for k, v in out2.items()}
+        return _fetch_channels(out2)
 
     return rescue_refetch(host, batch, lens, over, _PAIR_KEYS, dispatch,
                           RESCUE_MAX_PAIRS)

@@ -178,6 +178,13 @@ _COUNTERS = (
     # routing failures (no routable host, dial error)
     "control_applies", "control_freezes", "control_ticks",
     "proxy_connections", "proxy_bytes", "proxy_route_errors",
+    # the host-device link, counted where the bytes move
+    # (tpu/device_common.py h2d/d2h): uploaded batch bytes and the
+    # lines' own share of them, copies back and their bytes; and each
+    # dispatched batch's real rows against its padded bucket
+    # (tpu/batch.py)
+    "h2d_bytes", "packed_line_bytes", "d2h_bytes", "d2h_calls",
+    "batch_rows_real", "batch_rows_padded",
 )
 
 # cumulative per-stage wall-clock accumulators (add_seconds)
@@ -186,6 +193,11 @@ _SECONDS_NAMES = (
     "device_fetch_seconds", "encode_seconds",
     "device_encode_declined_seconds",
     "pack_stage_seconds", "pack_slice_seconds", "pack_copy_seconds",
+    # the lane fetcher's compute wall per batch, by the route that
+    # served it (tpu/batch.py _pop_emit): host sets the pace, fused
+    # and device are the economics' probes
+    "route_pop_seconds_host", "route_pop_seconds_fused",
+    "route_pop_seconds_device",
 )
 
 # point-in-time gauges with literal names (set_gauge/init_gauge)

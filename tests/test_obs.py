@@ -630,6 +630,289 @@ def test_jsonl_mode_and_trace_dump_cli(tmp_path):
             assert key in e
 
 
+# -- sub-spans, profiler annotations, link counters --------------------------
+
+class _FakeAnnotation:
+    """Stands where ``jax.profiler.TraceAnnotation`` would: records
+    every open and close with the thread it happened on."""
+
+    log = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        self.log.append(("open", self.name, self.kw.get("batch"),
+                         threading.current_thread().name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name, self.kw.get("batch"),
+                         threading.current_thread().name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(obs_trace, "_annotation_class",
+                        lambda: _FakeAnnotation)
+    monkeypatch.setattr(obs_trace.tracer, "_annotation", None)
+    return _FakeAnnotation.log
+
+
+@pytest.fixture
+def host_route(monkeypatch):
+    """The route ``backfill.drain`` serves 99.6% of its rows on: split
+    decode on the device, block encode on the host."""
+    monkeypatch.setenv("FLOWGGER_DEVICE_ENCODE", "0")
+    return Config.from_string('[input]\ntpu_fuse = "off"\n')
+
+
+def _run_batch(cfg, lines):
+    from flowgger_tpu.decoders import RFC5424Decoder
+    from flowgger_tpu.encoders import GelfEncoder
+    from flowgger_tpu.mergers import NulMerger
+    from flowgger_tpu.tpu.batch import BatchHandler
+
+    tx = queue.Queue()
+    h = BatchHandler(tx, RFC5424Decoder(), GelfEncoder(cfg), cfg,
+                     start_timer=False, merger=NulMerger(cfg))
+    h.ingest_sep = b"\n"
+    h.ingest_strip_cr = True
+    h.ingest_chunk(b"".join(lines))
+    h.flush()
+    h.close()
+    out = []
+    while not tx.empty():
+        item = tx.get()
+        out.append(bytes(getattr(item, "data", item)))
+    return out
+
+
+_LINES = [b"<13>1 2015-08-05T15:53:45Z h a p m - hello %d\n" % i
+          for i in range(5)]
+
+
+def test_tracing_off_opens_no_sub_span_and_no_annotation(annotations,
+                                                         host_route):
+    assert obs_trace.tracer.sub(1, "d2h", "fetch") is obs_trace._NO_SUB
+    _run_batch(host_route, _LINES)
+    assert annotations == []
+    assert obs_trace.tracer._annotation is None   # jax.profiler untouched
+    assert obs_trace.tracer.snapshot() == []
+
+
+def test_sub_spans_carry_parent_thread_and_batch(annotations, host_route):
+    obs_trace.tracer.configure("ring")
+    _run_batch(host_route, _LINES)
+    rec = obs_trace.tracer.snapshot()[-1]
+    by_stage = {}
+    for sp in rec["sub"]:
+        assert sp["t1"] >= sp["t0"]
+        by_stage.setdefault(sp["stage"], []).append(sp)
+    assert set(by_stage) == {"h2d", "device_wait", "d2h"}
+    ingest = {sp["thread"] for sp in rec["spans"] if sp["stage"] == "decode"}
+    fetcher = {sp["thread"] for sp in rec["spans"] if sp["stage"] == "fetch"}
+    assert ingest != fetcher
+    assert {sp["parent"] for sp in by_stage["h2d"]} == {"decode"}
+    assert {sp["thread"] for sp in by_stage["h2d"]} == ingest
+    for stage in ("device_wait", "d2h"):
+        assert {sp["parent"] for sp in by_stage[stage]} == {"fetch"}
+        assert {sp["thread"] for sp in by_stage[stage]} == fetcher
+    # every stage and sub-span held an annotation open for this batch,
+    # on the thread that did the work, and every one was closed
+    opened = [e[1:] for e in annotations if e[0] == "open"]
+    closed = [e[1:] for e in annotations if e[0] == "close"]
+    assert sorted(opened) == sorted(closed)
+    assert {b for _n, b, _t in opened} == {rec["bid"]}
+    names = {n for n, _b, _t in opened}
+    assert names == {"flowgger." + s for s in
+                     [sp["stage"] for sp in rec["spans"] + rec["sub"]]}
+    assert {t for n, _b, t in opened if n == "flowgger.d2h"} == fetcher
+    assert {t for n, _b, t in opened if n == "flowgger.h2d"} == ingest
+
+
+def test_stage_spans_are_the_same_with_and_without_sub_spans(
+        monkeypatch, host_route):
+    obs_trace.tracer.configure("ring")
+    _run_batch(host_route, _LINES)
+    with_sub = obs_trace.tracer.snapshot()[-1]
+    monkeypatch.setattr(obs_trace.tracer, "sub",
+                        lambda *a, **kw: obs_trace._NO_SUB)
+    _run_batch(host_route, _LINES)
+    without = obs_trace.tracer.snapshot()[-1]
+    assert with_sub["sub"] and not without["sub"]
+    stages = [sp["stage"] for sp in with_sub["spans"]]
+    assert stages == [sp["stage"] for sp in without["spans"]]
+    assert stages == ["pack", "decode", "submit", "fetch", "encode",
+                      "sequence", "emit"]
+    assert set(stages) <= set(obs_trace.STAGES)
+
+
+def test_chrome_events_nest_sub_spans_under_their_parent(host_route):
+    obs_trace.tracer.configure("ring")
+    _run_batch(host_route, _LINES)
+    events = [e for e in obs_trace.tracer.chrome_events()
+              if e.get("ph") == "X"]
+    subs = [e for e in events if e["cat"] == "sub"]
+    assert {e["name"] for e in subs} == {"h2d", "device_wait", "d2h"}
+    for e in subs:
+        parents = [p for p in events if p["cat"] == "batch"
+                   and p["name"] == e["args"]["parent"]
+                   and p["tid"] == e["tid"]
+                   and p["args"]["batch"] == e["args"]["batch"]]
+        assert len(parents) == 1
+        p = parents[0]
+        # epoch microseconds in a float resolve a quarter of one; and
+        # the handler lays ``fetch`` from the pop's start with the
+        # length block_fetch_encode measured from its own, some tens
+        # of microseconds later
+        assert p["ts"] <= e["ts"] + 2
+        assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1000
+        # the stage comes first in the document, its children after
+        assert events.index(p) < events.index(e)
+    d2h = [e for e in subs if e["name"] == "d2h"]
+    assert all(e["args"]["note"] and e["args"]["bytes"] > 0 for e in d2h)
+
+
+def test_link_counters_on_the_served_route(host_route):
+    import numpy as np
+
+    from flowgger_tpu.tpu import pack, rfc5424
+
+    n, max_len = len(_LINES), 512
+    rows = pack.bucket_rows(n)
+    _run_batch(host_route, _LINES)
+    snap = registry.snapshot()
+    assert snap["batch_rows_real"] == n
+    assert snap["batch_rows_padded"] == rows
+    # a [rows, 512] u8 batch and its i32 lengths went up; the lines are
+    # what is not padding
+    line_bytes = sum(len(ln) - 1 for ln in _LINES)
+    assert snap["h2d_bytes"] == rows * max_len + rows * 4
+    assert snap["packed_line_bytes"] == line_bytes
+    assert snap["h2d_bytes"] - snap["packed_line_bytes"] == \
+        rows * (max_len + 4) - line_bytes
+    # one copy per output channel of the decode program came down
+    batch, lens = pack.pack_lines_2d([ln[:-1] for ln in _LINES],
+                                     max_len)[:2]
+    out = rfc5424.decode_rfc5424_submit(batch, lens)[0]
+    assert snap["d2h_calls"] == len(out) == 31
+    assert snap["d2h_bytes"] == sum(np.asarray(v).nbytes
+                                    for v in out.values())
+    # the fetcher's seconds went to the host route and to no other
+    assert snap["route_pop_seconds_host"] > 0
+    assert "route_pop_seconds_fused" not in snap
+    assert "route_pop_seconds_device" not in snap
+    # the device encoder's own count keeps its meaning: it never ran
+    assert snap["device_encode_fetch_bytes"] == 0
+
+
+def test_the_pair_rescue_is_a_second_round_over_the_link(host_route):
+    """A row with 7..16 SD pairs sends the batch's overflow rows
+    through the wider kernel: one more upload, one more wait and 31
+    more copies, all inside the fetch stage."""
+    seven = (b'<13>1 2015-08-05T15:53:45Z h a p m [a@1 k1="1" k2="2" '
+             b'k3="3" k4="4"][b@1 k5="5" k6="6" k7="7"] rescued\n')
+    obs_trace.tracer.configure("ring")
+    _run_batch(host_route, _LINES + [seven])
+    snap = registry.snapshot()
+    assert snap["d2h_calls"] == 62
+    rows = 256                     # both the batch's bucket and the rescue's
+    assert snap["h2d_bytes"] == 2 * rows * (512 + 4)
+    rec = obs_trace.tracer.snapshot()[-1]
+    count = {}
+    for sp in rec["sub"]:
+        key = (sp["stage"], sp["parent"])
+        count[key] = count.get(key, 0) + 1
+    assert count == {("h2d", "decode"): 1, ("h2d", "fetch"): 1,
+                     ("device_wait", "fetch"): 2, ("d2h", "fetch"): 62}
+    fetch = next(sp for sp in rec["spans"] if sp["stage"] == "fetch")
+    assert all(fetch["t0"] <= sp["t0"] and sp["t1"] <= fetch["t1"] + 1e-3
+               for sp in rec["sub"] if sp["parent"] == "fetch")
+
+
+def test_h2d_counts_host_arrays_only():
+    import numpy as np
+
+    from flowgger_tpu.tpu.device_common import h2d
+
+    batch = np.zeros((256, 64), dtype=np.uint8)
+    lens = np.zeros(256, dtype=np.int32)
+    lens[:3] = (5, 7, 0)
+    on_device = h2d(batch, lens)
+    assert registry.get("h2d_bytes") == 256 * 64 + 256 * 4
+    assert registry.get("packed_line_bytes") == 12
+    # a lane's block_submit has uploaded already: the format's own
+    # submit passes the device arrays through, and counts nothing
+    again = h2d(*on_device)
+    assert again[0] is on_device[0] and again[1] is on_device[1]
+    assert registry.get("h2d_bytes") == 256 * 64 + 256 * 4
+
+
+def test_window_wait_is_the_stall_the_counter_adds():
+    from flowgger_tpu.tpu.overlap import InflightWindow
+
+    obs_trace.tracer.configure("ring")
+    release = threading.Event()
+
+    def pop(entry):
+        release.wait(10)
+        time.sleep(0.03)
+
+    win = InflightWindow(1, pop, name="t")
+    bid = obs_trace.tracer.begin("t")
+    try:
+        win.submit(0)               # the fetcher takes it and holds it
+        threading.Timer(0.05, release.set).start()
+        for i in (1, 2, 3):         # each finds the window full
+            win.submit(i)
+    finally:
+        release.set()
+        win.close()
+    rec = next(r for r in obs_trace.tracer._open.values()
+               if r["bid"] == bid)
+    waits = [sp for sp in rec["sub"] if sp["stage"] == "window_wait"]
+    assert len(waits) == 3
+    assert {sp["parent"] for sp in waits} == {"submit"}
+    stalled = registry.snapshot()["overlap_stall_seconds"]
+    assert stalled > 0.08
+    assert abs(sum(sp["t1"] - sp["t0"] for sp in waits) - stalled) < 1e-3
+    obs_trace.tracer.end(bid)
+
+
+def test_sink_bytes_are_the_same_traced_and_untraced(host_route):
+    lines = _LINES + [b"not a syslog line\n",
+                      b"<13>1 2015-08-05T15:53:45Z h a p m "
+                      b'[x@1 k="v"] tail\n']
+    plain = _run_batch(host_route, lines)
+    obs_trace.tracer.configure("ring")
+    traced = _run_batch(host_route, lines)
+    assert plain and traced == plain
+
+
+def test_compile_sub_span_is_an_annotation_and_no_record(annotations,
+                                                         monkeypatch):
+    from flowgger_tpu.tpu import device_common
+
+    # an earlier test's compile may still hold the single-flight slot
+    monkeypatch.setattr(device_common, "_compile_sema",
+                        threading.Semaphore(1))
+    monkeypatch.setattr(device_common, "_compile_active_box", {})
+    obs_trace.tracer.configure("ring")
+    name = "test-obs:compile-span"
+    assert device_common.guarded_compile_call(
+        name, lambda x: x + 1, 1, timeout_s=10) == 2
+    # a warm name calls inline: no worker, no span
+    assert device_common.guarded_compile_call(
+        name, lambda x: x + 1, 2, timeout_s=10) == 3
+    mine = [e for e in annotations if e[3] == f"xla-compile:{name}"]
+    assert [e[:3] for e in mine] == [("open", "flowgger.compile", None),
+                                     ("close", "flowgger.compile", None)]
+    assert obs_trace.tracer.stats()["open"] == 0
+
+
 def test_trace_dump_cli_bad_source(tmp_path):
     bad = tmp_path / "bad.jsonl"
     bad.write_text("{not json\n")
