@@ -16,7 +16,8 @@ Inside a stage, the boundaries the stage's wall hides are **sub-spans**
 ingest thread blocked on a full window (``window_wait`` under
 ``submit``), the batch's host-to-device copy (``h2d`` under
 ``decode``), the wait for the device program (``device_wait``) and
-each device-to-host copy (``d2h``, one per channel) under ``fetch``,
+each wait on the link for its outputs (``d2h``: one per program, whose
+copies were begun at dispatch) under ``fetch``,
 and a program's first call (``compile``, no parent: it runs on the
 compile watchdog's worker).  They go to the batch record's ``sub``
 list, each with its ``parent``, so ``spans`` holds the stages and

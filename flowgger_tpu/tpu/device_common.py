@@ -40,9 +40,14 @@ _U8 = jnp.uint8
 # -- the link ----------------------------------------------------------------
 # Every batch crosses the host-device link twice, and both crossings go
 # through here so that they are counted where the bytes move (``h2d_bytes``
-# with the lines' own share of it, ``packed_line_bytes``; ``d2h_bytes`` and
-# ``d2h_calls``) and, while tracing is on, bounded as sub-spans of the
-# stage they lie in (obs/trace.py).
+# with the lines' own share of it, ``packed_line_bytes``; ``d2h_bytes``,
+# and ``d2h_calls``: the times a thread blocked on the link for them) and,
+# while tracing is on, bounded as sub-spans of the stage they lie in
+# (obs/trace.py).  A copy back costs by the call and hardly by the byte
+# (PERF.md section 5), so a program whose outputs are all wanted has their
+# copies begun together at its dispatch (``d2h_begin``, counted in
+# ``d2h_prefetched``) and collected with one wait (``d2h_all``); ``d2h``
+# stays for a copy that depends on the one before it.
 
 def _put(batch, lens, device):
     if device is not None:
@@ -69,14 +74,41 @@ def h2d(batch, lens, device=None, parent="decode"):
     return on_device
 
 
-def d2h(arr, name=None):
+def d2h(arr):
     """One blocking device-to-host copy (the first one after a dispatch
-    also waits for the program); ``name`` is the channel's."""
+    also waits for the program)."""
     with _tracer.sub(_tracer.bound(), "d2h", "fetch",
-                     nbytes=getattr(arr, "nbytes", None), note=name):
+                     nbytes=getattr(arr, "nbytes", None)):
         host = np.asarray(arr)
     _metrics.inc("d2h_calls")
     _metrics.inc("d2h_bytes", host.nbytes)
+    return host
+
+
+def d2h_begin(out):
+    """Begin the device-to-host copy of every output array of a program
+    that has just been dispatched.  Nothing blocks: each copy is queued
+    behind the program and lands in a host buffer that the array keeps,
+    so the ``d2h_all`` that comes for them later does not go to the
+    device again."""
+    leaves = jax.tree_util.tree_leaves(out)
+    for leaf in leaves:
+        leaf.copy_to_host_async()
+    _metrics.inc("d2h_prefetched", len(leaves))
+    return out
+
+
+def d2h_all(out):
+    """The host arrays of a program's output dict, in its order: one
+    wait on the link for all of them where ``d2h_begin`` began their
+    copies at dispatch (and one blocking copy after another where it
+    did not)."""
+    nbytes = sum(v.nbytes for v in out.values())
+    with _tracer.sub(_tracer.bound(), "d2h", "fetch", nbytes=nbytes,
+                     note=str(len(out))):
+        host = {k: np.asarray(v) for k, v in out.items()}
+    _metrics.inc("d2h_calls")
+    _metrics.inc("d2h_bytes", nbytes)
     return host
 
 

@@ -921,6 +921,8 @@ def decode_rfc5424_submit(batch, lens, max_sd: int = DEFAULT_MAX_SD,
     batch pipeline overlap device decode of batch N with host encoding
     of batch N-1 (double buffering).  ``sharded`` (a
     parallel.mesh.ShardedDecode) swaps in the multi-chip mesh kernel."""
+    from .device_common import d2h_begin, h2d
+
     impl = extract_impl or best_extract_impl()
     if sharded is not None:
         # the sharded fn was jitted with its own kernel params; the
@@ -932,7 +934,6 @@ def decode_rfc5424_submit(batch, lens, max_sd: int = DEFAULT_MAX_SD,
         out = sharded.fn(batch_dev, lens_dev)
     else:
         from .aot import decode_call
-        from .device_common import h2d
 
         batch_dev, lens_dev = h2d(batch, lens)
         # zero-JIT boot: a loaded AOT artifact replaces the trace+compile
@@ -950,6 +951,9 @@ def decode_rfc5424_submit(batch, lens, max_sd: int = DEFAULT_MAX_SD,
         if out is None:
             out = decode_rfc5424_jit(batch_dev, lens_dev,
                                      max_sd=max_sd, extract_impl=impl)
+    # every channel is wanted on the host a batch from now: their copies
+    # queue behind the program here, and the fetcher finds host buffers
+    d2h_begin(out)
     # the handle keeps the original *host* arrays (rescue_refetch slices
     # them without a device round-trip) plus the uploaded *device*
     # arrays so downstream device-side stages (tpu/device_gelf.py) can
@@ -992,17 +996,17 @@ def rescue_refetch(host, batch, lens, rows_idx, field_keys, dispatch,
 
 
 def _fetch_channels(out):
-    """One blocking copy per output channel of a decode program, the
-    first of them also the wait for the program."""
+    """The host channels of a decode program whose copies were begun at
+    its dispatch: one wait, for the program and the copies behind it."""
     from ..obs.trace import tracer as _tracer
-    from .device_common import d2h
+    from .device_common import d2h_all
 
     if _tracer.active:
-        # tracing only: tell the wait for the program from the first
-        # copy, which would otherwise hold both
+        # tracing only: tell the wait for the program from the wait for
+        # its copies, which would otherwise hold both
         with _tracer.sub(_tracer.bound(), "device_wait", "fetch"):
             jax.block_until_ready(out)
-    return {k: d2h(v, k) for k, v in out.items()}
+    return d2h_all(out)
 
 
 def decode_rfc5424_fetch(handle):
@@ -1013,7 +1017,7 @@ def decode_rfc5424_fetch(handle):
     come back widened to RESCUE_MAX_PAIRS when any row needed tier 2."""
     import numpy as np
 
-    from .device_common import h2d
+    from .device_common import d2h_begin, h2d
 
     out, batch, lens, max_sd, impl = handle[:5]
     host = _fetch_channels(out)
@@ -1021,12 +1025,13 @@ def decode_rfc5424_fetch(handle):
     over = np.flatnonzero((pc > DEFAULT_MAX_PAIRS) & (pc <= RESCUE_MAX_PAIRS))
 
     def dispatch(sub_b, sub_l):
-        # a second round over the link, inside the fetch stage
+        # a second round over the link, inside the fetch stage: it can
+        # only start once ``pair_count`` is on the host
         out2 = decode_rfc5424_jit(*h2d(sub_b, sub_l, parent="fetch"),
                                   max_sd=max_sd,
                                   max_pairs=RESCUE_MAX_PAIRS,
                                   extract_impl=impl)
-        return _fetch_channels(out2)
+        return _fetch_channels(d2h_begin(out2))
 
     return rescue_refetch(host, batch, lens, over, _PAIR_KEYS, dispatch,
                           RESCUE_MAX_PAIRS)

@@ -180,10 +180,12 @@ _COUNTERS = (
     "proxy_connections", "proxy_bytes", "proxy_route_errors",
     # the host-device link, counted where the bytes move
     # (tpu/device_common.py h2d/d2h): uploaded batch bytes and the
-    # lines' own share of them, copies back and their bytes; and each
-    # dispatched batch's real rows against its padded bucket
-    # (tpu/batch.py)
+    # lines' own share of them, the bytes copied back, the times a
+    # thread blocked on the link for them, and the arrays whose copy
+    # was begun at their program's dispatch; and each dispatched
+    # batch's real rows against its padded bucket (tpu/batch.py)
     "h2d_bytes", "packed_line_bytes", "d2h_bytes", "d2h_calls",
+    "d2h_prefetched",
     "batch_rows_real", "batch_rows_padded",
 )
 

@@ -794,11 +794,13 @@ def test_link_counters_on_the_served_route(host_route):
     assert snap["packed_line_bytes"] == line_bytes
     assert snap["h2d_bytes"] - snap["packed_line_bytes"] == \
         rows * (max_len + 4) - line_bytes
-    # one copy per output channel of the decode program came down
+    # every output channel of the decode program came down: their
+    # copies begun at its dispatch, the fetcher blocked for them once
     batch, lens = pack.pack_lines_2d([ln[:-1] for ln in _LINES],
                                      max_len)[:2]
     out = rfc5424.decode_rfc5424_submit(batch, lens)[0]
-    assert snap["d2h_calls"] == len(out) == 31
+    assert snap["d2h_prefetched"] == len(out) == 31
+    assert snap["d2h_calls"] == 1
     assert snap["d2h_bytes"] == sum(np.asarray(v).nbytes
                                     for v in out.values())
     # the fetcher's seconds went to the host route and to no other
@@ -811,23 +813,32 @@ def test_link_counters_on_the_served_route(host_route):
 
 def test_the_pair_rescue_is_a_second_round_over_the_link(host_route):
     """A row with 7..16 SD pairs sends the batch's overflow rows
-    through the wider kernel: one more upload, one more wait and 31
-    more copies, all inside the fetch stage."""
+    through the wider kernel: one more upload, one more wait for the
+    program and one more for its 31 copies, begun at its dispatch like
+    the first program's, all inside the fetch stage."""
     seven = (b'<13>1 2015-08-05T15:53:45Z h a p m [a@1 k1="1" k2="2" '
              b'k3="3" k4="4"][b@1 k5="5" k6="6" k7="7"] rescued\n')
     obs_trace.tracer.configure("ring")
     _run_batch(host_route, _LINES + [seven])
     snap = registry.snapshot()
-    assert snap["d2h_calls"] == 62
+    assert snap["d2h_prefetched"] == 62
+    assert snap["d2h_calls"] == 2
     rows = 256                     # both the batch's bucket and the rescue's
     assert snap["h2d_bytes"] == 2 * rows * (512 + 4)
+    # every channel of both programs, once: 113 B a row beside the six
+    # pair channels, which are [256, 6] from the first and [256, 16]
+    # from the wide one (five int32 and one bool)
+    assert snap["d2h_bytes"] == rows * (2 * 113 + 21 * (6 + 16))
     rec = obs_trace.tracer.snapshot()[-1]
     count = {}
     for sp in rec["sub"]:
         key = (sp["stage"], sp["parent"])
         count[key] = count.get(key, 0) + 1
     assert count == {("h2d", "decode"): 1, ("h2d", "fetch"): 1,
-                     ("device_wait", "fetch"): 2, ("d2h", "fetch"): 62}
+                     ("device_wait", "fetch"): 2, ("d2h", "fetch"): 2}
+    d2h = [sp for sp in rec["sub"] if sp["stage"] == "d2h"]
+    assert [sp["note"] for sp in d2h] == ["31", "31"]
+    assert sum(sp["bytes"] for sp in d2h) == snap["d2h_bytes"]
     fetch = next(sp for sp in rec["spans"] if sp["stage"] == "fetch")
     assert all(fetch["t0"] <= sp["t0"] and sp["t1"] <= fetch["t1"] + 1e-3
                for sp in rec["sub"] if sp["parent"] == "fetch")
