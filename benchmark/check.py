@@ -16,8 +16,14 @@ the limit 0:
                    explains: a duplicate, a junk line kept, an altered
                    timestamp, a record cut short at the file's end;
                    every record
-``out_of_order``   places where the sink's order departs from the
-                   order sent; every record
+``out_of_order``   places where a connection's own records leave the
+                   order they were sent in; every record.  A record's
+                   connection is that of the line written with its due
+                   time (for a record no line explains: of the last
+                   line due before it).  Between connections the order
+                   is not defined (upstream's shared queue), so with
+                   one stream this is every place where the sink's
+                   order departs from the order sent
 ``bytes_differ``   well-formed lines of the sample for which the sink
                    holds no record that is byte for byte the reference's
 
@@ -73,12 +79,24 @@ def even_cuts(weights, parts):
 
 
 def written(log):
-    """One entry per line written: (pool line, due time), in the order
-    of the log."""
+    """One entry per line written: (pool line, due time, stream), in
+    the order of the log."""
     n = log[:, 2]
     row = np.repeat(np.arange(len(log)), n)
     j = np.arange(int(n.sum())) - np.repeat(np.cumsum(n) - n, n)
-    return log[row, 1] + j, log[row, 3] + j % traffic.SPREAD_US
+    return log[row, 1] + j, log[row, 3] + j % traffic.SPREAD_US, log[row, 0]
+
+
+def out_of_order(ts, due, stream):
+    """Places where one stream's records, in the sink's order ``ts``,
+    do not rise.  ``due`` and ``stream`` say which stream each line
+    written went on; ``due`` arrives sorted."""
+    if not len(ts) or not len(due):
+        return int((np.diff(ts) <= 0).sum())
+    of = stream[np.maximum(np.searchsorted(due, ts, "right") - 1, 0)]
+    by_stream = np.argsort(of, kind="stable")
+    ts, of = ts[by_stream], of[by_stream]
+    return int(((np.diff(ts) <= 0) & (of[1:] == of[:-1])).sum())
 
 
 class Sink:
@@ -136,7 +154,8 @@ def compare(work, sink, log, window, seed):
     pool_size = cat("size", parts[:n_pool_jobs], np.int64)
     want_fp = cat("fp", parts[n_pool_jobs:n_expect], np.uint64)
     have_fp = cat("fp", parts[n_expect:], np.uint64)
-    line, due = written(log)
+    line, due, stream = written(log)
+    by_due = np.argsort(due, kind="stable")
     keep = pool_size[line] > 0
     exp_line, exp_due = line[keep], due[keep]
     at, unexpected = stats.match(np.sort(exp_due, kind="stable"),
@@ -145,7 +164,8 @@ def compare(work, sink, log, window, seed):
     at_fp, _ = stats.match(np.sort(want_fp), np.sort(have_fp))
     got = {"missing": int((at < 0).sum()),
            "unexpected": unexpected + (1 if sink.rest else 0),
-           "out_of_order": int((np.diff(sink.ts) <= 0).sum()),
+           "out_of_order": out_of_order(sink.ts, due[by_due],
+                                        stream[by_due]),
            "bytes_differ": int((at_fp < 0).sum())}
     t0, t1 = window
     cand = exp_line[(exp_due >= t0) & (exp_due < t1)]

@@ -14,7 +14,12 @@ to the sink's queue, one block in ``EVERY``.
 ``alter``      one byte of one record's message changed
 ``half``       the second half of the block left out
 ``swap``       two neighbouring records exchanged (breaks the order a
-               one-stream deployment promises)
+               one-stream deployment promises; where a block holds the
+               records of several connections the two may be two
+               senders', and the exchange legal)
+``reverse``    the block's records back to front: whatever the batching,
+               some connection's own order is broken (the order control
+               of a deployment with many connections)
 """
 
 from __future__ import annotations
@@ -76,8 +81,12 @@ def swap(recs):
     return recs[:k - 1] + [recs[k], recs[k - 1]] + recs[k + 1:]
 
 
+def reverse(recs):
+    return recs[::-1]
+
+
 FAULTS = {"coarse_ts": coarse_ts, "drop": drop, "dup": dup, "alter": alter,
-          "half": half, "swap": swap}
+          "half": half, "swap": swap, "reverse": reverse}
 
 
 def install(pipe, name):

@@ -5,15 +5,16 @@
 
 The cell's configuration, traffic mix and per-layer metrics are data
 files found by the names in ``BENCHMARK.json`` (``README.md`` here says
-how to add one).  A run: set-up (native build, compile cache, pool,
-children, pipeline, the shape walk, the mix itself for ``warm_min_s``
-seconds and on until a slice of it compiles and declines nothing), the
-measured window, opened on the clock, stop and drain, then the
-comparison with
-the plain reference, the report, and one JSON object as the last line
-of standard output.  Without a TPU it exits non-zero and prints no
-result; ``--rehearse`` runs the control flow on whatever device JAX has
-and names that device.
+how to add one).  The deployment's ``[input] type`` says which way in the
+lines take: ``stdin`` (the generator's pipe on fd 0) or ``tcp`` (the
+generator's connections to the listener).  A run: set-up (native build,
+compile cache, pool, children, pipeline, the shape walk, the mix itself
+for ``warm_min_s`` seconds and on until a slice of it compiles and
+declines nothing), the measured window, opened on the clock, stop and
+drain, then the comparison with the plain reference, the report, and
+one JSON object as the last line of standard output.  Without a TPU it
+exits non-zero and prints no result; ``--rehearse`` runs the control
+flow on whatever device JAX has and names that device.
 """
 
 from __future__ import annotations
@@ -32,6 +33,7 @@ import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
+import tomllib  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -47,6 +49,7 @@ COLD_S = 1.0            # a compile this long holds the stream up
 WALK_FROM, WALK_TOP = 200, 32768   # bursts of 200, 400, ... 25,600 lines
 TRACE_SLICE_S = 5.0     # the profiler's slice, from the middle of the window
 WAIT_S = 600.0
+HOST = "127.0.0.1"      # a tcp deployment's @LISTEN@: any free port, here
 # what a warm slice, and the window, may not count
 MUST_BE_ZERO = ("device_encode_compile_declines", "framing_declines",
                 "pallas_declines", "breaker_trips", "device_decode_errors",
@@ -232,6 +235,7 @@ def load_cell(name):
         return "workloads" not in m or name in m["workloads"]
 
     return {"cell": cell, "toml": toml,
+            "input": tomllib.loads(toml).get("input", {}).get("type"),
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
 
@@ -274,6 +278,7 @@ class Run:
         self.gen = self.tail = None
         self.failure = None
         self.window = None
+        self.lines0 = 0     # the collector's input_lines before any line
         self.facts = {}
 
     # -- set-up --------------------------------------------------------------
@@ -284,7 +289,11 @@ class Run:
                 "--work", self.work]
         if self.args.rehearse:
             argv += ["--pool-lines", "8192"]
-        self.gen = Child(argv, answers=True, stdout=subprocess.PIPE)
+        if self.spec["input"] == "tcp":
+            self.gen = Child(argv + ["--sockets"], answers=True,
+                             stdout=subprocess.DEVNULL)
+        else:
+            self.gen = Child(argv, answers=True, stdout=subprocess.PIPE)
         self.tail = Child([os.path.join(HERE, "sinktail.py"), self.sink_path,
                            os.path.join(self.work, "sink.npz")])
 
@@ -309,11 +318,21 @@ class Run:
         from flowgger_tpu.pipeline import Pipeline
 
         text = self.spec["toml"].replace("@SINK@", self.sink_path).replace(
-            "@CACHE@", os.path.join(ROOT, ".jax_cache"))
+            "@CACHE@", os.path.join(ROOT, ".jax_cache")).replace(
+            "@LISTEN@", HOST + ":0")
         if self.args.rehearse:
             # small batches; and no persistent cache, the program's
             # default on the CPU backend
             text = text.replace("[input]\n", "[input]\ntpu_batch_size = 512\n")
+            if self.spec["input"] == "tcp":
+                # the chip's framing tier, which "auto" engages there
+                # and not on the CPU: each connection's bytes then go
+                # through a raw session of its own, whose order the
+                # flush keeps.  The host splitters' shared list does
+                # not keep it between racing flushes (PERF.md, Open
+                # questions), and the chip never takes that path
+                text = text.replace("[input]\n",
+                                    '[input]\ntpu_framing = "on"\n')
             text = text.replace('tpu_compile_cache_dir = "', '# "')
         if self.args.trace:
             text += '\n[metrics]\ntrace = "ring"\ntrace_ring = 65536\n'
@@ -326,10 +345,21 @@ class Run:
         return pipe
 
     # -- the conductor: warm-up, window, stop --------------------------------
-    def settle(self):
-        """Nothing moves any more and no compile is left running."""
+    def settle(self, written):
+        """Nothing moves any more and no compile is left running.
+        ``written``: the generator's answer, with the lines it has
+        written by now.  Sockets take a burst whole before the
+        collector has read a byte, so on the tcp way in the collector
+        first has to have counted as many."""
         from flowgger_tpu.tpu.device_common import join_compile_workers
 
+        lines = written["lines"] if self.spec["input"] == "tcp" else 0
+        deadline = time.time() + WAIT_S
+        while snapshot().get("input_lines", 0) < self.lines0 + lines:
+            if time.time() > deadline:
+                raise Failed(f"the collector counted fewer than the {lines} "
+                             f"lines written, {WAIT_S:.0f}s on")
+            time.sleep(0.05)
         last, since = None, time.time()
         while time.time() - since < 0.3:
             now = snapshot()
@@ -350,8 +380,7 @@ class Run:
         c0, m0, n = compiles.mark(), snapshot(), WALK_FROM
         while n < top:
             self.gen.tell(f"burst {n}")
-            self.gen.answer("burst")
-            self.settle()
+            self.settle(self.gen.answer("burst"))
             n *= 2
         d = delta(snapshot(), m0)
         say(f"shape walk: bursts of {WALK_FROM}..{n // 2} lines, "
@@ -369,9 +398,10 @@ class Run:
         first time, whenever one of the collector's periodic probes
         falls on a batch of another size: those runs warm up longer,
         and leave the programs in the cache); and the last slice loaded
-        and compiled no program, counted no decline and flowed (half of
-        the best slice's records at least: a stream that stands still
-        is waiting for a compile that has not reported yet)."""
+        and compiled no program, counted no decline and flowed (some
+        records, and half of the best slice's at least: a stream that
+        stands still is waiting for a compile that has not reported
+        yet)."""
         slice_s = 1.0 if self.args.rehearse else SLICE_S
         least = 0.0 if self.args.rehearse else float(self.mix["warm_min_s"])
         c_first = compiles.mark()
@@ -386,7 +416,7 @@ class Run:
             bad = declines(d)
             out = d.get("output_written", 0)
             best = max(best, out)
-            if out < best / 2:
+            if out < best / 2 or not out:
                 bad.append("the stream stood still" if out else
                            "nothing reached the sink")
             cold = programs(comp)
@@ -406,8 +436,7 @@ class Run:
                 # let the compile workers land with the stream held
                 # back, or a cold process would fill the disk meanwhile
                 self.gen.tell("pause")
-                self.gen.answer("paused")
-                self.settle()
+                self.settle(self.gen.answer("paused"))
                 self.gen.tell("run")
         setup = compiles.since(c_first)
         loaded = sum(1 for e in compiles.events[c_first:] if e[2])
@@ -417,10 +446,16 @@ class Run:
             f"{sum(t for _, t in setup.values()):.1f}s, {loaded} of them "
             "loaded from the checkout's cache")
 
-    def conduct(self, compiles):
+    def conduct(self, compiles, port=None):
         """Runs beside the pipeline: everything between "the pipeline is
-        up" and "the generator has stopped"."""
+        up" and "the generator has stopped".  ``port``: the listener's,
+        where the lines arrive on connections."""
         self.gen.answer("ready")
+        self.lines0 = snapshot().get("input_lines", 0)
+        if port is not None:
+            self.gen.tell(f"connect {HOST}:{port}")
+            say(f"generator: {self.gen.answer('connected')['sources']} "
+                f"connections to port {port}, held to the end")
         self.warm_up(compiles)
         seconds = self.args.seconds
         t0 = time.time()
@@ -462,9 +497,9 @@ class Run:
         self.gen.tell("stop")
         self.facts["gen_done"] = self.gen.answer("done")
 
-    def conduct_guarded(self, compiles):
+    def conduct_guarded(self, compiles, port=None):
         try:
-            self.conduct(compiles)
+            self.conduct(compiles, port)
         except BaseException as e:  # noqa: BLE001 - re-raised on the main thread
             self.failure = e
             # the stream has to end, or the pipeline never returns
@@ -490,6 +525,46 @@ class Run:
         finally:
             os.dup2(saved, 0)
             os.close(saved)
+
+    def serve_tcp(self, compiles):
+        """The listener on a thread of its own, as ``chip_smoke.py``'s
+        TCP phase serves it (``TcpInput.accept`` never returns), the
+        conductor here.  When the generator has written every queued
+        line and closed every socket, the connections' reader threads
+        end of themselves; then the drain ``Pipeline.run()`` would make:
+        flush, the queue through the sink, the compile workers."""
+        from flowgger_tpu.tpu.device_common import join_compile_workers
+
+        pipe = self.pipeline()
+        threads = pipe.start_output()
+        if not isinstance(threads, list):
+            threads = [threads]
+        # flowcheck: disable=FC10 -- TcpInput.accept never returns: the listener lives as long as the process, as in the CLI, and dies with it (daemon)
+        threading.Thread(target=pipe.input.accept, daemon=True,
+                         args=(pipe.handler_factory,),
+                         name="bench-accept").start()
+        try:
+            deadline = time.time() + 30.0
+            while pipe.input.bound_port is None:
+                if time.time() > deadline:
+                    raise Failed("the listener did not come up")
+                time.sleep(0.01)
+            self.conduct_guarded(compiles, pipe.input.bound_port)
+            # the sockets are closed (or the generator is dead): every
+            # reader thread sees the end of its stream
+            left = pipe.input.join_handlers(timeout=WAIT_S)
+            if left and self.failure is None:
+                self.failure = Failed(
+                    f"{left} connections' readers were still at work "
+                    f"{WAIT_S:.0f}s after their sockets closed")
+        finally:
+            pipe._drain(threads)
+        if join_compile_workers():
+            say("drain: a kernel compile was still running at the end")
+        stragglers = snapshot().get("drain_stragglers", 0)
+        if stragglers and self.failure is None:
+            self.failure = Failed(f"drain_stragglers={stragglers}: the drain "
+                                  "left a thread behind; no sound run")
 
     # -- after the drain -----------------------------------------------------
     def reduce(self):
@@ -529,6 +604,8 @@ class Run:
         # a neighbour that takes the cores shows as fewer of them here
         say(f"window: the collector's process kept {f['cores']:.2f} of "
             f"{os.cpu_count()} cores busy")
+        say(f"run: the generator wrote {f['gen_done']['lines']} lines, the "
+            f"collector counted {snapshot().get('input_lines', 0) - self.lines0}")
         say("window: " + " ".join(f"{k}={d.get(k, 0)}" for k in SHOWN))
         say(f"window: XLA compiles inside it: {fmt_compiles(f['compiles'])}")
         say(f"window: declines: {', '.join(declines(d)) or 'none'}")
@@ -642,15 +719,16 @@ def main():
         print(f"benchmark/run.py: {e}", file=sys.stderr)
         return 2
     run = Run(args, spec, mix)
-    if 'type = "stdin"' not in spec["toml"]:
-        print("benchmark/run.py: the harness feeds a deployment through "
-              "stdin; another input comes with the cell that needs it",
-              file=sys.stderr)
+    serve = {"stdin": run.serve_stdin, "tcp": run.serve_tcp}.get(spec["input"])
+    if serve is None or (spec["input"] == "stdin" and mix["sources"] != 1):
+        print(f"benchmark/run.py: the harness feeds a deployment through "
+              f"stdin (one stream) or tcp, not {mix['sources']} sources "
+              f"through [input] type = {spec['input']!r}", file=sys.stderr)
         return 2
     try:
         run.start_children()
         compiles = begin(run, args, spec)
-        run.serve_stdin(compiles)
+        serve(compiles)
         if run.failure is not None:
             raise run.failure
         run.reduce()

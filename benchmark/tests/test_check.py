@@ -74,6 +74,7 @@ def test_a_long_run_is_sampled_from_the_seed(sent, monkeypatch):
     ("alter", {"bytes_differ"}),
     ("half", {"missing", "bytes_differ"}),
     ("swap", {"out_of_order"}),
+    ("reverse", {"out_of_order"}),
 ])
 def test_each_fault_fails_the_numbers_it_should(sent, name, fails):
     work, log, records = sent
@@ -97,3 +98,90 @@ def test_a_junk_line_kept_is_unexpected(sent):
     work, log, records = sent
     got, _ = judge(work, log, records + [b'{"short_message":"junk"}\0'])
     assert got["unexpected"] == 1
+
+
+# -- order is a connection's own ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def fleet(tmp_path_factory):
+    """Two connections' writes, turn by turn: the log's first column
+    says whose each is; the records in the order written."""
+    work = tmp_path_factory.mktemp("fleet")
+    pool = corpus.build_pool(5, 1500, "loghub_syslog")
+    corpus.save_pool(pool, work / "pool.npz")
+    rows = [[k % 2, (k * 250) % 1500, 250, BASE + 2000 * k,
+             BASE + 2000 * k + 40] for k in range(12)]
+    log = np.asarray(rows, np.int64)
+    per_row = [[r + b"\0" for r in map(
+        refchunk.reference.gelf, refchunk.written_lines(pool, log[k:k + 1]))
+        if r] for k in range(12)]
+    return work, log, per_row
+
+
+def flat(blocks):
+    return [r for b in blocks for r in b]
+
+
+def old_count(records):
+    """``out_of_order`` as it was before a log had connections: every
+    place where the sink's order departs from the order sent."""
+    tail = sinktail.Tail()
+    tail.feed(b"".join(records), BASE)
+    return int((np.diff(tail.columns()[0]) <= 0).sum())
+
+
+def test_a_legal_interleaving_of_two_connections_is_in_order(fleet):
+    work, log, per_row = fleet
+    # as sent; then one connection's writes all before the other's; then
+    # the two taking turns two writes at a time: each keeps its own order
+    for order in (range(12), [0, 2, 4, 6, 8, 10, 1, 3, 5, 7, 9, 11],
+                  [1, 3, 0, 2, 5, 7, 4, 6, 9, 11, 8, 10]):
+        records = flat(per_row[k] for k in order)
+        got, _ = judge(work, log, records)
+        assert got == {"missing": 0, "unexpected": 0, "out_of_order": 0,
+                       "bytes_differ": 0}
+    assert old_count(records) == 3      # what one stream's rule would say
+
+
+def test_a_swap_inside_a_connection_is_out_of_order(fleet):
+    work, log, per_row = fleet
+    # two of connection 0's writes exchanged, connection 1's between
+    records = flat(per_row[k] for k in [2, 1, 0, 3, 4, 5, 6, 7, 8, 9, 10, 11])
+    got, _ = judge(work, log, records)
+    assert got["out_of_order"] == 1
+    assert got["missing"] == got["unexpected"] == got["bytes_differ"] == 0
+    # two neighbouring records of one write exchanged
+    got, _ = judge(work, log, flat(per_row[:3]) + faults.swap(per_row[3])
+                   + flat(per_row[4:]))
+    assert got["out_of_order"] == 1
+    # the same fault on a block that holds both connections' records
+    # falls on two senders' neighbours here, which may change places
+    got, _ = judge(work, log, faults.swap(flat(per_row)))
+    assert got["out_of_order"] == 0
+    # and a block of both connections' records back to front
+    got, _ = judge(work, log, faults.reverse(flat(per_row)))
+    assert got["out_of_order"] >= len(flat(per_row)) - 12
+
+
+def test_records_across_connections_may_swap(fleet):
+    work, log, per_row = fleet
+    records = flat(per_row)
+    k = len(per_row[0])                 # last of write 0, first of write 1
+    records[k - 1], records[k] = records[k], records[k - 1]
+    got, _ = judge(work, log, records)
+    assert got["out_of_order"] == 0 and old_count(records) == 1
+
+
+@pytest.mark.parametrize("name", ["swap", "reverse", "dup", "coarse_ts",
+                                  "drop"])
+def test_one_stream_reads_what_it_always_read(sent, name):
+    """With one source the per-connection count is the count over the
+    whole sink, as ``check.py`` made it before connections."""
+    work, log, records = sent
+    broken = faults.FAULTS[name](list(records))
+    got, _ = judge(work, log, broken)
+    assert got["out_of_order"] == old_count(broken)
+    shuffled = [records[i] for i in
+                np.random.default_rng(7).permutation(len(records))]
+    got, _ = judge(work, log, shuffled)
+    assert got["out_of_order"] == old_count(shuffled) > 1000

@@ -7,7 +7,9 @@ control first.  Slow: some 25 s a run.
 The faults a cell of this system can have: an answer altered where it
 is produced (``alter``), part of a batch left out (``half``, ``drop``),
 an answer given twice (``dup``), the order of one stream broken
-(``swap``).  It has no training state and no exchange between chips.
+(``swap``; with many connections ``reverse``, which breaks some
+connection's own order whatever the batching).  It has no training state
+and no exchange between chips.
 """
 
 import json
@@ -16,20 +18,34 @@ import subprocess
 import sys
 
 import pytest
+import relay
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 WORK = os.path.join(ROOT, "benchmark", "work")
-MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
 
 
-def run(cell, *more, seed=2**31 + 77):
+@pytest.fixture(scope="module")
+def roots(tmp_path_factory):
+    """Where each cell is run from: the admitted one from the repo's
+    root, the relay cell from a scratch root whose manifest names it."""
+    return {"backfill.drain": ROOT, relay.CELL["name"]: relay.scratch_root(
+        tmp_path_factory.mktemp("relay_root"))}
+
+
+# a rehearsal's window, seconds: with 64 reader threads beside the
+# conductor a CPU run of the relay cell can pass two seconds without a
+# flush counted, and a ratio over `batches` then finds nothing to read
+SECONDS = {"backfill.drain": "2", relay.CELL["name"]: "6"}
+
+
+def run(root, cell, *more, seed=2**31 + 77):
     before = set(os.listdir(WORK)) if os.path.isdir(WORK) else set()
     p = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", cell, "--seed", str(seed), "--seconds", "2",
-         "--rehearse", *more],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        [sys.executable, os.path.join(root, "benchmark", "run.py"),
+         "--workload", cell, "--seed", str(seed), "--seconds",
+         SECONDS[cell], "--rehearse", *more],
+        capture_output=True, text=True, timeout=600, cwd=root,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert p.returncode == 0, p.stderr[-2000:]
     after = set(os.listdir(WORK)) if os.path.isdir(WORK) else set()
@@ -37,13 +53,14 @@ def run(cell, *more, seed=2**31 + 77):
     return json.loads(p.stdout.splitlines()[-1]), p
 
 
-CELLS = ["backfill.drain"]
+# the admitted cell, and the one that takes the tcp way in
+CELLS = ["backfill.drain", relay.CELL["name"]]
 
 
 @pytest.mark.parametrize("trace", ["0", "1"])
 @pytest.mark.parametrize("cell", CELLS)
-def test_a_sound_run_is_correct(cell, trace):
-    result, p = run(cell, "--trace", trace)
+def test_a_sound_run_is_correct(roots, cell, trace):
+    result, p = run(roots[cell], cell, "--trace", trace)
     assert list(result)[:5] == ["correct", "attempted", "failed", "metrics",
                                 "device"]
     assert list(result)[-1] == "compared"
@@ -53,7 +70,7 @@ def test_a_sound_run_is_correct(cell, trace):
     assert all(v == 0 and lim == 0 for v, lim in result["compared"].values())
     last = p.stderr.strip().splitlines()[-1]
     assert last.startswith("compared (") and "limit 0" in last
-    with open(MANIFEST) as f:
+    with open(os.path.join(roots[cell], "BENCHMARK.json")) as f:
         bench = json.load(f)
     kind = "per_layer" if trace == "1" else "end_to_end"
     mine = {m["name"] for m in bench[kind]
@@ -67,13 +84,14 @@ def test_a_sound_run_is_correct(cell, trace):
 
 
 FAULTS = {"backfill.drain": ["coarse_ts", "alter", "drop", "half", "dup",
-                             "swap"]}
+                             "swap"],
+          relay.CELL["name"]: ["coarse_ts", "reverse", "alter", "half", "dup"]}
 
 
 @pytest.mark.parametrize("cell, fault", [(c, f) for c in CELLS
                                          for f in FAULTS[c]])
-def test_a_broken_run_is_not_correct(cell, fault):
-    result, _ = run(cell, "--trace", "0", "--break", fault)
+def test_a_broken_run_is_not_correct(roots, cell, fault):
+    result, _ = run(roots[cell], cell, "--trace", "0", "--break", fault)
     assert result["correct"] is False
     assert any(v > lim for v, lim in result["compared"].values())
 

@@ -17,6 +17,12 @@ def test_every_traffic_file_loads(name):
     mix = traffic.load(name)
     assert mix["judged"] == "tput" and mix["rate_lines_per_s"] == "max"
     assert mix["pool_lines"] >= 8192 and mix["warm_min_s"] >= 0
+    assert 1 <= mix["sources"] <= traffic.MAX_SOURCES
+
+
+def test_the_number_of_sources():
+    assert traffic.load("drain")["sources"] == 1      # the default: one pipe
+    assert traffic.load("fleet_catchup")["sources"] == 64
 
 
 def test_stamps_of_one_write_stay_within_a_millisecond():
@@ -30,6 +36,16 @@ def test_stamps_of_one_write_stay_within_a_millisecond():
     {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": 8000},
     {"judged": "tput", "corpus": "no_such", "rate_lines_per_s": "max"},
     {"corpus": "loghub_syslog", "rate_lines_per_s": "max"},
+    {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": "max",
+     "sources": 0},
+    {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": "max",
+     "sources": 1025},
+    {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": "max",
+     "sources": "64"},
+    {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": "max",
+     "sources": 2.5},
+    {"judged": "tput", "corpus": "loghub_syslog", "rate_lines_per_s": "max",
+     "sources": True},
 ])
 def test_a_file_the_generator_cannot_write_is_refused(tmp_path, monkeypatch,
                                                       bad):

@@ -6,10 +6,16 @@ Keys of ``traffic/<mix>.json``:
                      mix reports (``"tput"``: every
                      ``layer_metrics/*.tput.json``)
 ``corpus``           the lines: a file under ``corpora/`` (``corpus.py``)
-``rate_lines_per_s`` ``"max"``: a closed loop, the one stream written as
-                     fast as its pipe takes it.  (An open loop, on a
-                     schedule, comes with the first cell that needs one:
-                     PERF.md, Open questions.)
+``rate_lines_per_s`` ``"max"``: a closed loop, every stream written as
+                     fast as its pipe or socket takes it.  (An open
+                     loop, on a schedule, comes with the first cell
+                     that needs one: PERF.md, Open questions.)
+``sources``          the streams the lines arrive on (default 1, at most
+                     1024): the one pipe of a stdin deployment, or that
+                     many TCP connections to a tcp deployment's
+                     listener, all opened before set-up and held to the
+                     end, with equal shares: the next chunk goes to
+                     whichever stream has nothing queued
 ``pool_lines``       lines made from the seed and replayed in a cycle
 ``chunk_lines``      lines stamped and queued at a time
 ``warm_min_s``       set-up runs the mix at least this long before the
@@ -32,7 +38,9 @@ import os
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-DEFAULTS = {"pool_lines": 262144, "chunk_lines": 512, "warm_min_s": 0}
+DEFAULTS = {"pool_lines": 262144, "chunk_lines": 512, "warm_min_s": 0,
+            "sources": 1}
+MAX_SOURCES = 1024
 # lines of one write are stamped a microsecond apart, within this many
 SPREAD_US = 1000
 
@@ -46,6 +54,11 @@ def load(name):
     if mix.get("rate_lines_per_s") != "max":
         raise ValueError(f'traffic {name}: rate_lines_per_s must be "max" '
                          "(this generator writes closed loops)")
+    n = mix["sources"]
+    if not (isinstance(n, int) and not isinstance(n, bool)
+            and 1 <= n <= MAX_SOURCES):
+        raise ValueError(f"traffic {name}: sources is a whole number from 1 "
+                         f"to {MAX_SOURCES}")
     if not os.path.exists(os.path.join(HERE, "corpora",
                                        str(mix.get("corpus")) + ".json")):
         raise ValueError(f"traffic {name}: no corpora/{mix.get('corpus')}.json")
