@@ -17,7 +17,10 @@ ingest thread blocked on a full window (``window_wait`` under
 ``submit``), the batch's host-to-device copy (``h2d`` under
 ``decode``), the wait for the device program (``device_wait``) and
 each wait on the link for its outputs (``d2h``: one per program, whose
-copies were begun at dispatch) under ``fetch``,
+copies were begun at dispatch) under ``fetch``, the block encoder's
+rows that go through the scalar oracle one by one and the joining of
+their output with the columnar tier's (``splice`` under ``encode``: one
+a batch, none for a batch without such a row),
 and a program's first call (``compile``, no parent: it runs on the
 compile watchdog's worker).  They go to the batch record's ``sub``
 list, each with its ``parent``, so ``spans`` holds the stages and

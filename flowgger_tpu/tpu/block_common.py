@@ -11,6 +11,7 @@ pre-applied with the pipeline's merger (merger/mod.rs:30-32).
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -18,6 +19,8 @@ import numpy as np
 from ..block import EncodedBlock
 from ..encoders import EncodeError
 from ..mergers import LineMerger, Merger, NulMerger, SyslenMerger
+from ..obs.trace import tracer as _tracer
+from ..utils.metrics import registry as _metrics
 from .assemble import (
     build_source,
     concat_segments,
@@ -327,10 +330,13 @@ def finish_block(
     merger: Optional[Merger],
     encoder,
     scalar_fn=_scalar_line,
+    max_len: Optional[int] = None,
 ) -> BlockResult:
     """Fallback rows through the scalar oracle (``scalar_fn``, the
     rfc5424 one by default), splice in input order, compute message
-    bounds; returns the BlockResult."""
+    bounds; returns the BlockResult.  ``max_len`` is the device's row
+    width, given so that the rows that fell back for their length are
+    counted apart (``splice_rows_overlen``)."""
     errors: List[Tuple[str, str]] = []
     row_bytes_len = np.zeros(n, dtype=np.int64)
     emit = np.zeros(n, dtype=bool)
@@ -339,60 +345,73 @@ def finish_block(
         emit[ridx] = True
 
     fb_idx = np.flatnonzero(~cand)
-    fallback_payload: Dict[int, bytes] = {}
     fb_prefix: Dict[int, int] = {}
     fallback_rows = 0  # parity with the per-row path: utf8 errors excluded
     error_rows: List[int] = []
-    for i in fb_idx.tolist():
-        s = int(starts64[i])
-        ln = int(lens64[i])
-        raw = chunk_bytes[s:s + ln]
-        try:
-            line = raw.decode("utf-8")
-        except UnicodeDecodeError:
-            errors.append(("__utf8__", ""))
-            error_rows.append(i)
-            continue
-        fallback_rows += 1
-        res = scalar_fn(line)
-        if res.record is None:
-            errors.append((res.error, line))
-            error_rows.append(i)
-            continue
-        try:
-            payload = encoder.encode(res.record)
-        except EncodeError as e:
-            errors.append((str(e), line))
-            error_rows.append(i)
-            continue
-        framed_b = merger.frame(payload) if merger is not None else payload
-        fallback_payload[i] = framed_b
-        fb_prefix[i] = len(framed_b) - len(payload) - len(suffix)
-        row_bytes_len[i] = len(framed_b)
-        emit[i] = True
-
-    # splice tier runs and fallback rows in input order: fb_idx is
-    # exactly the non-tier rows, so every gap between consecutive
-    # fallback rows is a contiguous run of tier rows whose bytes are
-    # already contiguous in final_buf — one slice per run.
+    data = final_buf
     if fb_idx.size:
-        pieces: List[bytes] = []
-        tpos = np.cumsum(cand) - 1  # tier ordinal per row
-        prev = 0
-        for i in fb_idx.tolist():
-            if i > prev:
-                pieces.append(
-                    final_buf[int(row_off[tpos[prev]]):
-                              int(row_off[tpos[i - 1] + 1])])
-            fp = fallback_payload.get(i)
-            if fp is not None:
-                pieces.append(fp)
-            prev = i + 1
-        if prev < n:
-            pieces.append(final_buf[int(row_off[tpos[prev]]):])
-        data = b"".join(pieces)
-    else:
-        data = final_buf
+        # one sub-span and one set of counters a batch, never per row
+        t_splice = time.perf_counter()
+        with _tracer.sub(_tracer.bound(), "splice", "encode",
+                         rows=fb_idx.size,
+                         nbytes=int(lens64[fb_idx].sum())):
+            fallback_payload: Dict[int, bytes] = {}
+            for i in fb_idx.tolist():
+                s = int(starts64[i])
+                ln = int(lens64[i])
+                raw = chunk_bytes[s:s + ln]
+                try:
+                    line = raw.decode("utf-8")
+                except UnicodeDecodeError:
+                    errors.append(("__utf8__", ""))
+                    error_rows.append(i)
+                    continue
+                fallback_rows += 1
+                res = scalar_fn(line)
+                if res.record is None:
+                    errors.append((res.error, line))
+                    error_rows.append(i)
+                    continue
+                try:
+                    payload = encoder.encode(res.record)
+                except EncodeError as e:
+                    errors.append((str(e), line))
+                    error_rows.append(i)
+                    continue
+                framed_b = (merger.frame(payload) if merger is not None
+                            else payload)
+                fallback_payload[i] = framed_b
+                fb_prefix[i] = len(framed_b) - len(payload) - len(suffix)
+                row_bytes_len[i] = len(framed_b)
+                emit[i] = True
+
+            # splice tier runs and fallback rows in input order: fb_idx
+            # is exactly the non-tier rows, so every gap between
+            # consecutive fallback rows is a contiguous run of tier rows
+            # whose bytes are already contiguous in final_buf — one
+            # slice per run.
+            pieces: List[bytes] = []
+            tpos = np.cumsum(cand) - 1  # tier ordinal per row
+            prev = 0
+            for i in fb_idx.tolist():
+                if i > prev:
+                    pieces.append(
+                        final_buf[int(row_off[tpos[prev]]):
+                                  int(row_off[tpos[i - 1] + 1])])
+                fp = fallback_payload.get(i)
+                if fp is not None:
+                    pieces.append(fp)
+                prev = i + 1
+            if prev < n:
+                pieces.append(final_buf[int(row_off[tpos[prev]]):])
+            data = b"".join(pieces)
+        _metrics.add_seconds("splice_seconds",
+                             time.perf_counter() - t_splice)
+        _metrics.inc("splice_rows", int(fb_idx.size))
+        _metrics.inc("splice_bytes_out", int(row_bytes_len[fb_idx].sum()))
+        if max_len is not None:
+            _metrics.inc("splice_rows_overlen",
+                         int(np.count_nonzero(lens64[fb_idx] > max_len)))
 
     bounds = exclusive_cumsum(row_bytes_len[emit])
     prefix_lens = None

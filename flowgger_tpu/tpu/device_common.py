@@ -1339,5 +1339,5 @@ def fetch_encode_driver(kernel, out, batch_dev, lens_dev, packed, encoder,
                 round(float(row_off[-1]) / int(ridx.size), 1))
     res = finish_block(chunk, starts64, lens64, n, cand, ridx, final_buf,
                        row_off, prefix_lens_tier, suffix, syslen, merger,
-                       encoder, scalar_fn=scalar_fn)
+                       encoder, scalar_fn=scalar_fn, max_len=max_len)
     return res, t_fetch

@@ -123,7 +123,7 @@ def _extra_blob(extra: List[Tuple[str, str]]) -> bytes:
 
 def _capnp_assemble(chunk_bytes, starts64, lens64, n, cand, ridx,
                     texts, sid, pairs, ts, fac, sev, encoder, merger,
-                    suffix, syslen, scalar_fn=None, typed=None):
+                    suffix, syslen, scalar_fn=None, typed=None, max_len=None):
     """Shared layout + assembly for every format wrapper, over
     ridx-selected [R] arrays.
 
@@ -325,7 +325,7 @@ def _capnp_assemble(chunk_bytes, starts64, lens64, n, cand, ridx,
     kw = {} if scalar_fn is None else {"scalar_fn": scalar_fn}
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, **kw)
+                        syslen, merger, encoder, max_len=max_len, **kw)
 
 
 def encode_rfc5424_capnp_block(
@@ -359,7 +359,8 @@ def encode_rfc5424_capnp_block(
     if not ridx.size:
         return _capnp_assemble(chunk_bytes, starts64, lens64, n, cand,
                                ridx, [], None, None, None, None, None,
-                               encoder, merger, suffix, syslen)
+                               encoder, merger, suffix, syslen,
+                               max_len=max_len)
     st = starts64[ridx]
 
     def span(a_key, b_key):
@@ -415,7 +416,7 @@ def encode_rfc5424_capnp_block(
         chunk_bytes, starts64, lens64, n, cand, ridx, texts,
         (sid_a, sid_l, has_sd),
         (name_a, name_l, val_a, val_l, pvalid, has_sd),
-        ts, fac, sev, encoder, merger, suffix, syslen)
+        ts, fac, sev, encoder, merger, suffix, syslen, max_len=max_len)
 
 
 def encode_rfc3164_capnp_block(
@@ -478,7 +479,7 @@ def encode_rfc3164_capnp_block(
     return _capnp_assemble(
         chunk_bytes, starts64, lens64, n, cand, ridx, texts, None, None,
         ts, fac, sev, encoder, merger, suffix, syslen,
-        scalar_fn=_scalar_3164)
+        scalar_fn=_scalar_3164, max_len=max_len)
 
 
 def encode_ltsv_capnp_block(
@@ -592,7 +593,7 @@ def encode_ltsv_capnp_block(
         (zero, zero, np.zeros(R, dtype=bool)),   # sd_id is None for ltsv
         (name_a, name_l2, val_a, val_l, pvalid, has_sd),
         ts, fac, sev, encoder, merger, suffix, syslen,
-        scalar_fn=scalar_fn)
+        scalar_fn=scalar_fn, max_len=max_len)
 
 
 def encode_gelf_capnp_block(
@@ -644,7 +645,7 @@ def encode_gelf_capnp_block(
         return _capnp_assemble(chunk_bytes, starts64, lens64, n, cand,
                                ridx, [], None, None, None, None, None,
                                encoder, merger, suffix, syslen,
-                               scalar_fn=_scalar_gelf)
+                               scalar_fn=_scalar_gelf, max_len=max_len)
 
     # timestamps: per-unique float of the span (dedup dict)
     from .block_common import span_f64_values
@@ -738,4 +739,4 @@ def encode_gelf_capnp_block(
         chunk_bytes, starts64, lens64, n, cand, ridx, texts,
         (zero, zero, np.zeros(R, dtype=bool)),   # sd_id is None for gelf
         pairs, ts, fac, sev, encoder, merger, suffix, syslen,
-        scalar_fn=_scalar_gelf, typed=typed)
+        scalar_fn=_scalar_gelf, typed=typed, max_len=max_len)

@@ -95,7 +95,7 @@ def dns_screen(chunk_bytes, starts, orig_lens, out, n_real: int,
 
 
 def _assemble_fixed(chunk_bytes, s, cols_fn, fmt_fn, suffix, syslen,
-                    merger, encoder):
+                    merger, encoder, max_len=None):
     """Shared fixed-skeleton assembly: ``cols_fn(ridx, consts_offsets,
     cbase, ts_off, ts_len)`` returns the per-row (src, len) column
     grid."""
@@ -137,7 +137,8 @@ def _assemble_fixed(chunk_bytes, s, cols_fn, fmt_fn, suffix, syslen,
             final_buf = body.tobytes()
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=_scalar_dns)
+                        syslen, merger, encoder, scalar_fn=_scalar_dns,
+                        max_len=max_len)
 
 
 def encode_dns_gelf_block(
@@ -200,7 +201,7 @@ def encode_dns_gelf_block(
             )
 
     return _assemble_fixed(chunk_bytes, s, Cols(), json_f64, suffix,
-                           syslen, merger, encoder)
+                           syslen, merger, encoder, max_len=max_len)
 
 
 def encode_dns_ltsv_block(
@@ -266,4 +267,4 @@ def encode_dns_ltsv_block(
             )
 
     return _assemble_fixed(chunk_bytes, s, Cols(), display_f64, suffix,
-                           syslen, merger, encoder)
+                           syslen, merger, encoder, max_len=max_len)

@@ -405,4 +405,4 @@ def encode_rfc5424_gelf_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder)
+                        syslen, merger, encoder, max_len=max_len)

@@ -458,4 +458,5 @@ def encode_gelf_gelf_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=_scalar_gelf)
+                        syslen, merger, encoder, scalar_fn=_scalar_gelf,
+                        max_len=max_len)

@@ -416,7 +416,8 @@ def encode_jsonl_gelf_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=_scalar_jsonl)
+                        syslen, merger, encoder, scalar_fn=_scalar_jsonl,
+                        max_len=max_len)
 
 
 def encode_jsonl_ltsv_block(
@@ -473,7 +474,7 @@ def encode_jsonl_ltsv_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand,
                             ridx, b"", np.zeros(1, dtype=np.int64),
                             None, suffix, syslen, merger, encoder,
-                            scalar_fn=_scalar_jsonl)
+                            scalar_fn=_scalar_jsonl, max_len=max_len)
 
     scratch, ts_off, ts_len = span_f64_scratch(
         chunk_bytes, s["tsa_all"][ridx], s["tsb_all"][ridx], display_f64)
@@ -529,4 +530,4 @@ def encode_jsonl_ltsv_block(
     return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                       src, cbase, pc, pair_flat, o_col, o_tab,
                       cols, (), suffix, syslen, merger, encoder,
-                      scalar_fn=_scalar_jsonl)
+                      scalar_fn=_scalar_jsonl, max_len=max_len)

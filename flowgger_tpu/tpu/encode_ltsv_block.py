@@ -178,16 +178,17 @@ def encode_rfc5424_ltsv_block(
         )
         return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                           src, cbase, pc, pair_flat, o_col, o_tab,
-                          cols, (), suffix, syslen, merger, encoder)
+                          cols, (), suffix, syslen, merger, encoder,
+                          max_len=max_len)
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder)
+                        syslen, merger, encoder, max_len=max_len)
 
 
 def _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx, src, cbase,
                pc, pair_flat, o_col, o_tab, fixed_cols, tabfix,
-               suffix, syslen, merger, encoder, scalar_fn=None):
+               suffix, syslen, merger, encoder, scalar_fn=None, max_len=None):
     """Segment assembly shared by every →LTSV wrapper.
 
     Per row: pairs (4 segs each: name ':' value '\\t'), then
@@ -249,7 +250,7 @@ def _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx, src, cbase,
     kw = {} if scalar_fn is None else {"scalar_fn": scalar_fn}
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, **kw)
+                        syslen, merger, encoder, **kw, max_len=max_len)
 
 
 def encode_ltsv_ltsv_block(
@@ -318,7 +319,7 @@ def encode_ltsv_ltsv_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=scalar_fn)
+                            scalar_fn=scalar_fn, max_len=max_len)
     st = starts64[ridx]
 
     def sp(a_key, b_key):
@@ -380,7 +381,7 @@ def encode_ltsv_ltsv_block(
     return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                       src, cbase, pc, pair_flat, o_col, o_tab,
                       cols, (8,), suffix, syslen, merger, encoder,
-                      scalar_fn=scalar_fn)
+                      scalar_fn=scalar_fn, max_len=max_len)
 
 
 def encode_rfc3164_ltsv_block(
@@ -423,7 +424,7 @@ def encode_rfc3164_ltsv_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=_scalar_3164)
+                            scalar_fn=_scalar_3164, max_len=max_len)
     st = starts64[ridx]
     host_a = st + np.asarray(out["host_start"])[:n][ridx].astype(np.int64)
     host_l = (np.asarray(out["host_end"])[:n][ridx].astype(np.int64)
@@ -477,7 +478,7 @@ def encode_rfc3164_ltsv_block(
     return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                       src, cbase, pc, None, o_col, o_tab,
                       cols, (), suffix, syslen, merger, encoder,
-                      scalar_fn=_scalar_3164)
+                      scalar_fn=_scalar_3164, max_len=max_len)
 
 
 def encode_gelf_ltsv_block(
@@ -532,7 +533,7 @@ def encode_gelf_ltsv_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=_scalar_gelf)
+                            scalar_fn=_scalar_gelf, max_len=max_len)
 
     # timestamps: dedupe span texts, per-unique float + Display
     from .block_common import span_f64_scratch
@@ -601,4 +602,4 @@ def encode_gelf_ltsv_block(
     return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                       src, cbase, pc, pair_flat, o_col, o_tab,
                       cols, (), suffix, syslen, merger, encoder,
-                      scalar_fn=_scalar_gelf)
+                      scalar_fn=_scalar_gelf, max_len=max_len)

@@ -449,4 +449,5 @@ def encode_ltsv_gelf_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=scalar_fn)
+                        syslen, merger, encoder, scalar_fn=scalar_fn,
+                        max_len=max_len)

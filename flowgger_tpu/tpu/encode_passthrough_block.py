@@ -140,4 +140,5 @@ def _passthrough_block(chunk_bytes, starts64, lens64, out, n, max_len,
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=scalar_fn)
+                        syslen, merger, encoder, scalar_fn=scalar_fn,
+                        max_len=max_len)

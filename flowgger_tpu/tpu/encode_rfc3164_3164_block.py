@@ -133,4 +133,5 @@ def encode_rfc3164_3164_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=_scalar_3164)
+                        syslen, merger, encoder, scalar_fn=_scalar_3164,
+                        max_len=max_len)

@@ -122,7 +122,7 @@ def encode_rfc5424_rfc5424_block(
             return finish_block(chunk_bytes, starts64, lens64, n, cand,
                                 ridx, final_buf, row_off,
                                 prefix_lens_tier, suffix, syslen, merger,
-                                encoder)
+                                encoder, max_len=max_len)
 
     if R:
         chunk_arr = np.frombuffer(chunk_bytes, dtype=np.uint8)
@@ -272,7 +272,7 @@ def encode_rfc5424_rfc5424_block(
 
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder)
+                        syslen, merger, encoder, max_len=max_len)
 
 
 
@@ -312,7 +312,7 @@ def encode_rfc3164_rfc5424_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=_scalar_3164)
+                            scalar_fn=_scalar_3164, max_len=max_len)
     st = starts64[ridx]
     host_a = st + np.asarray(out["host_start"])[:n][ridx].astype(np.int64)
     host_l = (np.asarray(out["host_end"])[:n][ridx].astype(np.int64)
@@ -353,13 +353,14 @@ def encode_rfc3164_rfc5424_block(
     return _ltsv_core(chunk_bytes, starts64, lens64, n, cand, ridx,
                       src, cbase, pc, None, 0, 0,
                       cols, (), suffix, syslen, merger, encoder,
-                      scalar_fn=_scalar_3164)
+                      scalar_fn=_scalar_3164, max_len=max_len)
 
 
 def _rfc5424_sd_assemble(chunk_bytes, chunk_arr, src, offs, starts64,
                          lens64, n, cand, ridx, pc, ts_off, ts_len,
                          host_a, host_l, msg_a, msg_l, has_msg, pairs,
-                         suffix, syslen, merger, encoder, scalar_fn):
+                         suffix, syslen, merger, encoder, scalar_fn,
+                         max_len=None):
     """Shared RFC5424 row assembly for the Record-shaped routes
     (gelf→RFC5424, ltsv→RFC5424): constant <13> PRI head, rfc3339-ms
     stamp, host, " - - " proc/msgid slots, one SD block (or "- "),
@@ -443,7 +444,8 @@ def _rfc5424_sd_assemble(chunk_bytes, chunk_arr, src, offs, starts64,
         final_buf = body.tobytes()
     return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                         final_buf, row_off, prefix_lens_tier, suffix,
-                        syslen, merger, encoder, scalar_fn=scalar_fn)
+                        syslen, merger, encoder, scalar_fn=scalar_fn,
+                        max_len=max_len)
 
 
 def encode_gelf_rfc5424_block(
@@ -491,7 +493,7 @@ def encode_gelf_rfc5424_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=_scalar_gelf)
+                            scalar_fn=_scalar_gelf, max_len=max_len)
 
     # timestamps: per-unique span parse + rfc3339-ms format, one pass
     from .block_common import span_f64_scratch
@@ -538,7 +540,8 @@ def encode_gelf_rfc5424_block(
     return _rfc5424_sd_assemble(
         chunk_bytes, chunk_arr, chunk_src, offs[:11], starts64, lens64,
         n, cand, ridx, pc, ts_off, ts_len, host_a, host_l, msg_a, msg_l,
-        has_msg, pairs, suffix, syslen, merger, encoder, _scalar_gelf)
+        has_msg, pairs, suffix, syslen, merger, encoder, _scalar_gelf,
+        max_len=max_len)
 
 
 def encode_ltsv_rfc5424_block(
@@ -602,7 +605,7 @@ def encode_ltsv_rfc5424_block(
         return finish_block(chunk_bytes, starts64, lens64, n, cand, ridx,
                             b"", np.zeros(1, dtype=np.int64), None,
                             suffix, syslen, merger, encoder,
-                            scalar_fn=scalar_fn)
+                            scalar_fn=scalar_fn, max_len=max_len)
     st = starts64[ridx]
 
     ts_vals = ltsv_ts_vals(out, n, ridx, chunk_bytes, starts64)
@@ -639,4 +642,5 @@ def encode_ltsv_rfc5424_block(
     return _rfc5424_sd_assemble(
         chunk_bytes, chunk_arr, chunk_src, offs, starts64, lens64, n,
         cand, ridx, pc, ts_off, ts_len, host_a, host_l, msg_a, msg_l,
-        has_msg, pairs, suffix, syslen, merger, encoder, scalar_fn)
+        has_msg, pairs, suffix, syslen, merger, encoder, scalar_fn,
+        max_len=max_len)

@@ -486,6 +486,7 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
                      detail="compile watchdog (gather)")
         raise FramingDeclined("compile watchdog (gather)") from None
     _metrics.inc("framing_rows", n)
+    _pack.note_overlen(orig_lens, max_len)
     if gather_out is not None:
         # rows that went through the Pallas tier end to end (spans may
         # have too, but the gather is the [rows, max_len] pass that

@@ -191,12 +191,27 @@ def _note_stage(name: str, seconds: float) -> None:
     _metrics.add_seconds("pack_stage_seconds", seconds)
 
 
+def note_overlen(lens: np.ndarray, max_len: int) -> None:
+    """Count one packed batch's rows longer than the device's row, and
+    the bytes past ``max_len`` that the device never sees (the block
+    encoders hand such a row to the scalar oracle whole)."""
+    over = lens > max_len
+    k = int(np.count_nonzero(over))
+    if k:
+        from ..utils.metrics import registry as _metrics
+
+        _metrics.inc("overlen_rows", k)
+        _metrics.inc("overlen_bytes_clipped",
+                     int(lens[over].sum(dtype=np.int64)) - k * max_len)
+
+
 def _finish(chunk: bytes, starts: np.ndarray, lens: np.ndarray, n: int,
             max_len: int):
     import time as _time
 
     np_rows = bucket_rows(n)
     _note_shape(np_rows, max_len)
+    note_overlen(lens, max_len)
     t0 = _time.perf_counter()
     batch, lens_p = _pack_dense(chunk, starts, lens, max_len, np_rows)
     _note_stage("pack_copy_seconds", _time.perf_counter() - t0)

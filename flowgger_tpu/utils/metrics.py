@@ -187,6 +187,18 @@ _COUNTERS = (
     "h2d_bytes", "packed_line_bytes", "d2h_bytes", "d2h_calls",
     "d2h_prefetched",
     "batch_rows_real", "batch_rows_padded",
+    # lines longer than the device's row (input.tpu_max_line_len),
+    # counted a batch at a time where the batch is packed (tpu/pack.py,
+    # tpu/framing.py): the rows the device sees clipped and the bytes
+    # past the row's width that it never sees; and the rows that a
+    # block encoder handed to the scalar oracle one by one
+    # (tpu/block_common.py finish_block), for any cause: how many, the
+    # bytes they came to at the sink, and those of them that were
+    # over-length, so that splice_rows - splice_rows_overlen is what
+    # fell back for high bytes, pair caps and escapes.  fallback_rows
+    # keeps its meaning: splice_rows less the rows that are not UTF-8
+    "overlen_rows", "overlen_bytes_clipped",
+    "splice_rows", "splice_rows_overlen", "splice_bytes_out",
 )
 
 # cumulative per-stage wall-clock accumulators (add_seconds)
@@ -200,6 +212,9 @@ _SECONDS_NAMES = (
     # and device are the economics' probes
     "route_pop_seconds_host", "route_pop_seconds_fused",
     "route_pop_seconds_device",
+    # finish_block's scalar loop and the joining of its pieces, a part
+    # of encode_seconds on the lane fetcher's thread
+    "splice_seconds",
 )
 
 # point-in-time gauges with literal names (set_gauge/init_gauge)

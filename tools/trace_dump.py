@@ -19,9 +19,10 @@ Three sources, one output (Perfetto / chrome://tracing loadable):
     python tools/trace_dump.py --fleet 127.0.0.1:8476 -o fleet.json
 
 A batch's sub-spans (``window_wait``, ``h2d``, ``device_wait``, one
-``d2h`` per copy) follow its stages as ``cat: "sub"`` events with their
-``parent`` stage in ``args``: same thread, contained in time, so the
-viewer nests them under it.
+``d2h`` per copy, one ``splice`` where rows took the scalar oracle)
+follow its stages as ``cat: "sub"`` events with their ``parent`` stage
+in ``args``: same thread, contained in time, so the viewer nests them
+under it.
 
 Without ``-o`` the document prints to stdout.  Exit codes: 0 dumped,
 2 unreadable source / bad arguments (lint-style, so a soak-run script
