@@ -56,7 +56,8 @@ LIMITS = {"missing": 0, "unexpected": 0, "out_of_order": 0,
 
 def in_children(work, jobs):
     """Run ``refchunk.py`` once per job, all at once, each in a process
-    that cannot see the chip; returns what each wrote."""
+    that cannot see the chip; returns what each wrote, its own
+    ``peak_rss`` with it."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     procs = []
     for k, job in enumerate(jobs):
@@ -76,6 +77,16 @@ def even_cuts(weights, parts):
     total = np.cumsum(weights)
     marks = np.searchsorted(total, total[-1] * np.arange(1, parts) / parts)
     return [0, *(int(m) + 1 for m in marks), len(weights)]
+
+
+def sink_shares(end, parts=SINK_CHILDREN):
+    """The sink file cut at records' starts into ``parts`` byte ranges
+    of about as many records each; ``end``: each record's terminator's
+    offset."""
+    cuts = even_cuts(np.ones(len(end)), parts)
+    starts = np.concatenate(([0], end + 1))
+    return [(int(starts[a]), int(starts[b]))
+            for a, b in zip(cuts[:-1], cuts[1:])]
 
 
 def written(log):
@@ -124,9 +135,10 @@ def sample_rows(log, seed):
 def compare(work, sink, log, window, seed):
     """Returns the numbers compared, and what the metrics are made of:
     ``attempted`` well-formed lines written in the whole run,
-    ``sampled`` of them compared byte for byte, and of the well-formed
+    ``sampled`` of them compared byte for byte, of the well-formed
     lines due in the window the mean ``line_bytes`` and
-    ``record_bytes``."""
+    ``record_bytes``, and ``children_rss``: the peak resident bytes
+    each child says it reached, by what it did."""
     off = np.load(os.path.join(work, "pool.npz"))["line_off"]
     n_pool = len(off) - 1
     cuts = np.linspace(0, n_pool, REF_CHILDREN + 1).astype(int)
@@ -141,10 +153,8 @@ def compare(work, sink, log, window, seed):
             jobs.append(("expect", work, rows, "-"))
     n_expect = len(jobs)
     if len(sink.end):
-        cuts = even_cuts(np.ones(len(sink.end)), SINK_CHILDREN)
-        starts = np.concatenate(([0], sink.end + 1))
-        jobs += [("sink", sink.path, starts[a], starts[b])
-                 for a, b in zip(cuts[:-1], cuts[1:])]
+        jobs += [("sink", sink.path, a, b)
+                 for a, b in sink_shares(sink.end)]
     parts = in_children(work, jobs)
 
     def cat(key, some, dtype):
@@ -170,6 +180,11 @@ def compare(work, sink, log, window, seed):
     t0, t1 = window
     cand = exp_line[(exp_due >= t0) & (exp_due < t1)]
     return got, {"attempted": int(keep.sum()), "sampled": len(want_fp),
+                 "children_rss": {
+                     kind: [int(p["peak_rss"]) for p in some]
+                     for kind, some in (("pool", parts[:n_pool_jobs]),
+                                        ("expect", parts[n_pool_jobs:n_expect]),
+                                        ("sink", parts[n_expect:]))},
                  "line_bytes": float((off[1:] - off[:-1] - 1)[cand].mean())
                  if len(cand) else None,
                  # the pool's record, placeholder timestamp and all: the

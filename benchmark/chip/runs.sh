@@ -27,7 +27,7 @@ for seed in "${seeds[@]}"; do
   code=$?
   [ $code -ne 0 ] && rc=$code
   echo "== $tag seed $seed seconds $seconds trace $trace $*: exit $code, $(( $(date +%s) - t0 )) s wall"
-  grep -E "shape walk|warm-up|set-up:|records at the sink in each second|cores busy|XLA compiles inside|declines:|comparison:|generator:|run:" "$out.out" | cut -c1-700
+  grep -E "shape walk|warm-up|set-up:|records at the sink in each second|cores busy|XLA compiles inside|declines:|comparison:|memory:|generator:|run:" "$out.out" | cut -c1-700
   tail -n 1 "$out.err" | cut -c1-400
   tail -n 1 "$out.out" | cut -c1-3000
 done

@@ -43,6 +43,8 @@ sys.path[0] = ROOT
 # nothing, and every machine starts a checkout's first run cold alike
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
+from benchmark import peakrss  # noqa: E402
+
 SLICE_S = 4.0           # one warm-up slice
 WARM_MAX_S = 150.0      # then the window opens as things stand
 COLD_S = 1.0            # a compile this long holds the stream up
@@ -174,6 +176,7 @@ class Child:
         if answers:
             os.close(wfd)
         self.buf = b""
+        self.peak_rss = None    # the kernel's count, once it has ended
 
     def tell(self, word):
         self.proc.stdin.write(word.encode() + b"\n")
@@ -199,17 +202,19 @@ class Child:
         return msg
 
     def reap(self, timeout=60.0):
-        """Wait for the process to end; kill it if it will not."""
+        """Wait for the process to end; kill it if it will not.  Keeps
+        what the kernel says it peaked at (``peakrss.wait``: sound for
+        a child started before this process grew)."""
         if self.proc.stdin and not self.proc.stdin.closed:
             try:
                 self.proc.stdin.close()
             except BrokenPipeError:
                 say("child's command pipe was already broken")
         try:
-            rc = self.proc.wait(timeout)
+            rc, self.peak_rss = peakrss.wait(self.proc, timeout)
         except subprocess.TimeoutExpired:
             self.proc.kill()
-            rc = self.proc.wait()
+            rc, self.peak_rss = peakrss.wait(self.proc)
         if self.rfd is not None:
             os.close(self.rfd)
             self.rfd = None
@@ -584,7 +589,12 @@ class Run:
         cols = np.load(os.path.join(self.work, "sink.npz"))
         sink = check.Sink(self.sink_path, cols)
         log = np.load(os.path.join(self.work, "gen_log.npy"))
-        t_cmp = time.time()
+        sink_bytes = os.path.getsize(self.sink_path)
+        # said before the children start: a run that is stopped for its
+        # memory inside the comparison leaves at least this
+        say(f"comparison: the sink file holds {sink_bytes} bytes in "
+            f"{len(sink.ts)} records")
+        rss_before, t_cmp = peakrss.own(), time.time()
         got, made = check.compare(self.work, sink, log, self.window,
                                   self.args.seed)
         say(f"comparison: {made['attempted']} well-formed lines written, "
@@ -592,6 +602,16 @@ class Run:
             f"by line, {len(sink.ts)} records of the sink, "
             f"{time.time() - t_cmp:.1f}s in "
             f"{check.REF_CHILDREN + check.SINK_CHILDREN} children")
+        by_kind = made.pop("children_rss")
+        children = [n for some in by_kind.values() for n in some]
+        gb = peakrss.gb
+        say(f"memory: collector {gb(rss_before)} before the comparison, "
+            f"{gb(peakrss.own())} after; generator {gb(self.gen.peak_rss)}; "
+            f"sink reader {gb(self.tail.peak_rss)}; comparison children: "
+            f"sum {gb(sum(children))}, largest {gb(max(children))} "
+            f"({len(children)} children, the sink's "
+            f"{len(by_kind['sink'])}: sum {gb(sum(by_kind['sink']))}); "
+            f"sink file {gb(sink_bytes)}, {len(sink.ts)} records")
         f, (t0, t1) = self.facts, self.window
         e2e = {"setup_s": f["setup_s"],
                "lines_per_s": stats.rate(sink.seen, t0, t1)}

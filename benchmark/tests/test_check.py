@@ -185,3 +185,108 @@ def test_one_stream_reads_what_it_always_read(sent, name):
                 np.random.default_rng(7).permutation(len(records))]
     got, _ = judge(work, log, shuffled)
     assert got["out_of_order"] == old_count(shuffled) > 1000
+
+
+# -- the sink's fingerprints, a chunk at a time -------------------------------
+
+def whole_read(data):
+    """``refchunk.sink`` as it was before PR 33: the share read whole
+    and split, twice the share in memory.  Kept here as the oracle."""
+    return refchunk.fingerprints(data.split(b"\0")[:-1])
+
+
+def _seeded(seed, n=300):
+    rng = np.random.default_rng(seed)
+    return b"".join(rng.integers(1, 256, int(k), np.uint8).tobytes() + b"\0"
+                    for k in rng.integers(0, 120, n))
+
+
+# what a sink can hold, by name: each ends as the case says
+SINKS = {
+    "seeded": _seeded(1),
+    "seeded_2": _seeded(2**31 + 9),
+    "empty_records": b"\0\0ab\0\0\0c\0\0",
+    "only_nuls": b"\0" * 41,
+    "one_long_record": b"x" * 5000 + b"\0",
+    "long_among_short": b"a\0" + b"y" * 3000 + b"\0b\0\0" + b"z" * 2047
+                        + b"\0",
+    "trailing_piece": _seeded(3, 40) + b"cut short, no terminator",
+    "no_terminator_at_all": b"never closed",
+    "nothing": b"",
+    # a NUL on a chunk's last byte and on the next one's first, for
+    # chunks of 4 and 8
+    "nul_at_the_seams": b"abc\0\0efg\0ijk\0\0nop\0",
+}
+CHUNKS = [1, 2, 3, 4, 5, 7, 8, 64, 1000, 4096, refchunk.SINK_CHUNK]
+
+
+@pytest.mark.parametrize("chunk", CHUNKS)
+@pytest.mark.parametrize("name", SINKS)
+def test_a_share_read_in_chunks_is_the_share_read_whole(tmp_path, name,
+                                                        chunk):
+    data = SINKS[name]
+    path = tmp_path / "sink.gelf"
+    path.write_bytes(data)
+    want = whole_read(data)
+    got = refchunk.sink_fingerprints(str(path), 0, len(data), chunk)
+    assert got.dtype == np.uint64 and got.tolist() == want.tolist()
+    # shares as ``compare`` cuts them: at records' starts, one share a
+    # child; and a share of no bytes
+    ends = np.flatnonzero(np.frombuffer(data, np.uint8) == 0)
+    if not len(ends):
+        return
+    shares = check.sink_shares(ends, 3)
+    parts = [refchunk.sink_fingerprints(str(path), a, b, chunk)
+             for a, b in shares]
+    assert sum(map(len, parts)) == len(ends)
+    assert np.concatenate(parts).tolist() == want.tolist()
+    for (a, b), part in zip(shares, parts):
+        assert part.tolist() == whole_read(data[a:b]).tolist()
+    first = shares[0][1]
+    assert len(refchunk.sink_fingerprints(str(path), first, first,
+                                          chunk)) == 0
+
+
+def test_a_range_past_the_files_end_stops_at_the_end(tmp_path):
+    path = tmp_path / "sink.gelf"
+    path.write_bytes(b"ab\0cd\0ef")
+    assert refchunk.sink_fingerprints(str(path), 0, 10_000, 4).tolist() \
+        == whole_read(b"ab\0cd\0ef").tolist()
+
+
+@pytest.mark.slow
+def test_a_sink_child_holds_a_chunk_not_its_share(tmp_path):
+    """A child over a share of 1 GiB of 822 B records (the size of
+    ``backfill.longlines``'s) peaks under 0.4 GB; the whole read peaked
+    at 2.06 times its share (PERF.md, PR 33)."""
+    import os
+    import subprocess
+    import sys
+
+    from benchmark import peakrss
+
+    rng = np.random.default_rng(33)
+    block = b"".join(rng.integers(1, 256, 821, np.uint8).tobytes() + b"\0"
+                     for _ in range(1275))          # 1,048,050 B
+    path, out = tmp_path / "sink.gelf", tmp_path / "chunk.npz"
+    with open(path, "wb") as f:
+        for _ in range(1025):
+            f.write(block)
+    size = os.path.getsize(path)
+    assert size >= 1 << 30
+    p = subprocess.Popen(
+        [sys.executable, os.path.join(check.HERE, "refchunk.py"), "sink",
+         str(path), "0", str(size), str(out)], stdin=subprocess.DEVNULL,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    rc, kernel_says = peakrss.wait(p, 600)
+    assert rc == 0
+    made = np.load(out)
+    assert len(made["fp"]) == 1025 * 1275
+    assert (made["fp"][:1275] == whole_read(block)).all()
+    assert (made["fp"][-1275:] == made["fp"][:1275]).all()
+    # its own word (the most it held at its fullest instants) and the
+    # kernel's, which is never under what this test's process held when
+    # it started the child, and that is under 0.4 GB too (``statm``
+    # counts in batches of pages: a percent of slack)
+    assert 60e6 < int(made["peak_rss"]) < 0.4e9
+    assert int(made["peak_rss"]) <= 1.02 * kernel_says < 0.4e9

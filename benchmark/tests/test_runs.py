@@ -14,6 +14,7 @@ and no exchange between chips.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -53,6 +54,13 @@ def run(root, cell, *more, seed=2**31 + 77):
     return json.loads(p.stdout.splitlines()[-1]), p
 
 
+GB_ = r"\d+\.\d{3} GB"
+MEMORY = re.compile(
+    rf"memory: collector {GB_} before the comparison, {GB_} after; "
+    rf"generator {GB_}; sink reader {GB_}; comparison children: sum {GB_}, "
+    rf"largest {GB_} \(\d+ children, the sink's \d+: sum {GB_}\); "
+    rf"sink file {GB_}, \d+ records$")
+
 # the admitted cell, and the one that takes the tcp way in
 CELLS = ["backfill.drain", relay.CELL["name"]]
 
@@ -61,9 +69,22 @@ CELLS = ["backfill.drain", relay.CELL["name"]]
 @pytest.mark.parametrize("cell", CELLS)
 def test_a_sound_run_is_correct(roots, cell, trace):
     result, p = run(roots[cell], cell, "--trace", trace)
-    assert list(result)[:5] == ["correct", "attempted", "failed", "metrics",
-                                "device"]
-    assert list(result)[-1] == "compared"
+    # the parent's keys, in its order: the memory line added none
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", *(["breakdown"] if trace == "1"
+                                        else []), "compared"]
+    assert set(result["device"]) == {
+        "platform", "kind", "count", "memory_peak_bytes",
+        *(["busy_s", "window_s"] if trace == "1" else [])}
+    out = p.stdout.splitlines()
+    said = [k for k, l in enumerate(out) if "] memory: " in l]
+    assert len(said) == 1
+    assert MEMORY.search(out[said[0]]), out[said[0]]
+    metric_lines = [k for k, l in enumerate(out)
+                    if any(f"] {m} = " in l for m in result["metrics"])]
+    assert len(metric_lines) == len(result["metrics"])
+    assert said[0] < min(metric_lines)
+    assert "not read" not in out[said[0]]       # every process's peak
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 10_000
     assert result["device"]["platform"] == "cpu"     # named, never a TPU
