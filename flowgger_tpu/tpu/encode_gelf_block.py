@@ -16,11 +16,14 @@ core) for the flagship route.  Two engines produce identical bytes:
   duplicate or >48-byte SD names (vectorized sort-key limits); those
   rows re-run the scalar oracle instead.
 
-Rows outside the tier (kernel-flagged, oversized, non-ASCII, SD values
-needing unescape) re-run the scalar oracle (decoder → GelfEncoder), so
-observable bytes stay identical to the reference semantics
-(gelf_encoder.rs:51-116) in every case; differential tests drive both
-engines against the Record path.
+Rows outside the tier (kernel-flagged, non-ASCII, SD values needing
+unescape on the numpy engine) re-run the scalar oracle (decoder →
+GelfEncoder), so observable bytes stay identical to the reference
+semantics (gelf_encoder.rs:51-116) in every case; differential tests
+drive both engines against the Record path.  A row longer than the
+device's row stays in the tier where its clipped decode holds the whole
+header and MSG's start: only MSG's end is read from the bytes past the
+clip, on the host.
 
 Framing (merger/mod.rs:30-32) is pre-applied: line/nul suffixes ride
 the tail constant and syslen's length prefix is rendered inline; the
@@ -44,6 +47,7 @@ import numpy as np
 from json.encoder import encode_basestring as _quote
 
 from ..mergers import Merger
+from ..utils.metrics import registry as _metrics
 from ..utils.rustfmt import json_f64
 from .assemble import (
     build_source,
@@ -92,6 +96,27 @@ _FIXED_KEYS = ("application_name", "full_message", "host", "level",
                "process_id", "sd_id", "short_message", "timestamp",
                "version")
 
+
+def _overlen_tails(chunk_arr, starts64, lens64, max_len, rows, clip_end):
+    """What the device never saw of the over-length ``rows``: the bytes
+    past ``max_len``, gathered into one array and read column-wise.
+    Returns (ascii [k] bool: no byte >= 0x80 there; trim_end [k]: the
+    whole line's right-trimmed end from the row's start, ``clip_end``
+    (the clipped decode's) where the tail is all blanks)."""
+    tl = lens64[rows] - max_len
+    off = exclusive_cumsum(tl)
+    lo = off[:-1]
+    pos = np.arange(off[-1], dtype=np.int64)
+    idx = np.repeat(starts64[rows] + max_len - lo, tl)
+    idx += pos
+    tail = chunk_arr[idx]
+    ascii_tail = np.maximum.reduceat(tail, lo) < 0x80
+    # Python's str.strip() set over ASCII, as the kernel's is_ws has it
+    # (tpu/rfc5424.py): 9-13 and 28-32
+    blank = (tail <= 32) & ((tail >= 28) | ((tail >= 9) & (tail <= 13)))
+    pos[blank] = -1
+    last = np.maximum.reduceat(pos, lo)     # -1: no other byte in the tail
+    return ascii_tail, np.where(last >= 0, max_len + 1 + last - lo, clip_end)
 
 def gelf_extra_slots(extra):
     """Render ``[output.gelf_extra]`` pairs into the static insertion
@@ -193,9 +218,31 @@ def encode_rfc5424_gelf_block(
     name_start = np.asarray(out["name_start"])[:n]
     name_end = np.asarray(out["name_end"])[:n]
 
-    cand = ok & (lens64 <= max_len) & ~has_high
-
     chunk_arr = np.frombuffer(chunk_bytes, dtype=np.uint8)
+    trim_end = np.asarray(out["trim_end"])[:n]
+
+    # A row longer than the device's row was decoded from its first
+    # max_len bytes.  That decode speaks for the whole line where MSG
+    # has begun inside the clip (a non-blank byte of it, so
+    # msg_trim_start is the true one; the kernel's sd_msg_ok already
+    # refuses structured data that runs to the clip's edge): the
+    # decoder takes all that follows as MSG without looking at it.
+    # Only where the line ends right-trimmed, and whether a byte >= 0x80
+    # lies past the clip (to the scalar oracle, as has_high rows go), is
+    # then read from the tail; the spans index chunk_bytes, which holds
+    # the line whole.
+    long_ = lens64 > max_len
+    cand = ok & ~has_high & (
+        ~long_ | (np.asarray(out["msg_trim_start"])[:n] < trim_end))
+    over = np.flatnonzero(cand & long_)
+    if over.size:
+        ascii_tail, over_end = _overlen_tails(
+            chunk_arr, starts64, lens64, max_len, over, trim_end[over])
+        cand[over[~ascii_tail]] = False
+        trim_end = trim_end.copy()
+        trim_end[over] = over_end
+        # every reader below takes these rows' end from here
+        out = {**out, "trim_end": trim_end}
     # the native row assembler predates the extras slots: extras run on
     # the numpy segment engine (still columnar, still ~20x the Record
     # path)
@@ -237,6 +284,8 @@ def encode_rfc5424_gelf_block(
 
     ridx = np.flatnonzero(cand)
     R = ridx.size
+    if over.size:
+        _metrics.inc("overlen_rows_kept", int(np.count_nonzero(cand[over])))
     final_buf = b""
     row_off = np.zeros(1, dtype=np.int64)
     prefix_lens_tier: Optional[np.ndarray] = None

@@ -196,9 +196,14 @@ _COUNTERS = (
     # bytes they came to at the sink, and those of them that were
     # over-length, so that splice_rows - splice_rows_overlen is what
     # fell back for high bytes, pair caps and escapes.  fallback_rows
-    # keeps its meaning: splice_rows less the rows that are not UTF-8
+    # keeps its meaning: splice_rows less the rows that are not UTF-8.
+    # overlen_rows_kept: the over-length rows that stayed on the
+    # columnar rfc5424 -> GELF encoder (tpu/encode_gelf_block.py), a
+    # batch at a time; on that route overlen_rows is overlen_rows_kept
+    # + splice_rows_overlen
     "overlen_rows", "overlen_bytes_clipped",
     "splice_rows", "splice_rows_overlen", "splice_bytes_out",
+    "overlen_rows_kept",
 )
 
 # cumulative per-stage wall-clock accumulators (add_seconds)

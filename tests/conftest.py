@@ -94,3 +94,17 @@ def session_pem(tmp_path_factory):
          "-subj", "/CN=localhost"],
         check=True, capture_output=True)
     return str(path)
+
+
+@pytest.fixture(params=["native", "numpy"])
+def gelf_engine(request, monkeypatch):
+    """The rfc5424 -> GELF block encoder's two engines: the native row
+    assembler, and the numpy segment engine that serves where the
+    library is absent."""
+    from flowgger_tpu import native
+
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "gelf_rows_available", lambda: False)
+    elif not native.gelf_rows_available():
+        pytest.skip("no native library")
+    return request.param

@@ -4,16 +4,20 @@ corpus (``benchmark/corpora/loghub_applog.json``: one line in twelve an
 exception with its stack folded into the line) against the benchmark's
 plain reference, which imports nothing of the program.
 
-An over-length row is clipped at pack, decoded as clipped, refused by
-the block encoder and served whole by the scalar oracle in
+An over-length row is clipped at pack and decoded as clipped.  Where
+the clip holds the whole header and a non-blank byte of MSG, and the
+bytes past it are ASCII, the row stays on the columnar encoder
+(``encode_gelf_block``), which reads MSG's end from the tail; every
+other over-length row is served whole by the scalar oracle in
 ``block_common.finish_block``, which joins its output with the columnar
 tier's in input order.  Held here: the sink's bytes and order for every
-line at three row widths, the joining's edges, the counters that say
-how many rows took that way and why, and the one ``splice`` sub-span a
-batch.  On the CPU this proves bytes and counts, never a rate.  The
-device encode tiers stay at ``auto`` where bytes are compared; the
+line at three row widths, on the native row assembler and the numpy
+engine, the joining's edges, the counters that say how many rows took
+which way and why, and the one ``splice`` sub-span a batch with a
+fallback row.  On the CPU this proves bytes and counts, never a rate.
+The device encode tiers stay at ``auto`` where bytes are compared; the
 counter and tracing cases pin the host block route, whose counts they
-are.
+are.  (The clip's every position: ``tests/test_encode_gelf_block.py``.)
 """
 
 import queue
@@ -83,6 +87,17 @@ def line_of(length, tail=b"", sd=b"-"):
     return head + b"x" * pad + tail
 
 
+def still_scalar():
+    """Over-length lines that the scalar oracle still serves: a byte
+    >= 0x80 past the clip, MSG beginning past the clip, structured data
+    crossing the clip."""
+    return [line_of(700, "caf\u00e9".encode()),
+            line_of(107).replace(b" ERROR java.io.IOException:", b"")
+            + b" " * 500 + b"begins late",
+            line_of(800, b" end", sd=b'[mdc@18060 thread="' + b"t" * 500
+                    + b'"]')]
+
+
 def run(lines_by_batch, extra="", frame="nul", max_len=None):
     """Each entry is flushed as a device batch of its own, the fetcher a
     batch behind the ingest thread; returns the sink's records as
@@ -150,6 +165,19 @@ def test_the_pools_sink_is_the_references_line_for_line(seed, max_len):
         (lens[lens > max_len] - max_len).sum())
 
 
+@pytest.mark.parametrize("frame", sorted(FRAMES))
+@pytest.mark.parametrize("seed", SEEDS)
+def test_the_pools_sink_on_each_engine_and_merger(
+        seed, frame, gelf_engine, host_route):
+    lines = pool_lines(seed)
+    assert_sink(batches_of(lines), frame=frame, extra=host_route)
+    # every over-length row of the pool is an ASCII stack trace whose
+    # MSG begins far inside the clip: none goes to the scalar oracle
+    assert registry.get("overlen_rows_kept") == sum(
+        len(ln) > 512 for ln in lines)
+    assert registry.get("splice_rows_overlen") == 0
+
+
 @pytest.mark.parametrize("seed", SEEDS)
 def test_the_same_with_tracing_on(seed):
     obs_trace.tracer.configure("ring", ring=64)
@@ -177,8 +205,8 @@ def test_each_merger_frames_the_spliced_rows_as_its_own(frame):
 
 # ---- the row's width, to the byte -------------------------------------------
 
-@pytest.mark.parametrize("length", [511, 512, 513])
-def test_a_row_of_just_the_rows_width(length, host_route):
+@pytest.mark.parametrize("length", [511, 512, 513, 4800])
+def test_a_row_of_just_the_rows_width(length, host_route, gelf_engine):
     lines = pool_lines(SEEDS[0], 64)
     short = [ln for ln in lines if len(ln) <= 512][:6]
     batch = short[:3] + [line_of(length, b" end")] + short[3:]
@@ -187,8 +215,9 @@ def test_a_row_of_just_the_rows_width(length, host_route):
     clipped = length > 512
     assert registry.get("overlen_rows") == int(clipped)
     assert registry.get("overlen_bytes_clipped") == max(0, length - 512)
-    assert registry.get("splice_rows") == int(clipped)
-    assert registry.get("splice_rows_overlen") == int(clipped)
+    assert registry.get("overlen_rows_kept") == int(clipped)
+    assert registry.get("splice_rows") == 0
+    assert registry.get("splice_rows_overlen") == 0
 
 
 # ---- the joining's edges ----------------------------------------------------
@@ -212,17 +241,32 @@ _PLACES = {
 
 @pytest.mark.parametrize("frame", ["nul", "syslen"])
 @pytest.mark.parametrize("place", sorted(_PLACES))
-def test_an_over_length_row_at_the_pieces_edges(place, frame):
+def test_an_over_length_row_at_the_pieces_edges(place, frame, gelf_engine):
     short, long_ = _short_and_long()
     batch = _PLACES[place](short, long_)
     assert_sink([batch], frame=frame)
     n_long = sum(len(ln) > 512 for ln in batch)
     assert registry.get("overlen_rows") == n_long
-    assert registry.get("splice_rows_overlen") == n_long
+    assert (registry.get("overlen_rows_kept")
+            + registry.get("splice_rows_overlen")) == n_long
+
+
+@pytest.mark.parametrize("frame", ["nul", "syslen"])
+@pytest.mark.parametrize("place", sorted(_PLACES))
+def test_a_row_that_still_falls_back_at_the_pieces_edges(
+        place, frame, gelf_engine, host_route):
+    short, long_ = _short_and_long()
+    batch = _PLACES[place](short + long_[:2], still_scalar())
+    assert_sink([batch], frame=frame, extra=host_route)
+    n_kept = sum(ln in long_ for ln in batch)
+    n_late = sum(ln in still_scalar() for ln in batch)
+    assert n_late and registry.get("overlen_rows") == n_kept + n_late
+    assert registry.get("overlen_rows_kept") == n_kept
+    assert registry.get("splice_rows_overlen") == n_late
 
 
 def test_an_over_length_row_the_scalar_decoder_refuses_is_dropped_once(
-        host_route, capfd):
+        host_route, gelf_engine, capfd):
     short, _long = _short_and_long()
     # a 13th month, past the row's width: the clipped decode and the
     # scalar decoder both refuse the line
@@ -235,13 +279,14 @@ def test_an_over_length_row_the_scalar_decoder_refuses_is_dropped_once(
     assert registry.get("input_lines") == 5
     assert registry.get("splice_rows") == 1
     assert registry.get("splice_rows_overlen") == 1
+    assert registry.get("overlen_rows_kept") == 0
     assert registry.get("splice_bytes_out") == 0
     # counted as a fallback row like every row the oracle was asked
     assert registry.get("fallback_rows") == 1
     assert capfd.readouterr().err.count("2026-13-21") == 1
 
 
-def test_an_over_length_row_with_an_escaped_sd_value():
+def test_an_over_length_row_with_an_escaped_sd_value(gelf_engine):
     short, _long = _short_and_long()
     sd = b'[mdc@18060 thread="main" class="te\\st sc\\"ript \\] x"]'
     esc = line_of(900, b" tail", sd=sd)
@@ -261,38 +306,82 @@ def test_over_length_rows_in_every_batch_of_a_stream_keep_their_order():
 # ---- the counters -----------------------------------------------------------
 
 # fallback_rows of the four batches of each seed's pool at the default
-# row width, as the parent commit's program counted them (PR 31: read
-# on a checkout of the parent with this file's helpers): the rows past
-# 512 B and the junk lines, every one of them valid UTF-8
-_PARENT_FALLBACK_ROWS = {11: 162, 2**31 + 5: 185, 2147492000: 138}
+# row width, as PR 31's program counted them, when every over-length
+# row went to the scalar oracle: the rows past 512 B and the junk lines,
+# every one of them valid UTF-8
+_PR31_FALLBACK_ROWS = {11: 162, 2**31 + 5: 185, 2147492000: 138}
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-def test_the_counters_say_what_the_pool_holds(seed, host_route):
-    lines = pool_lines(seed)
+def test_the_counters_say_what_the_pool_holds(seed, host_route, gelf_engine):
+    """The pool, and in its first batch three over-length rows that the
+    scalar oracle still serves."""
+    pool = pool_lines(seed)
+    late = still_scalar()
+    lines = late + pool
     lens = np.array([len(ln) for ln in lines])
     over = lens > 512
     junk = sum(ln == JUNK for ln in lines)
     want = expected(lines)
-    framed, _data = run(batches_of(lines), extra=host_route)
+    framed, _data = run([late + pool[:BATCH_LINES]]
+                        + batches_of(pool[BATCH_LINES:]), extra=host_route)
     assert framed == want
     snap = registry.snapshot()
-    assert snap["batch_rows_real"] == POOL_LINES
+    assert snap["batch_rows_real"] == len(lines)
     assert snap["overlen_rows"] == int(over.sum())
     assert snap["overlen_bytes_clipped"] == int((lens[over] - 512).sum())
-    assert snap["splice_rows"] == int(over.sum()) + junk
-    assert snap["splice_rows_overlen"] == int(over.sum())
-    by_line = {ln: len(reference.gelf(ln)) + 1 for ln in lines if
-               len(ln) > 512}
+    # the pool's over-length rows stay columnar; the three do not
+    assert snap["overlen_rows_kept"] == int(over.sum()) - len(late)
+    assert snap["splice_rows_overlen"] == len(late)
+    assert (snap["overlen_rows_kept"] + snap["splice_rows_overlen"]
+            == snap["overlen_rows"])
+    assert snap["splice_rows"] == len(late) + junk
     assert snap["splice_bytes_out"] == sum(
-        by_line[ln] for ln in lines if len(ln) > 512)
+        len(reference.gelf(ln)) + 1 for ln in late)
     assert snap["splice_seconds"] > 0
-    assert snap["fallback_rows"] == _PARENT_FALLBACK_ROWS[seed]
     assert snap["fallback_rows"] == snap["splice_rows"]
+    assert (snap["fallback_rows"] + snap["overlen_rows_kept"]
+            == _PR31_FALLBACK_ROWS[seed] + len(late))
     assert snap["decode_errors"] == junk
-    assert snap["input_lines"] == POOL_LINES
+    assert snap["input_lines"] == len(lines)
     # 7-9% of the rows, as the deployment's table has it
     assert 0.06 < snap["overlen_rows"] / snap["batch_rows_real"] < 0.10
+
+
+def test_the_kept_rows_are_counted_once_a_batch(host_route, monkeypatch):
+    short, long_ = _short_and_long()
+    batches = [short[:4] + long_[:3], short, long_[3:5] + still_scalar(),
+               still_scalar()[:1] + short[:2]]
+    calls = []
+    inc = registry.inc
+
+    def spy(name, n=1):
+        calls.append((name, n))
+        return inc(name, n)
+
+    monkeypatch.setattr(registry, "inc", spy)
+    framed, _data = run(batches, extra=host_route)
+    assert framed == expected([ln for b in batches for ln in b])
+    # one increment for each batch that holds an over-length row whose
+    # clipped decode could speak for the line (the last batch's one has
+    # a high byte in its tail); none for a batch without
+    assert [n for name, n in calls if name == "overlen_rows_kept"] == [
+        3, 2, 0]
+    assert [n for name, n in calls if name == "splice_rows_overlen"] == [3, 1]
+    assert registry.get("overlen_rows") == 9
+
+
+def test_a_batch_whose_over_length_rows_all_stay_columnar_opens_no_splice(
+        host_route, gelf_engine):
+    short, long_ = _short_and_long()
+    obs_trace.tracer.configure("ring", ring=4)
+    assert_sink([short[:3] + long_ + short[3:]], extra=host_route)
+    snap = registry.snapshot()
+    assert snap["overlen_rows"] == snap["overlen_rows_kept"] == len(long_)
+    assert snap["splice_rows"] == 0 and snap["splice_rows_overlen"] == 0
+    assert "splice_seconds" not in snap
+    (rec,) = obs_trace.tracer.snapshot()
+    assert _splices(rec) == []
 
 
 def test_a_batch_without_a_fallback_row_counts_no_splice(host_route):
@@ -308,7 +397,8 @@ def test_the_new_counters_are_in_the_registrys_snapshot_from_the_start():
 
     snap = registry.snapshot()
     for name in ("overlen_rows", "overlen_bytes_clipped", "splice_rows",
-                 "splice_rows_overlen", "splice_bytes_out"):
+                 "splice_rows_overlen", "splice_bytes_out",
+                 "overlen_rows_kept"):
         assert snap[name] == 0
         assert metrics.classify_metric(name) == "counter"
     assert metrics.classify_metric("splice_seconds") == "seconds"
@@ -323,10 +413,12 @@ def _splices(rec):
 def test_one_splice_sub_span_a_batch_with_fallback_rows_and_none_without(
         host_route):
     short, long_ = _short_and_long()
-    batches = [short[:4] + long_[:3] + short[4:],   # three rows spliced
+    late = still_scalar()
+    batches = [short[:4] + late + short[4:],        # three rows spliced
                short,                               # none
-               [long_[3], JUNK] + short[:2],        # two, one of them junk
-               long_[4:8]]                          # all four
+               [late[0], JUNK] + short[:2],         # two, one of them junk
+               long_[4:8],                          # none: all stay columnar
+               [late[1]] + long_[:2] + [late[2]]]   # two of the four
     obs_trace.tracer.configure("ring", ring=16)
     framed, _data = run(batches, extra=host_route)
     assert framed == expected([ln for b in batches for ln in b])
@@ -335,10 +427,11 @@ def test_one_splice_sub_span_a_batch_with_fallback_rows_and_none_without(
     got = [[(sp["parent"], sp["rows"], sp["bytes"]) for sp in _splices(r)]
            for r in recs]
     assert got == [
-        [("encode", 3, sum(len(ln) for ln in long_[:3]))],
+        [("encode", 3, sum(len(ln) for ln in late))],
         [],
-        [("encode", 2, len(long_[3]) + len(JUNK))],
-        [("encode", 4, sum(len(ln) for ln in long_[4:8]))],
+        [("encode", 2, len(late[0]) + len(JUNK))],
+        [],
+        [("encode", 2, len(late[1]) + len(late[2]))],
     ]
     for rec in recs:
         for sp in _splices(rec):
@@ -350,7 +443,8 @@ def test_one_splice_sub_span_a_batch_with_fallback_rows_and_none_without(
     in_subs = sum(sp["t1"] - sp["t0"] for r in recs for sp in _splices(r))
     assert registry.snapshot()["splice_seconds"] == pytest.approx(
         in_subs, rel=0.2, abs=2e-3)
-    assert registry.get("splice_rows") == 9
+    assert registry.get("splice_rows") == 7
+    assert registry.get("overlen_rows_kept") == 6
 
 
 def _finish_two_fallback_rows():

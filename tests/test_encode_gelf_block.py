@@ -875,3 +875,232 @@ def test_rfc5424_block_numpy_fallback_engine(merger, monkeypatch):
 
     monkeypatch.setattr(native, "r5_rows_available", lambda: False)
     _route_check(RFC5424Encoder, "", merger)
+
+
+# -- rows longer than the device's row ---------------------------------------
+# An over-length row is clipped for the device (input.tpu_max_line_len,
+# 512) and decoded as clipped.  It stays on the columnar encoder where
+# the clip holds the whole header and a non-blank byte of MSG; MSG's end
+# and the tail's purity are read on the host.  Every case holds the
+# block's bytes against the scalar oracle's and says which way the row
+# went (overlen_rows_kept against splice_rows_overlen).
+
+_OL_HEAD = (b"<131>1 2026-09-21T10:00:00.000001Z dn01.ams.example.net "
+            b"hadoop-datanode 4242 - ")
+_OL_SHORT = _OL_HEAD + b"- a short line"
+_OL_WIDTH = 512
+
+
+def _ol(head, length, tail=b"", fill=b"x"):
+    """``head``, filled to ``length`` bytes, ending in ``tail``."""
+    pad = length - len(head) - len(tail)
+    assert pad >= 0
+    line = head + fill * pad + tail
+    assert len(line) == length
+    return line
+
+
+def _ol_sd_closing_at(pos):
+    """A line whose structured data's ``]`` is byte ``pos`` (from 0),
+    followed by a blank and MSG."""
+    line = _ol(_OL_HEAD + b'[mdc@18060 thread="', pos + 1, b'"]') \
+        + b" msg begins" + b"z" * 200
+    assert line[pos:pos + 1] == b"]"
+    return line
+
+
+_TRACE = (b"- ERROR java.io.IOException: Broken pipe" +
+          b"".join(b"#012#011at org.apache.hadoop.hdfs.Frame%d.run"
+                   b"(Frame%d.java:%d)" % (i, i, 100 + i)
+                   for i in range(40)))
+
+
+def _ol_trace_clipped_inside_012():
+    """A folded stack trace with the clip between ``#0`` and ``12``."""
+    head = _OL_HEAD + b"- "
+    at = _TRACE.index(b"#012", _OL_WIDTH - len(head) - 60)
+    line = _ol(head, _OL_WIDTH - 2 - at, fill=b"y") + _TRACE
+    assert line[_OL_WIDTH - 2:_OL_WIDTH + 2] == b"#012"
+    return line
+
+
+# name -> (line, the way it goes: "kept", "spliced", or None where the
+# row is not over-length)
+_OL_CASES = {
+    "512-bytes": (_ol(_OL_HEAD + b"- m", 512, b" end"), None),
+    "513-bytes": (_ol(_OL_HEAD + b"- m", 513, b" end"), "kept"),
+    "4800-bytes": (_ol(_OL_HEAD + b"- m", 4800, b" end"), "kept"),
+    "clip-in-the-header": (
+        b"<131>1 2026-09-21T10:00:00.000001Z " + b"h" * 600
+        + b" app 1 - - msg", "spliced"),
+    "clip-in-an-sd-value": (
+        _ol(_OL_HEAD + b'[mdc@18060 thread="', 700, b'"] msg'), "spliced"),
+    "clip-on-the-sds-closing-bracket": (_ol_sd_closing_at(511), "spliced"),
+    "clip-before-the-sds-closing-bracket": (_ol_sd_closing_at(512),
+                                            "spliced"),
+    "clip-on-the-blank-after-the-sd": (_ol_sd_closing_at(510), "spliced"),
+    "msg-begins-on-the-clips-last-byte": (_ol_sd_closing_at(509), "kept"),
+    "nil-sd-on-the-clips-last-byte": (
+        _ol(_OL_HEAD[:-3], 512, b" - -", fill=b"4") + b" msg" + b"z" * 90,
+        "spliced"),
+    "clip-inside-a-folded-newline": (_ol_trace_clipped_inside_012(), "kept"),
+    "tail-all-blanks": (_ol(_OL_HEAD + b"- m", 512) + b" " * 300, "kept"),
+    "blanks-on-both-sides-of-the-clip": (
+        _OL_HEAD + b"- only this" + b" " * 800, "kept"),
+    "tail-ends-in-blanks-and-controls": (
+        _ol(_OL_HEAD + b"- m", 700, b"end \x1c\x1d\x1e\x1f \t\r"), "kept"),
+    "tail-all-controls-28-31": (
+        _ol(_OL_HEAD + b"- m", 512) + b"\x1c\x1d\x1e\x1f" * 9, "kept"),
+    "msg-begins-past-the-clip": (
+        _OL_HEAD + b"-" + b" " * 600 + b"hello", "spliced"),
+    "one-utf8-character-in-the-tail": (
+        _ol(_OL_HEAD + b"- m", 700, "café au lait".encode()),
+        "spliced"),
+    "one-utf8-character-in-the-clip": (
+        _ol(_OL_HEAD + "- café ".encode(), 700), "spliced"),
+    "json-escapes-in-the-tail": (
+        _ol(_OL_HEAD + b"- m", 900,
+            b'q"uo\\te \x01\x08\x0c\x0b\t\x7f "\\" end'), "kept"),
+    "seven-pairs": (
+        _ol(_OL_HEAD + b'[mdc@18060 g="7" f="6" e="5" d="4" c="3" '
+            b'b="2" a="1"] m', 1300, b" end"), "kept"),
+    "escaped-sd-value": (
+        _ol(_OL_HEAD + b'[mdc@18060 thread="main" '
+            b'class="te\\st sc\\"ript \\] x"] m', 900, b" tail"),
+        "kept-native"),
+    "refused-by-the-decoder": (
+        _ol(_OL_HEAD + b"- m", 700).replace(b"2026-09-21", b"2026-13-21"),
+        "spliced"),
+}
+
+_OL_MERGERS = {"line": LineMerger, "nul": NulMerger, "syslen": SyslenMerger}
+
+
+@pytest.fixture
+def host_block_route(monkeypatch):
+    """No device encoder in the host block encoder's way (with
+    ``tpu_fuse = "off"`` in the handler's config)."""
+    monkeypatch.setenv("FLOWGGER_DEVICE_ENCODE", "0")
+
+
+def _ol_block_bytes(lines, merger):
+    tx = queue.Queue()
+    h = BatchHandler(tx, ORACLE, ENC,
+                     Config.from_string('[input]\ntpu_fuse = "off"\n'),
+                     fmt="rfc5424", start_timer=False, merger=merger)
+    for ln in lines:
+        h.handle_bytes(ln)
+    h.flush()
+    h.close()
+    got = []
+    while not tx.empty():
+        item = tx.get_nowait()
+        got.append(item.data if isinstance(item, EncodedBlock) else item)
+    return b"".join(got)
+
+
+def _ol_way(way, engine):
+    if way == "kept-native":
+        return "kept" if engine == "native" else "spliced"
+    return way
+
+
+@pytest.mark.parametrize("frame", sorted(_OL_MERGERS))
+@pytest.mark.parametrize("case", sorted(_OL_CASES))
+def test_over_length_row_matches_scalar(case, frame, gelf_engine,
+                                        host_block_route):
+    from flowgger_tpu.utils.metrics import registry
+
+    line, way = _OL_CASES[case]
+    way = _ol_way(way, gelf_engine)
+    assert (len(line) > _OL_WIDTH) == (way is not None)
+    merger = _OL_MERGERS[frame]()
+    lines = [_OL_SHORT, line, _OL_SHORT]
+    want = b"".join(scalar_frames(lines, merger))
+    registry.reset()
+    assert _ol_block_bytes(lines, merger) == want
+    assert registry.get("overlen_rows") == int(way is not None)
+    assert registry.get("overlen_rows_kept") == int(way == "kept")
+    assert registry.get("splice_rows_overlen") == int(way == "spliced")
+    assert registry.get("splice_rows") == int(way == "spliced")
+
+
+@pytest.mark.parametrize("frame", sorted(_OL_MERGERS))
+def test_every_over_length_case_in_one_batch(frame, gelf_engine,
+                                             host_block_route):
+    """Adjacent over-length rows: each tail is read as its own, also
+    where it is all blanks and its neighbour's is not."""
+    from flowgger_tpu.utils.metrics import registry
+
+    merger = _OL_MERGERS[frame]()
+    names = sorted(_OL_CASES)
+    lines = [_OL_CASES[k][0] for k in names] + [_OL_SHORT] \
+        + [_OL_CASES[k][0] for k in reversed(names)]
+    ways = [_ol_way(_OL_CASES[k][1], gelf_engine) for k in names]
+    registry.reset()
+    assert _ol_block_bytes(lines, merger) == b"".join(
+        scalar_frames(lines, merger))
+    assert registry.get("overlen_rows_kept") == 2 * ways.count("kept")
+    assert registry.get("splice_rows_overlen") == 2 * ways.count("spliced")
+
+
+def test_the_tail_scan_reads_each_rows_own_tail():
+    """``_overlen_tails`` alone, against a loop over the rows."""
+    from flowgger_tpu.tpu.encode_gelf_block import _overlen_tails
+
+    rng = np.random.default_rng(34)
+    blanks = np.array([9, 10, 11, 12, 13, 28, 29, 30, 31, 32], np.uint8)
+    rows, lens = [], []
+    for k in range(200):
+        n = int(rng.integers(17, 400))
+        row = rng.integers(33, 127, n).astype(np.uint8)
+        if k % 3 == 0:      # trailing blanks, sometimes the whole tail
+            cut = int(rng.integers(0, n - 16)) if k % 2 else 16
+            row[cut:] = rng.choice(blanks, n - cut)
+        if k % 7 == 0:
+            row[int(rng.integers(16, n))] = int(rng.integers(128, 256))
+        rows.append(row)
+        lens.append(n)
+    chunk = np.concatenate(rows)
+    lens = np.array(lens, dtype=np.int64)
+    starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    pick = np.flatnonzero(np.arange(200) % 2 == 0)
+    clip_end = np.full(pick.size, 7)
+    ascii_tail, end = _overlen_tails(chunk, starts, lens, 16, pick, clip_end)
+    for j, i in enumerate(pick.tolist()):
+        tail = bytes(rows[i][16:])
+        assert bool(ascii_tail[j]) == tail.isascii()
+        if tail.isascii():
+            kept = tail.decode().rstrip()
+            assert int(end[j]) == (16 + len(kept) if kept else 7)
+
+
+@pytest.mark.parametrize("frame", sorted(_OL_MERGERS))
+def test_over_length_rows_with_gelf_extra_take_the_same_extension(frame):
+    """``[output.gelf_extra]`` runs on the numpy engine, which reads the
+    same spans: its over-length rows stay columnar too."""
+    from flowgger_tpu.tpu import rfc5424
+    from flowgger_tpu.tpu.encode_gelf_block import encode_rfc5424_gelf_block
+    from flowgger_tpu.utils.metrics import registry
+
+    enc = GelfEncoder(Config.from_string(
+        '[output.gelf_extra]\nZone = "eu"\nkind = "syslog"\nzzz = "last"\n'))
+    merger = _OL_MERGERS[frame]()
+    names = sorted(_OL_CASES)
+    lines = [_OL_SHORT] + [_OL_CASES[k][0] for k in names]
+    want = []
+    for ln in lines:
+        try:
+            want.append(merger.frame(enc.encode(ORACLE.decode(ln.decode()))))
+        except DecodeError:
+            pass
+    packed = pack.pack_lines_2d(lines, _OL_WIDTH)
+    host_out = rfc5424.decode_rfc5424_host(packed[0], packed[1])
+    registry.reset()
+    res = encode_rfc5424_gelf_block(packed[2], packed[3], packed[4],
+                                    host_out, packed[5], _OL_WIDTH, enc,
+                                    merger)
+    assert res.block.data == b"".join(want)
+    ways = [_ol_way(_OL_CASES[k][1], "numpy") for k in names]
+    assert registry.get("overlen_rows_kept") == ways.count("kept")
+    assert registry.get("splice_rows_overlen") == ways.count("spliced")
