@@ -2167,133 +2167,6 @@ def bench_framing(extra, smoke):
     return bool(ok)
 
 
-def bench_pallas(extra, smoke):
-    """Pallas structural-pass smoke gates (single-VMEM kernels PR):
-
-    1. Pass-count reduction: the stage-1 structural screen's
-       [N,L]-touching op count — jnp compiled-HLO census vs the Pallas
-       classifier's TPU StableHLO materializations — must shrink >=5x
-       (the honest CPU-box proxy for the VMEM win: every fusion the
-       census counts is an HBM round-trip over the byte plane that the
-       single kernel doesn't make);
-    2. Byte identity: interpret-mode span kernels vs the host
-       splitters' scalar scans on representative regions (the full
-       differential matrix lives in tests/test_pallas_kernels.py);
-    3. AOT ``pallas`` family: cpu+tpu artifacts build from this host,
-       the manifest validates, and a cpu dispatch hits the store
-       (``aot_hits`` > 0 — zero fresh kernel traces on an artifact
-       boot).
-    All three run interpret/cpu here — label any BENCH entry derived
-    from this section ``cpu-interpret``, never an accelerator rate."""
-    import tempfile
-
-    import numpy as np
-
-    from flowgger_tpu.tpu import pack as _pack
-    from flowgger_tpu.tpu import pallas_kernels as PK
-    from flowgger_tpu.utils.metrics import registry as _registry
-
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.abspath(__file__)), "tools"))
-    from hlo_stats import jnp_stage1_passes, pallas_stage1_passes
-
-    t0 = time.perf_counter()
-    n_rows, length = 512, 256
-    jnp_passes, _counts = jnp_stage1_passes(n_rows, length)
-    pallas_passes = pallas_stage1_passes(n_rows, length)
-    reduction = jnp_passes / max(pallas_passes, 1)
-    passes_ok = reduction >= 5.0
-
-    # interpret-mode byte identity, spans vs the host scalar scans
-    ident_ok = True
-    blob = b"".join(b"pallas smoke line %d\r\n" % i for i in range(40))
-    blob += b"tail without newline"
-    out = PK.frame_sep_spans_pallas(
-        np.frombuffer(blob, np.uint8), np.int32(len(blob)), sep=10,
-        strip_cr=True, ncap=64, interpret=True)
-    hs, hl, hn, carry = _pack.split_chunk(blob, strip_cr=True)
-    ident_ok &= (int(out["n"]) == hn
-                 and int(out["consumed"]) == len(blob) - len(carry)
-                 and np.array_equal(np.asarray(out["starts"])[:hn], hs)
-                 and np.array_equal(np.asarray(out["lens"])[:hn], hl))
-    from flowgger_tpu.splitters import _scan_syslen_region
-
-    sblob = b"".join(b"%d pallas smoke rec %d" % (len(b"pallas smoke "
-                     b"rec %d" % i), i) for i in range(30)) + b"7 trunc"
-    sout = PK.frame_syslen_spans_pallas(
-        np.frombuffer(sblob, np.uint8), np.int32(len(sblob)), ncap=64,
-        interpret=True)
-    shs, shl, shn, shcons, sherr = _scan_syslen_region(sblob)
-    ident_ok &= (not bool(sout["decline"]) and int(sout["n"]) == shn
-                 and int(sout["consumed"]) == shcons
-                 and bool(sout["err"]) == sherr
-                 and np.array_equal(np.asarray(sout["starts"])[:shn], shs)
-                 and np.array_equal(np.asarray(sout["lens"])[:shn], shl))
-
-    # AOT pallas family: cross-platform build + cpu dispatch hit
-    aot_ok = False
-    aot_entries = 0
-    try:
-        from flowgger_tpu.tpu import aot
-        import jax.numpy as jnp_mod
-
-        with tempfile.TemporaryDirectory() as td:
-            PK.set_mode("interpret")
-            aot.build_artifacts(td, platforms=("cpu", "tpu"),
-                                families=("pallas",),
-                                formats=("rfc5424",), rows_grid=(64,),
-                                max_len=128, quiet=True)
-            store = aot.AotStore.load(td)
-            aot.activate_store(store)
-            try:
-                _registry.reset()
-                from flowgger_tpu.tpu.framing import region_bucket
-
-                rb = region_bucket(64 * aot.FRAMING_AVG_BYTES)
-                reg = np.zeros(rb, np.uint8)
-                reg[:len(blob)] = np.frombuffer(blob, np.uint8)
-                st = aot.pallas_statics("line", 64, rb)
-                hit = aot.pallas_call(
-                    "line",
-                    (jnp_mod.asarray(reg),
-                     jnp_mod.asarray(np.int32(len(blob)))), st)
-                entries = store.manifest["entries"].values()
-                plats = {e["platform"] for e in entries}
-                aot_entries = len(store.manifest["entries"])
-                aot_ok = (hit is not None and int(hit["n"]) == hn
-                          and _registry.get("aot_hits") > 0
-                          and plats == {"cpu", "tpu"})
-            finally:
-                aot.activate_store(None)
-    except Exception as e:  # noqa: BLE001 - the gate fails, the smoke reports
-        print(f"pallas aot round trip failed: {type(e).__name__}: {e}",
-              file=sys.stderr)
-    finally:
-        PK.set_mode("off")
-
-    ok = passes_ok and ident_ok and aot_ok
-    payload = {
-        "metric": "pallas_smoke",
-        "backend": "cpu-interpret",
-        "gate": ("stage1 [N,L] pass count reduced >=5x AND interpret "
-                 "span kernels byte-identical to the host scans AND "
-                 "the AOT pallas family round-trips cpu+tpu with an "
-                 "aot_hits dispatch"),
-        "stage1_geometry": [n_rows, length],
-        "jnp_stage1_passes": jnp_passes,
-        "pallas_stage1_passes": pallas_passes,
-        "pass_reduction": round(reduction, 1),
-        "span_byte_identity": bool(ident_ok),
-        "aot_round_trip": bool(aot_ok),
-        "aot_entries": aot_entries,
-        "wall_seconds": round(time.perf_counter() - t0, 1),
-        "ok": bool(ok),
-    }
-    print(json.dumps(payload))
-    extra["pallas_smoke"] = payload
-    return bool(ok)
-
-
 def smoke_main():
     """``bench.py --smoke``: the CI gate for the overlap executor.
 
@@ -2382,10 +2255,6 @@ def smoke_main():
     # all three framings + span-metadata fetch under emit bytes/row
     # (runs before the fused section for the same clean-machine reason)
     framing_ok = bench_framing(extra, smoke=True)
-    # Pallas structural kernels: stage-1 [N,L] pass count >=5x down vs
-    # the jnp screen, interpret span kernels byte-identical to the
-    # host scans, AOT pallas family round-trips cpu+tpu
-    pallas_ok = bench_pallas(extra, smoke=True)
     # fused route matrix: byte-identical to the split path + fetched
     # bytes/row at or under the split path's (and under emitted)
     fused_ok = bench_fused_routes(extra, smoke=True)
@@ -2403,9 +2272,7 @@ def smoke_main():
     # section 6 jax-free subprocess runs (~15s), and the new-format
     # section two foreground kernel compiles (~60s), and the framing
     # section ~9 short e2e passes + three span-kernel compiles (~40s),
-    # and the pallas section one HLO census + a small cross-platform
-    # artifact build (~90s), so the smoke budget is 630s — still
-    # bounded, still CI-friendly
+    # so the smoke budget is 630s — still bounded, still CI-friendly
     budget = 630
     print(json.dumps({
         "metric": "e2e_overlap_smoke",
@@ -2418,7 +2285,7 @@ def smoke_main():
         "wall_seconds": round(wall, 1),
         "ok": bool(ok and lanes_ok and tenancy_ok and obs_ok
                    and durability_ok and control_ok and newfmt_ok
-                   and framing_ok and pallas_ok and fused_ok and aot_ok
+                   and framing_ok and fused_ok and aot_ok
                    and fleet_ok and wall < budget),
     }))
     if not framing_ok:
@@ -2427,13 +2294,6 @@ def smoke_main():
               "fetch bytes/row above emitted, or throughput below the "
               "backend-tiered floor — see the framing_smoke JSON line)",
               file=sys.stderr)
-        sys.exit(1)
-    if not pallas_ok:
-        print("SMOKE FAIL: pallas gates missed (stage-1 [N,L] pass "
-              "count not reduced >=5x vs the jnp screen, interpret "
-              "span kernels diverged from the host scans, or the AOT "
-              "pallas family failed its cpu+tpu round trip — see the "
-              "pallas_smoke JSON line)", file=sys.stderr)
         sys.exit(1)
     if not newfmt_ok:
         print("SMOKE FAIL: jsonl/dns block-route gates missed (byte "

@@ -32,7 +32,7 @@ holds the chip.  With no arguments (one TPU chip) it
    decoded on the device, rows also encoded there, batches per encode
    route, declines, breaker, compiles — and exits non-zero if the
    device decoded under 95% of the rows, the breaker left ``closed``, a
-   compile/framing/Pallas decline or a device error was counted, a
+   compile or framing decline or a device error was counted, a
    program was compiled inside the window (the fetch driver's
    per-length ``dynamic_slice`` programs excepted: they are printed),
    the bytes differ, or any phase raised.  Which *encoder* finishes a
@@ -80,7 +80,7 @@ WINDOWS_MAX = 10    # windows a handler gets to show one with no cold program
 
 # what a measured window may not count (any of these > 0 fails the run)
 MUST_BE_ZERO = ("device_encode_compile_declines", "framing_declines",
-                "pallas_declines", "breaker_trips", "device_decode_errors",
+                "breaker_trips", "device_decode_errors",
                 "drain_flush_errors", "output_errors")
 # printed beside them; a fused fallback is tolerated only as the 5% rule
 # applied to one small ragged batch (== a counted device_encode_declined)
@@ -88,7 +88,7 @@ COUNTERS = ("input_lines", "output_written", "batches", "fused_rows",
             "device_encode_rows", "device_encode_scalar_rows",
             "encode_route_fused", "encode_route_device",
             "encode_route_host", "fallback_rows", "framing_rows",
-            "pallas_rows", "fused_fallbacks", "device_encode_declined",
+            "fused_fallbacks", "device_encode_declined",
             "compile_cache_hits", "compile_cache_misses") + MUST_BE_ZERO
 
 
@@ -311,8 +311,7 @@ def report_window(name, d, compiles, breaker_state, enforce):
         f"fused_rows={d['fused_rows']}, the rest the split device "
         f"encoder), with {d['device_encode_scalar_rows']} scalar rows "
         f"spliced into those batches; all other rows: the host block "
-        f"encoder.  framing_rows={d['framing_rows']} "
-        f"pallas_rows={d['pallas_rows']}")
+        f"encoder.  framing_rows={d['framing_rows']}")
     say(f"[{name}] batches by encode route, as the route economics "
         f"measured and chose: encode_route_fused={d['encode_route_fused']} "
         f"encode_route_device={d['encode_route_device']} "
@@ -544,7 +543,7 @@ def stdin_phase(args, rng, compiles, name, extra="", hold=True):
     handler = pipe._handlers[0]
     say(f"[{name}] handler: lanes={len(handler._lane_devices)} "
         f"framing_engaged={handler._framing_engaged} "
-        f"pallas={handler._pallas_mode} economics="
+        "economics="
         + json.dumps([e.snapshot() for e in handler._econs])
         + " framing economics="
         + json.dumps(handler._framing_econ.snapshot()))

@@ -49,11 +49,7 @@ echo "== overlap-executor + fused-route + zero-JIT-boot smoke (<630s) =="
 # TPU fused-route export round-trips build-only (aot_smoke line),
 # AND the device-resident framing tier emits byte-identical output on
 # line/nul/syslen with span-metadata fetch bytes/row under emitted
-# (framing_smoke line; throughput gate backend-tiered),
-# AND the Pallas tier passes its three gates: stage-1 [N,L] pass count
-# reduced >=5x vs the jnp screen, interpret span kernels byte-identical
-# to the host scans, and the AOT pallas family round-tripping cpu+tpu
-# with an aot_hits dispatch (pallas_smoke line, backend cpu-interpret)
+# (framing_smoke line; throughput gate backend-tiered)
 JAX_PLATFORMS=cpu timeout 900 python bench.py --smoke
 
 echo "== python test suite (virtual 8-device CPU mesh) =="
@@ -168,27 +164,6 @@ echo "== framing deep fuzz (random chunk splits vs host splitters) =="
 # prefix and delimiters exactly on chunk edges): device spans == host
 # splitter output, e2e bytes identical across 1/2 lanes
 timeout 900 python tools/deep_fuzz.py --routes framing 1 4
-
-echo "== Pallas kernels (interpret-mode differentials, slow half) =="
-# the non-slow Pallas half (span kernels vs host scans, the
-# decline/hysteresis ladders, config validation) already ran in the
-# main suite step — this step adds the slow-marked half: the
-# compiled-NFA classifier and decode differentials vs the jnp screen,
-# raw-ingest byte identity, the fused framing→decode entries vs the
-# split path, the line/nul/syslen × rfc5424/jsonl × 1/2-lane e2e
-# matrix, and the AOT pallas-family round trip with aot_hits asserted.
-# Interpret-mode compiles dominate the wall time (each geometry
-# compiles once, then differentials are cheap)
-JAX_PLATFORMS=cpu timeout 1800 python -m pytest tests/test_pallas_kernels.py -q -m "slow and not faults"
-
-echo "== Pallas deep fuzz (interpret kernels vs host scans + jnp screen) =="
-# randomized regions (partial tails, bad prefixes) vs the host scalar
-# scans, randomized JSON rows (escape runs straddling ESC_RUN_CAP) vs
-# the jnp lax/sum screen, and e2e chunk plans splitting records
-# mid-byte and mid-syslen-prefix with tpu_pallas on vs the all-host
-# pipeline; the larger-budget version is
-# `python tools/deep_fuzz.py --routes pallas <seed> <trials>`
-timeout 900 python tools/deep_fuzz.py --routes pallas 1 2
 
 echo "== fault-injection suite (robustness degradation paths) =="
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "faults and not slow"

@@ -3,7 +3,7 @@
 A from-scratch framework with the capabilities of awslabs/flowgger
 (reference mounted at /root/reference): transports → framing → decode →
 encode → queue → sinks, driven by the same TOML config surface, with the
-hot decode path batched onto TPU via columnar JAX/Pallas kernels
+hot decode path batched onto TPU via columnar JAX (jnp) programs
 (``input.format = "rfc5424_tpu"`` and friends).
 
 Public API matches the reference's single entry point

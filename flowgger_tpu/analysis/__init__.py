@@ -14,7 +14,7 @@ Rules (see ``flowcheck --list-rules`` / README "Static analysis"):
 
 - **FC01 trace-safety** — no wall clocks, Python RNG, I/O, host syncs,
   or tracer-dependent Python branching in code reachable from a
-  ``jax.jit`` / Pallas kernel entry point;
+  ``jax.jit`` entry point;
 - **FC02 thread discipline** — counters mutated from thread targets are
   lock-guarded (or routed through ``utils.metrics``), and no blocking
   call is made while holding a lock;

@@ -1,4 +1,4 @@
-"""FC01 — trace-safety of jit/Pallas kernel entry points.
+"""FC01 — trace-safety of jit entry points.
 
 A jitted function is traced once per input signature; anything
 impure that runs during tracing is baked in (wall clocks, RNG draws) or
@@ -10,8 +10,8 @@ hot-path discipline).
 
 The rule finds jit roots in a module (``@jax.jit`` /
 ``@partial(jax.jit, static_argnames=...)`` decorators, ``f =
-jax.jit(g)`` assignments, kernels handed to ``pl.pallas_call``),
-computes the module-local call-graph closure under them, and flags:
+jax.jit(g)`` assignments), computes the module-local call-graph closure
+under them, and flags:
 
 - wall-clock reads (``time.time/monotonic/perf_counter/...``) and
   ``time.sleep``;
@@ -92,10 +92,6 @@ class _ModuleIndex:
                 target = dotted_name(node.args[0])
                 if target in self.functions:
                     self.roots.setdefault(target, _static_argnames(node))
-            elif name in ("pl.pallas_call", "pallas_call") and node.args:
-                target = dotted_name(node.args[0])
-                if target in self.functions:
-                    self.roots.setdefault(target, set())
 
     def reachable(self) -> Dict[str, Tuple[str, Optional[Set[str]]]]:
         """name -> (root it is reachable from, static args if it IS a
@@ -160,7 +156,7 @@ def _traced_names_in_test(test: ast.AST, traced: Set[str]) -> Set[str]:
 @register
 class TraceSafety(Rule):
     id = "FC01"
-    title = "trace-safety of jit/Pallas entry points"
+    title = "trace-safety of jit entry points"
 
     def check(self, module: Module, project: Project) -> Iterable[Finding]:
         index = _ModuleIndex(module.tree)

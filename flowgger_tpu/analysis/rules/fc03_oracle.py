@@ -36,10 +36,8 @@ from ..core import Finding, Module, Project, Rule, register
 
 _PATTERNS = ("*tpu/device_*.py", "*tpu/encode_*_block.py",
              "*tpu/fused_*.py", "*tpu/aot.py", "*tpu/framing.py",
-             "*tpu/pallas_kernels.py",
              "tpu/device_*.py", "tpu/encode_*_block.py",
-             "tpu/fused_*.py", "tpu/aot.py", "tpu/framing.py",
-             "tpu/pallas_kernels.py")
+             "tpu/fused_*.py", "tpu/aot.py", "tpu/framing.py")
 _EXEMPT_BASENAMES = {"device_common.py"}
 
 

@@ -125,7 +125,6 @@ REASONS = (
     "economics_switch",
     "aot_reject",
     "framing_decline",
-    "pallas_decline",
     "fused_fallback",
     "device_error",
     "tenant_shed",

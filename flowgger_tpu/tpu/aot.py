@@ -78,7 +78,7 @@ FUSED_ROUTES = ("rfc5424_gelf", "rfc3164_gelf", "ltsv_gelf", "gelf_gelf",
 # framing name -> block merger suffix; syslen shares "line"'s b"\n"
 # (block_common.merger_suffix: the syslen prefix is a host-side splice)
 FRAMINGS = {"line": b"\n", "nul": b"\x00"}
-FAMILIES = ("decode", "fused", "encode", "framing", "pallas")
+FAMILIES = ("decode", "fused", "encode", "framing")
 # device-resident framing (tpu/framing.py): stage-A span kernels per
 # input framing plus the shared stage-B gather
 FRAMING_KINDS = ("line", "nul", "syslen")
@@ -228,15 +228,9 @@ def fused_statics(route_name: str, suffix: bytes, impl: str,
                "demand": DEMAND[route_name], "elide": True}
     if route_name in ("rfc5424_gelf", "rfc5424_rfc5424", "rfc5424_ltsv",
                       "rfc5424_capnp"):
-        from .pallas_kernels import fused_leg_mode
         from .rfc5424 import DEFAULT_MAX_SD
 
         statics["max_sd"] = DEFAULT_MAX_SD
-        # the rfc5424 decode leg traces differently per pallas mode —
-        # part of the artifact key so a loaded program always matches
-        # what the live closure would trace ("compiled" or "off";
-        # interpret never reaches a fused program)
-        statics["pallas"] = fused_leg_mode()
     return statics
 
 
@@ -256,31 +250,6 @@ def framing_statics(kind: str, ncap: int, region_bytes: int) -> Dict:
     if kind == "gather":
         return {"max_len": ncap}
     raise ValueError(f"unknown framing kind {kind!r}")
-
-
-def pallas_statics(kind: str, ncap: int, region_bytes: int) -> Dict:
-    """Static-arg recipe for one Pallas kernel entry (kind in
-    FRAMING_KINDS, ``gather`` — where ``ncap`` carries max_len — or
-    ``decode_rfc5424``/``decode_jsonl``).  ONE definition shared by the
-    builder and the probe sites (tpu/framing.py,
-    pallas_kernels.decode_tier); the ``interpret`` flag is appended
-    per-platform — cpu artifacts embed the interpreter path, Mosaic
-    only lowers on accelerators."""
-    if kind == "line":
-        return {"sep": 10, "strip_cr": True, "ncap": ncap}
-    if kind == "nul":
-        return {"sep": 0, "strip_cr": False, "ncap": ncap}
-    if kind == "syslen":
-        return {"ncap": ncap}
-    if kind == "gather":
-        return {"max_len": ncap}
-    if kind == "decode_rfc5424":
-        from .rfc5424 import DEFAULT_MAX_SD
-
-        return {"max_sd": DEFAULT_MAX_SD}
-    if kind == "decode_jsonl":
-        return {}
-    raise ValueError(f"unknown pallas kind {kind!r}")
 
 
 def encode_statics(module: str, suffix: bytes, impl: str,
@@ -718,33 +687,6 @@ def framing_call(kind: str, args, statics: Dict):
     return out
 
 
-def pallas_call(kind: str, args, statics: Dict):
-    """AOT lookup for one Pallas kernel call (stage-A spans, stage-B
-    gather, or a decode pass): the exported program's output, or None →
-    the caller jits the live kernel under its watchdog slot.  The
-    runtime's interpret flag joins the lookup key, so a cpu(interpret)
-    artifact never answers a compiled-mode probe — same decline
-    contract as framing_call."""
-    store = active_store()
-    if store is None:
-        return None
-    from .pallas_kernels import interpret_mode
-
-    full = {**statics, "interpret": interpret_mode()}
-    call = store.find(f"pallas_{kind}", full, args)
-    if call is None:
-        return None
-    try:
-        out = call(*args)
-    except Exception as e:  # noqa: BLE001 - decline to the live kernel, never lose data
-        key = entry_key(f"pallas_{kind}", store._platform(), full,
-                        args_spec(args))
-        store.reject_entry(key, "call_error", f"{type(e).__name__}: {e}")
-        return None
-    _metrics().inc("aot_hits")
-    return out
-
-
 def wrap_kernel(family: str, kernel, args, statics: Dict):
     """Wrap a device-encode/fused kernel closure (``kernel(ts_text,
     ts_len, assemble)``) so each call consults the store first and
@@ -971,12 +913,9 @@ def _fused_fn(route_name: str, statics: Dict):
     assemble = statics["assemble"]
     if route_name == "rfc5424_gelf":
         max_sd = statics["max_sd"]
-        pallas = statics.get("pallas", "off")
-
         return lambda b, ln, ts, tl: _fr._fused_rfc5424_gelf(
             b, ln, ts, tl, max_sd=max_sd, suffix=suffix, impl=impl,
-            assemble=assemble, extras=extras, demand=demand,
-            pallas=pallas)
+            assemble=assemble, extras=extras, demand=demand)
     if route_name == "rfc3164_gelf":
         return lambda b, ln, yr, ts, tl: _fr._fused_rfc3164_gelf(
             b, ln, yr, ts, tl, suffix=suffix, impl=impl,
@@ -987,31 +926,23 @@ def _fused_fn(route_name: str, statics: Dict):
             assemble=assemble, extras=extras, demand=demand)
     if route_name == "rfc5424_rfc5424":
         max_sd = statics["max_sd"]
-        pallas = statics.get("pallas", "off")
-
         return lambda b, ln, ts, tl: _fr._fused_rfc5424_rfc5424(
             b, ln, ts, tl, max_sd=max_sd, suffix=suffix,
-            assemble=assemble, demand=demand, pallas=pallas)
+            assemble=assemble, demand=demand)
     if route_name == "rfc3164_rfc5424":
         return lambda b, ln, yr, ts, tl: _fr._fused_rfc3164_rfc5424(
             b, ln, yr, ts, tl, suffix=suffix, assemble=assemble,
             demand=demand)
     if route_name == "rfc5424_ltsv":
         max_sd = statics["max_sd"]
-        pallas = statics.get("pallas", "off")
-
         return lambda b, ln, ts, tl: _fr._fused_rfc5424_ltsv(
             b, ln, ts, tl, max_sd=max_sd, suffix=suffix,
-            extras=extras, assemble=assemble, demand=demand,
-            pallas=pallas)
+            extras=extras, assemble=assemble, demand=demand)
     if route_name == "rfc5424_capnp":
         max_sd = statics["max_sd"]
-        pallas = statics.get("pallas", "off")
-
         return lambda b, ln, ts, tl: _fr._fused_rfc5424_capnp(
             b, ln, ts, tl, max_sd=max_sd, suffix=suffix,
-            extras=extras, assemble=assemble, demand=demand,
-            pallas=pallas)
+            extras=extras, assemble=assemble, demand=demand)
     return lambda b, ln, ts, tl: _fr._fused_gelf_gelf(
         b, ln, ts, tl, suffix=statics["suffix"],
         assemble=assemble, demand=demand)
@@ -1042,31 +973,6 @@ def _framing_fn(kind: str, statics: Dict):
         return lambda region, rlen: _framing.frame_syslen_spans_jit(
             region, rlen, **statics)
     return lambda region, rlen: _framing.frame_sep_spans_jit(
-        region, rlen, **statics)
-
-
-def _pallas_fn(kind: str, statics: Dict):
-    """Builder-side callable for one Pallas kernel entry (the loader
-    half is ``pallas_call``; ``statics`` includes the per-platform
-    ``interpret`` flag)."""
-    from . import pallas_kernels as _pk
-
-    if kind == "gather":
-        return lambda region, starts, lens: _pk.frame_gather_pallas(
-            region, starts, lens, **statics)
-    if kind == "syslen":
-        return lambda region, rlen: _pk.frame_syslen_spans_pallas(
-            region, rlen, **statics)
-    if kind == "decode_rfc5424":
-        def _dec(b, ln):
-            from .rfc5424 import decode_rfc5424_pallas
-
-            return decode_rfc5424_pallas(b, ln, **statics)
-
-        return _dec
-    if kind == "decode_jsonl":
-        return lambda b, ln: _pk.decode_jsonl_pallas(b, ln, **statics)
-    return lambda region, rlen: _pk.frame_sep_spans_pallas(
         region, rlen, **statics)
 
 
@@ -1221,40 +1127,6 @@ def build_artifacts(out_dir: str, platforms=("cpu",),
                 add_entry("framing_gather", platform, rows, "gather",
                           _framing_fn("gather", gst), (reg, sl, sl),
                           gst)
-            if "pallas" in families:
-                # Pallas structural kernels (PR 20): stage-A spans +
-                # stage-B gather + the single-VMEM decode passes.  cpu
-                # artifacts embed interpret mode (Mosaic only lowers on
-                # accelerators); regions past PALLAS_MAX_REGION get no
-                # artifact — the runtime tier disengages there anyway.
-                from . import pallas_kernels as _pk
-                from .framing import region_bucket
-
-                interp = platform == "cpu"
-                rb = region_bucket(rows * FRAMING_AVG_BYTES)
-                if rb <= _pk.PALLAS_MAX_REGION:
-                    reg = jax.ShapeDtypeStruct((rb,), jnp.uint8)
-                    rl = jax.ShapeDtypeStruct((), jnp.int32)
-                    for kind in FRAMING_KINDS:
-                        pst = {**pallas_statics(kind, rows, rb),
-                               "interpret": interp}
-                        add_entry(f"pallas_{kind}", platform, rows,
-                                  kind, _pallas_fn(kind, pst),
-                                  (reg, rl), pst)
-                    sl = jax.ShapeDtypeStruct((rows,), jnp.int32)
-                    gst = {**pallas_statics("gather", max_len, rb),
-                           "interpret": interp}
-                    add_entry("pallas_gather", platform, rows,
-                              "gather", _pallas_fn("gather", gst),
-                              (reg, sl, sl), gst)
-                for fmt in ("rfc5424", "jsonl"):
-                    if fmt not in formats:
-                        continue
-                    pst = {**pallas_statics(f"decode_{fmt}", rows, 0),
-                           "interpret": interp}
-                    add_entry(f"pallas_decode_{fmt}", platform, rows,
-                              fmt, _pallas_fn(f"decode_{fmt}", pst),
-                              (b, ln), pst)
             if "encode" in families:
                 for fmt in formats:
                     # jsonl/dns: no device-encode kernel (empty tuple);

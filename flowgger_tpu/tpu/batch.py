@@ -350,48 +350,6 @@ class BatchHandler(Handler):
                 "block route is disabled, auto format, or a sharded "
                 "mesh owns the format); using the host splitters",
                 file=sys.stderr)
-        # Pallas structural kernels (tpu/pallas_kernels.py).  The chip's
-        # compiler (Mosaic) refuses all six of them today
-        # (tests/test_chip_compile.py holds the verdicts), so "auto"
-        # resolves to the jnp tiers on every backend.  "on" is the
-        # explicit opt-in: interpret-mode kernels on the CPU backend
-        # (the differential tests), compiled kernels elsewhere — and
-        # there a kernel the compiler refuses is a start-up error that
-        # names the kernel, never a decline, a cooldown and a retry.
-        from . import pallas_kernels as _pallas_mod
-
-        pallas_mode = cfg.lookup_str(
-            "input.tpu_pallas", "input.tpu_pallas must be a string",
-            "auto")
-        if pallas_mode not in ("auto", "on", "off"):
-            from ..config import ConfigError
-
-            raise ConfigError("input.tpu_pallas must be auto, on or off")
-        pallas_ok = (self._block_mode and self.fmt != "auto"
-                     and self._kernel_fn is not None
-                     and self._block_route_ok())
-        if pallas_mode != "on" or not pallas_ok:
-            _pallas_mod.set_mode("off")
-            if pallas_mode == "on" and self._block_mode:
-                print(
-                    'flowgger-tpu: input.tpu_pallas = "on" but this '
-                    f"config cannot run Pallas kernels for format "
-                    f"'{fmt}' (the columnar block route is disabled or "
-                    "auto format); using the jnp kernel tiers",
-                    file=sys.stderr)
-        else:
-            import jax
-
-            if jax.default_backend() == "cpu":
-                _pallas_mod.set_mode("interpret")
-            else:
-                from . import pack as _pack_mod
-
-                _pallas_mod.require_compiles(
-                    fmt, _pack_mod.bucket_rows(self.batch_size),
-                    self.max_len, framing=framing_engaged)
-                _pallas_mod.set_mode("compiled")
-        self._pallas_mode = pallas_mode
         # background kernel prewarm: compile the configured format's
         # decode (+ engaged device-encode) kernels for the shape-bucket
         # grid now, so the first real batch of each steady-state shape

@@ -635,7 +635,7 @@ def _out_width(L: int, src_width: int = 0) -> int:
     return w
 
 
-def escape_stage(batch, lens, iota, cumsum_fn, assemble: bool):
+def escape_stage(batch, lens, iota, assemble: bool):
     """JSON-escape classification + (when assembling) the escaped row.
 
     Returns a dict with: ``esc_row`` ([N, L+E_CAP] u8 escaped bytes, or
@@ -649,7 +649,7 @@ def escape_stage(batch, lens, iota, cumsum_fn, assemble: bool):
     esc = ((bb == 34) | (bb == 92) | two_ctl) & valid
     bad_ctl = (bb < 32) & ~two_ctl & valid
     esc_i = esc.astype(_I32)
-    ne_incl = cumsum_fn(esc_i)
+    ne_incl = jnp.cumsum(esc_i, axis=1)
     ne_excl = ne_incl - esc_i
     ne_total = ne_incl[:, -1]
 

@@ -75,7 +75,7 @@ from .device_common import (  # noqa: F401  (re-exported for tests/siblings)
     sort_pairs_by_key8,
     ts_text_block as _ts_text_block,
 )
-from .rfc5424 import _cumsum, best_scan_impl
+from .rfc5424 import best_scan_impl
 
 _I32 = jnp.int32
 _U8 = jnp.uint8
@@ -148,8 +148,7 @@ def _encode_kernel(batch, lens, dec, ts_text, ts_len, *, suffix: bytes,
     iota = jax.lax.broadcasted_iota(_I32, (N, L), 1)
     bb = batch.astype(_I32)
 
-    es = escape_stage(batch, lens, iota,
-                      lambda x: _cumsum(x, impl), assemble)
+    es = escape_stage(batch, lens, iota, assemble)
     dmap = es["dmap"]
 
     # ---- fixed-field spans in escaped coordinates ------------------------

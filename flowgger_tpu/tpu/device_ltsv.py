@@ -63,7 +63,7 @@ from .encode_ltsv_gelf_block import (
     _C_UNKNOWN,
 )
 from .ltsv import _match_at
-from .rfc5424 import _cumsum, best_scan_impl
+from .rfc5424 import best_scan_impl
 
 _I32 = jnp.int32
 
@@ -133,8 +133,7 @@ def _encode_kernel(batch, lens, dec, ts_text, ts_len, *, suffix: bytes,
     iota = jax.lax.broadcasted_iota(_I32, (N, L), 1)
     bb = batch.astype(_I32)
 
-    es = escape_stage(batch, lens, iota,
-                      lambda x: _cumsum(x, impl), assemble)
+    es = escape_stage(batch, lens, iota, assemble)
     dmap = es["dmap"]
     lens32 = lens.astype(_I32)
     valid = iota < lens32[:, None]

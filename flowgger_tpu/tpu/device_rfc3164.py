@@ -47,7 +47,7 @@ from .encode_rfc3164_gelf_block import (
     _C_TAIL,
     _C_TS,
 )
-from .rfc5424 import _cumsum, best_scan_impl
+from .rfc5424 import best_scan_impl
 
 _I32 = jnp.int32
 
@@ -105,8 +105,7 @@ def _encode_kernel(batch, lens, dec, ts_text, ts_len, *, suffix: bytes,
     OW = _out_width(L, L + E_CAP + len(bank) + TS_W)
     iota = jax.lax.broadcasted_iota(_I32, (N, L), 1)
 
-    es = escape_stage(batch, lens, iota,
-                      lambda x: _cumsum(x, impl), assemble)
+    es = escape_stage(batch, lens, iota, assemble)
     dmap = es["dmap"]
 
     lens32 = lens.astype(_I32)

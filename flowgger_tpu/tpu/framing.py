@@ -271,97 +271,6 @@ def _aot_spans(framing: str, statics: dict, args):
     return aot.framing_call(framing, args, statics)
 
 
-# per-process decline hysteresis for the Pallas framing tier (one
-# namespace per framing kind, separate from the jnp tier's budgets)
-_PALLAS_STATE: dict = {}
-
-
-def _pallas_spans_probe(framing: str, region_dev, rlen, B: int,
-                        ncap: int, statics: dict, dev_label: str):
-    """Try the single-VMEM Pallas spans kernel; None = declined or
-    disengaged (the caller falls to the jnp scatter ladder).  Declines
-    ride the framing cooldown ladder under their own namespace; in
-    compiled mode only a watchdog timeout declines, anything else
-    raises (``pallas_kernels.must_raise``)."""
-    from . import aot as _aot
-    from . import pallas_kernels as _pallas
-
-    if not _pallas.framing_engaged(B):
-        return None
-    pstate = cooldown_state(_PALLAS_STATE, f"pallas:{framing}")
-    if in_cooldown(pstate):
-        return None
-    interp = _pallas.interpret_mode()
-    p_statics = _aot.pallas_statics(framing, ncap, B)
-    if framing == "syslen":
-        pfn = lambda: _pallas.frame_syslen_spans_pallas(  # noqa: E731
-            region_dev, rlen, interpret=interp, **p_statics)
-    else:
-        pfn = lambda: _pallas.frame_sep_spans_pallas(  # noqa: E731
-            region_dev, rlen, interpret=interp, **p_statics)
-
-    def stage_a_pallas():
-        out = _aot.pallas_call(framing, (region_dev, rlen), p_statics)
-        if out is not None:
-            return out
-        return pfn()
-
-    try:
-        out = _watchdogged(
-            f"pallas/{framing}:{B}x{ncap}:{dev_label}", stage_a_pallas)
-    except Exception as e:  # noqa: BLE001 - decline to the jnp tier, never lose data
-        if _pallas.must_raise(e):
-            raise
-        note_decline(pstate)
-        _metrics.inc("pallas_declines")
-        _events.emit("framing", "pallas_decline", route=framing,
-                     detail=f"{type(e).__name__}: {e}",
-                     cost=B, cost_unit="region_bytes")
-        return None
-    note_success(pstate)
-    return out
-
-
-def _pallas_gather_probe(region_dev, starts_dev, lens_dev, B: int,
-                         rows: int, max_len: int, dev_label: str):
-    """Stage-B analogue of :func:`_pallas_spans_probe`."""
-    from . import aot as _aot
-    from . import pallas_kernels as _pallas
-
-    if not _pallas.framing_engaged(B):
-        return None
-    pstate = cooldown_state(_PALLAS_STATE, "pallas:gather")
-    if in_cooldown(pstate):
-        return None
-    interp = _pallas.interpret_mode()
-    p_statics = _aot.pallas_statics("gather", max_len, B)
-
-    def stage_b_pallas():
-        res = _aot.pallas_call(
-            "gather", (region_dev, starts_dev, lens_dev), p_statics)
-        if res is not None:
-            return res
-        return _pallas.frame_gather_pallas(
-            region_dev, starts_dev, lens_dev, interpret=interp,
-            **p_statics)
-
-    try:
-        out = _watchdogged(
-            f"pallas/gather:{B}x{rows}x{max_len}:{dev_label}",
-            stage_b_pallas)
-    except Exception as e:  # noqa: BLE001 - decline to the jnp tier, never lose data
-        if _pallas.must_raise(e):
-            raise
-        note_decline(pstate)
-        _metrics.inc("pallas_declines")
-        _events.emit("framing", "pallas_decline", route="gather",
-                     detail=f"{type(e).__name__}: {e}",
-                     cost=B, cost_unit="region_bytes")
-        return None
-    note_success(pstate)
-    return out
-
-
 def _aot_gather(statics: dict, args):
     from . import aot
 
@@ -419,17 +328,9 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
             return out
         return kfn()
 
-    # Pallas tier first, where input.tpu_pallas = "on" engaged it: the
-    # single-VMEM spans kernel collapses the pointer-doubling scatter
-    # ladder to one region read; a decline rides its own cooldown
-    # ladder and falls straight to the jnp tier below — same bytes,
-    # same output.
-    out = _pallas_spans_probe(framing, region_dev, rlen, B, ncap,
-                              statics, dev_label)
     slot = f"framing/{framing}:{B}x{ncap}:{dev_label}"
     try:
-        if out is None:
-            out = _watchdogged(slot, stage_a)
+        out = _watchdogged(slot, stage_a)
     except CompileTimeout:
         _metrics.inc("framing_declines")
         _events.emit("framing", "framing_decline", route=framing,
@@ -472,14 +373,9 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
         return frame_gather_jit(region_dev, starts_dev, lens_dev,
                                 max_len=max_len)
 
-    gather_out = _pallas_gather_probe(region_dev, starts_dev, lens_dev,
-                                      B, rows, max_len, dev_label)
     gslot = f"framing/gather:{B}x{rows}x{max_len}:{dev_label}"
     try:
-        if gather_out is not None:
-            batch_dev, lens_c_dev = gather_out
-        else:
-            batch_dev, lens_c_dev = _watchdogged(gslot, stage_b)
+        batch_dev, lens_c_dev = _watchdogged(gslot, stage_b)
     except CompileTimeout:
         _metrics.inc("framing_declines")
         _events.emit("framing", "framing_decline", route=framing,
@@ -487,11 +383,6 @@ def device_frame_region(region: bytes, framing: str, max_len: int,
         raise FramingDeclined("compile watchdog (gather)") from None
     _metrics.inc("framing_rows", n)
     _pack.note_overlen(orig_lens, max_len)
-    if gather_out is not None:
-        # rows that went through the Pallas tier end to end (spans may
-        # have too, but the gather is the [rows, max_len] pass that
-        # defines the tier's throughput accounting)
-        _metrics.inc("pallas_rows", n)
     packed = (batch_dev, lens_c_dev, region, starts_np, orig_lens, n)
     return packed, consumed, err
 

@@ -157,32 +157,16 @@ def fused_compile_timeout_s():
 # driver formats timestamps from ("ok" + ts_keys) — so the driver needs
 # no separate decode output dict at all.
 
-def _rfc5424_leg(batch, lens, *, max_sd, demand, pallas: str):
-    """The rfc5424 decode leg of a fused program: the Pallas
-    single-VMEM structural pass when the tier is engaged (``pallas``
-    is the config-resolved mode string, a static jit arg so flipping
-    the tier retraces), else the demand-narrowed jnp decode."""
-    if pallas in ("compiled", "interpret"):
-        from .rfc5424 import decode_rfc5424_pallas
-
-        return decode_rfc5424_pallas(batch, lens, max_sd=max_sd,
-                                     interpret=pallas == "interpret")
-    from .rfc5424 import decode_rfc5424_jit
-
-    return decode_rfc5424_jit(batch, lens, max_sd=max_sd,
-                              extract_impl="sum", demand=demand)
-
-
 @partial(jax.jit, static_argnames=("max_sd", "suffix", "impl",
-                                   "assemble", "extras", "demand",
-                                   "pallas"))
+                                   "assemble", "extras", "demand"))
 def _fused_rfc5424_gelf(batch, lens, ts_text, ts_len, *, max_sd: int,
                         suffix: bytes, impl: str, assemble: bool,
-                        extras, demand, pallas: str = "off"):
+                        extras, demand):
     from .device_gelf import _encode_kernel
+    from .rfc5424 import decode_rfc5424_jit
 
-    dec = _rfc5424_leg(batch, lens, max_sd=max_sd, demand=demand,
-                       pallas=pallas)
+    dec = decode_rfc5424_jit(batch, lens, max_sd=max_sd,
+                             extract_impl="sum", demand=demand)
     res = _encode_kernel(batch, lens, dec, ts_text, ts_len,
                          suffix=suffix, max_sd=max_sd, impl=impl,
                          assemble=assemble, extras=extras, elide=True)
@@ -293,14 +277,14 @@ def _fused_gelf_gelf(batch, lens, ts_text, ts_len, *, suffix: bytes,
 # row-dependent heads from (fac8/sev8, gap offsets).
 
 @partial(jax.jit, static_argnames=("max_sd", "suffix", "assemble",
-                                   "demand", "pallas"))
+                                   "demand"))
 def _fused_rfc5424_rfc5424(batch, lens, ts_text, ts_len, *, max_sd: int,
-                           suffix: bytes, assemble: bool, demand,
-                           pallas: str = "off"):
+                           suffix: bytes, assemble: bool, demand):
     from .device_rfc5424_out import _encode_kernel
+    from .rfc5424 import decode_rfc5424_jit
 
-    dec = _rfc5424_leg(batch, lens, max_sd=max_sd, demand=demand,
-                       pallas=pallas)
+    dec = decode_rfc5424_jit(batch, lens, max_sd=max_sd,
+                             extract_impl="sum", demand=demand)
     res = _encode_kernel(batch, lens, dec, ts_text, ts_len,
                          suffix=suffix, max_sd=max_sd,
                          assemble=assemble, elide=True)
@@ -325,14 +309,14 @@ def _fused_rfc3164_rfc5424(batch, lens, year, ts_text, ts_len, *,
 
 
 @partial(jax.jit, static_argnames=("max_sd", "suffix", "extras",
-                                   "assemble", "demand", "pallas"))
+                                   "assemble", "demand"))
 def _fused_rfc5424_ltsv(batch, lens, ts_text, ts_len, *, max_sd: int,
-                        suffix: bytes, extras, assemble: bool, demand,
-                        pallas: str = "off"):
+                        suffix: bytes, extras, assemble: bool, demand):
     from .device_ltsv_out import _encode_kernel
+    from .rfc5424 import decode_rfc5424_jit
 
-    dec = _rfc5424_leg(batch, lens, max_sd=max_sd, demand=demand,
-                       pallas=pallas)
+    dec = decode_rfc5424_jit(batch, lens, max_sd=max_sd,
+                             extract_impl="sum", demand=demand)
     res = _encode_kernel(batch, lens, dec, ts_text, ts_len,
                          suffix=suffix, extras=extras,
                          assemble=assemble, elide=True)
@@ -342,14 +326,14 @@ def _fused_rfc5424_ltsv(batch, lens, ts_text, ts_len, *, max_sd: int,
 
 
 @partial(jax.jit, static_argnames=("max_sd", "suffix", "extras",
-                                   "assemble", "demand", "pallas"))
+                                   "assemble", "demand"))
 def _fused_rfc5424_capnp(batch, lens, ts_text, ts_len, *, max_sd: int,
-                         suffix: bytes, extras, assemble: bool, demand,
-                         pallas: str = "off"):
+                         suffix: bytes, extras, assemble: bool, demand):
     from .device_capnp import _encode_kernel
+    from .rfc5424 import decode_rfc5424_jit
 
-    dec = _rfc5424_leg(batch, lens, max_sd=max_sd, demand=demand,
-                       pallas=pallas)
+    dec = decode_rfc5424_jit(batch, lens, max_sd=max_sd,
+                             extract_impl="sum", demand=demand)
     res = _encode_kernel(batch, lens, dec, ts_text, ts_len,
                          suffix=suffix, extras=extras,
                          assemble=assemble, elide=True)
@@ -495,16 +479,13 @@ class FusedRoute:
 
         from .device_gelf import elide_spec
         from .materialize import _scalar_line
-        from .pallas_kernels import fused_leg_mode
         from .rfc5424 import DEFAULT_MAX_SD
-
-        pmode = fused_leg_mode()
 
         def kernel(ts_text, ts_len, assemble):
             return _fused_rfc5424_gelf(
                 b, ln, ts_text, ts_len, max_sd=DEFAULT_MAX_SD,
                 suffix=suffix, impl=impl, assemble=assemble,
-                extras=extras, demand=demand, pallas=pmode)
+                extras=extras, demand=demand)
 
         kernel = fused_wrap(self.name, kernel, (b, ln), suffix, impl,
                            extras)
@@ -518,18 +499,15 @@ class FusedRoute:
         reuses its split module's single-sourced callable elide, stamp
         renderer, and narrowed small fetch."""
         from .materialize import _scalar_line
-        from .pallas_kernels import fused_leg_mode
         from .rfc5424 import DEFAULT_MAX_SD
 
-        pmode = fused_leg_mode()
         if self.name == "rfc5424_rfc5424":
             from . import device_rfc5424_out as m
 
             def kernel(ts_text, ts_len, assemble):
                 return _fused_rfc5424_rfc5424(
                     b, ln, ts_text, ts_len, max_sd=DEFAULT_MAX_SD,
-                    suffix=suffix, assemble=assemble, demand=demand,
-                    pallas=pmode)
+                    suffix=suffix, assemble=assemble, demand=demand)
 
             kernel = fused_wrap(self.name, kernel, (b, ln), suffix,
                                impl, extras)
@@ -565,7 +543,7 @@ class FusedRoute:
                 return _fused_rfc5424_ltsv(
                     b, ln, ts_text, ts_len, max_sd=DEFAULT_MAX_SD,
                     suffix=suffix, extras=extras, assemble=assemble,
-                    demand=demand, pallas=pmode)
+                    demand=demand)
 
             kernel = fused_wrap(self.name, kernel, (b, ln), suffix,
                                impl, extras)
@@ -581,7 +559,7 @@ class FusedRoute:
             return _fused_rfc5424_capnp(
                 b, ln, ts_text, ts_len, max_sd=DEFAULT_MAX_SD,
                 suffix=suffix, extras=extras, assemble=assemble,
-                demand=demand, pallas=pmode)
+                demand=demand)
 
         kernel = fused_wrap(self.name, kernel, (b, ln), suffix, impl,
                            extras)

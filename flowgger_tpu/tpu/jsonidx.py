@@ -42,11 +42,6 @@ import jax.numpy as jnp
 from .rfc5424 import (
     _bitpack32,
     _esc_parity,
-    _row_all,
-    _row_any,
-    _row_max,
-    _row_min,
-    _row_sum,
     _scan_ordinals,
     _slot_geometry,
     _shift_left,
@@ -63,150 +58,13 @@ VT_STRING, VT_NUMBER, VT_TRUE, VT_FALSE, VT_NULL = 0, 1, 2, 3, 4
 VT_OBJECT, VT_ARRAY = 5, 6
 
 
-# ---------------------------------------------------------------------------
-# compiled-NFA string machine (the Pallas stage-1 classifier's core)
-#
-# The string/escape automaton as an explicit DFA over byte classes,
-# resolved in parallel by composing packed transition *functions* with
-# a log-shift ladder — the classic parallel-automaton scan (ParPaRaw's
-# quote/escape machinery, arxiv 1905.13415, and simdjson's stage-1
-# classification recast as one scan).  Four states track (in-string,
-# backslash-run parity):
-#
-#   0 = outside string, even bs-run   2 = inside string, even bs-run
-#   1 = outside string, odd  bs-run   3 = inside string, odd  bs-run
-#
-# Each byte class maps to a state->state function packed 2 bits per
-# state into one i32 (NFA_TABLE below — the "transition table": tiny
-# scalar constants that live in SMEM / fold into the kernel as
-# immediates).  Composition of two packed functions is branchless
-# elementwise shift arithmetic, so an inclusive prefix composition is
-# log2(L) compose steps — one automaton scan replaces the separate
-# quote-parity cumsum + backslash XOR ladder of the parity path, and
-# every op lowers under Mosaic (no gather, no scan primitive).
-#
-# Escape semantics mirror ``rfc5424._esc_parity`` exactly: a quote is
-# escaped iff the backslash run ending just before it has odd length
-# (tracked by the parity bit even *outside* strings, so junk like a
-# lone ``\"`` at top level classifies identically to the parity path).
-
-_S = 4                      # automaton states
-_SB = 2                     # bits per state in a packed function
-
-
-def _nfa_pack(dsts):
-    """Pack a state->state map (tuple of _S destinations) into an i32."""
-    word = 0
-    for s, d in enumerate(dsts):
-        word |= d << (_SB * s)
-    return word
-
-
-# byte class -> packed transition function
-NFA_OTHER = _nfa_pack((0, 0, 2, 2))    # bs-run parity resets
-NFA_QUOTE = _nfa_pack((2, 0, 0, 2))    # real toggles; escaped stays
-NFA_BS = _nfa_pack((1, 0, 3, 2))       # parity toggles
-NFA_IDENT = _nfa_pack((0, 1, 2, 3))    # ladder fill / start-of-row
-NFA_TABLE = (NFA_OTHER, NFA_QUOTE, NFA_BS)
-
-
-def _nfa_compose(g, f):
-    """h = g∘f over packed transition functions (elementwise, variable
-    shifts only — Mosaic-lowerable)."""
-    h = jnp.zeros_like(f)
-    for s in range(_S):
-        fs = (f >> (_SB * s)) & (_S - 1)
-        h = h | (((g >> (_SB * fs)) & (_S - 1)) << (_SB * s))
-    return h
-
-
-def _nfa_string_machine(quote, is_bs):
-    """Resolve the string/escape automaton over [N, L] quote/backslash
-    planes.  Returns ``(outside, escaped)``: the *exclusive* state at
-    each position (the state in which its byte is consumed) projected
-    to the outside-string and odd-backslash-parity predicates — exactly
-    the planes the parity path derives from ``_esc_parity`` + the
-    real-quote cumsum, computed here by one transition-function scan."""
-    L = quote.shape[1]
-    f = jnp.where(quote, NFA_QUOTE,
-                  jnp.where(is_bs, NFA_BS, NFA_OTHER)).astype(_I32)
-    k = 1
-    while k < L:
-        f = _nfa_compose(f, _shift_right(f, k, NFA_IDENT))
-        k <<= 1
-    st = _shift_right(f, 1, NFA_IDENT) & (_S - 1)  # state from start 0
-    outside = st < 2
-    escaped = (st & 1) == 1
-    return outside, escaped
-
-
-def _esc_cap_plane(is_bs):
-    """Positions whose preceding backslash run reached ESC_RUN_CAP —
-    the same cap plane ``_esc_parity(impl='manual')`` derives, computed
-    standalone for the NFA path (whose escape parity is exact at any
-    run length; the cap keeps row-flagging identical to the parity
-    path, so both tiers send the same rows to the scalar oracle)."""
-    from .rfc5424 import ESC_RUN_CAP
-
-    a_k = _shift_right(is_bs, 1, False)
-    for k in range(2, ESC_RUN_CAP + 1):
-        a_k = a_k & _shift_right(is_bs, k, False)
-    return a_k
-
-
-# ---------------------------------------------------------------------------
-# bounded-window lookarounds: reduce_window on the XLA paths, a
-# (W-1)-step shift ladder under ``manual`` (Mosaic has no reduce_window)
-
-def _window_max_before(v, W, fill, manual):
-    """max of v over the W positions ending at each position."""
-    if not manual:
-        return jax.lax.reduce_window(v, fill, jax.lax.max, (1, W), (1, 1),
-                                     ((0, 0), (W - 1, 0)))
-    m = v
-    for k in range(1, W):
-        m = jnp.maximum(m, _shift_right(v, k, fill))
-    return m
-
-
-def _window_min_after(v, W, fill, manual):
-    """min of v over the W positions starting at each position."""
-    if not manual:
-        return jax.lax.reduce_window(v, fill, jax.lax.min, (1, W), (1, 1),
-                                     ((0, 0), (0, W - 1)))
-    m = v
-    for k in range(1, W):
-        m = jnp.minimum(m, _shift_left(v, k, fill))
-    return m
-
-
-def _window_sum_before(v, W, manual):
-    """sum of v over the W positions ending at each position."""
-    if not manual:
-        return jax.lax.reduce_window(v, jnp.int32(0), jax.lax.add,
-                                     (1, W), (1, 1), ((0, 0), (W - 1, 0)))
-    s = v
-    for k in range(1, W):
-        s = s + _shift_right(v, k, 0)
-    return s
-
-
 def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
                      max_fields: int, scan_impl: str, extract_impl: str,
-                     nested: int = 0, string_impl: str = "parity"
-                     ) -> Dict[str, jnp.ndarray]:
+                     nested: int = 0) -> Dict[str, jnp.ndarray]:
     """Tokenize a packed [N, L] batch of one-JSON-object lines into
     per-key span channels (see module docstring).  Returns the channel
-    dict shared by the GELF and JSON-lines decoders.
-
-    ``string_impl`` picks the in/out-of-string classifier: ``"parity"``
-    (quote-parity cumsum + the bit-packed backslash XOR ladder — the
-    XLA paths) or ``"nfa"`` (one compiled-NFA transition-function scan,
-    the Pallas stage-1 path; identical planes on every row the parity
-    ladder classifies exactly, and identical row *flagging* everywhere
-    via the shared ESC_RUN_CAP plane)."""
+    dict shared by the GELF and JSON-lines decoders."""
     N, L = batch.shape
-    manual = scan_impl == "manual"
     lens = lens.astype(_I32)
     iota = jax.lax.broadcasted_iota(_I32, (N, L), 1)
     valid = iota < lens[:, None]
@@ -219,21 +77,12 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     # ---- escaped quotes & string parity ---------------------------------
     is_bs = (bb == 92) & valid
     quote = (bb == ord('"')) & valid
-    if string_impl == "nfa":
-        outside, escaped = _nfa_string_machine(quote, is_bs)
-        real_q = quote & ~escaped
-        cap_viol = _row_any(_esc_cap_plane(is_bs) & quote, manual)
-    else:
-        escaped, cap_plane, cap_words = _esc_parity(is_bs, scan_impl)
-        real_q = quote & ~escaped
-        if cap_plane is not None:
-            cap_viol = _row_any(cap_plane & quote, manual)
-        else:
-            cap_viol = jnp.any((cap_words & _bitpack32(quote)) != 0,
-                               axis=1)
-        (q_incl,) = _scan_ordinals([real_q], scan_impl)
-        q_excl = q_incl - real_q.astype(q_incl.dtype)
-        outside = (q_excl & 1) == 0
+    escaped, cap_words = _esc_parity(is_bs)
+    real_q = quote & ~escaped
+    cap_viol = jnp.any((cap_words & _bitpack32(quote)) != 0, axis=1)
+    (q_incl,) = _scan_ordinals([real_q], scan_impl)
+    q_excl = q_incl - real_q.astype(q_incl.dtype)
+    outside = (q_excl & 1) == 0
     open_q = real_q & outside
     close_q = real_q & ~outside
     inside_str = (~outside) & valid
@@ -247,20 +96,26 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     # packed (position << 8 | byte) reduce-window pass each way.
     bi32 = bb.astype(_I32)
     pv = jnp.where(nonws, (iota << 8) | bi32, -1)
-    rw_p = _window_max_before(pv, WS_WINDOW, jnp.int32(-1), manual)
+    rw_p = jax.lax.reduce_window(pv, jnp.int32(-1), jax.lax.max,
+                                 (1, WS_WINDOW), (1, 1),
+                                 ((0, 0), (WS_WINDOW - 1, 0)))
     ptb_w = _shift_right(rw_p, 1, -1)
     ptb = jnp.where(ptb_w >= 0, ptb_w & 255, 0)
     _BIG = jnp.int32(1 << 30)
     nv = jnp.where(nonws, (iota << 8) | bi32, _BIG)
-    rw_n = _window_min_after(nv, WS_WINDOW, _BIG, manual)
+    rw_n = jax.lax.reduce_window(nv, _BIG, jax.lax.min,
+                                 (1, WS_WINDOW), (1, 1),
+                                 ((0, 0), (0, WS_WINDOW - 1)))
     ntb_w = _shift_left(rw_n, 1, _BIG)
     ntb = jnp.where(ntb_w < _BIG, ntb_w & 255, 0)
 
     # ws run > WS_WINDOW outside strings: a windowed count hitting W+1
     # (edge padding contributes 0, so short runs at the line start can
-    # never flag, matching the shifted-AND ladder's False fill)
+    # never flag)
     run = is_ws & outside
-    rw_run = _window_sum_before(run.astype(_I32), WS_WINDOW + 1, manual)
+    rw_run = jax.lax.reduce_window(run.astype(_I32), jnp.int32(0),
+                                   jax.lax.add, (1, WS_WINDOW + 1), (1, 1),
+                                   ((0, 0), (WS_WINDOW, 0)))
     # every row-disqualifying plane ORs into one mask reduced by a
     # single any at the end
     viol = rw_run == WS_WINDOW + 1
@@ -281,7 +136,7 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
         # and the final '}' at 0
         depth = cum_open.astype(_I32) - cum_close.astype(_I32)
         viol |= (depth < 0) & valid
-        max_depth = _row_max(jnp.where(valid, depth, 0), manual)
+        max_depth = jnp.max(jnp.where(valid, depth, 0), axis=1)
         ok &= max_depth <= 1 + nested
         top = depth == 1
         # exactly one depth-1 '{' (the object) and one depth-0 '}'
@@ -307,12 +162,11 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     # first/last non-ws position with an is-it-the-brace tag packed into
     # the reduction word: first significant byte must be the object
     # open, last must be its close
-    wf = _row_min(jnp.where(nonws, 2 * iota + (~lb).astype(_I32),
-                            2 * L + 2), manual)
+    wf = jnp.min(jnp.where(nonws, 2 * iota + (~lb).astype(_I32),
+                           2 * L + 2), axis=1)
     first_is_lb = (wf & 1) == 0
     first_nonws = wf >> 1
-    wl = _row_max(jnp.where(nonws, 2 * iota + rb.astype(_I32), -1),
-                  manual)
+    wl = jnp.max(jnp.where(nonws, 2 * iota + rb.astype(_I32), -1), axis=1)
     last_is_rb = (wl & 1) == 1
     last_nonws = wl >> 1
     ok &= first_is_lb & last_is_rb & (first_nonws < last_nonws)
@@ -360,7 +214,7 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
             acc = grp[0].astype(_I32)
             for s, m in enumerate(grp[1:], 1):
                 acc = acc + (m.astype(_I32) << (cbits * s))
-            word = _row_sum(acc, manual)
+            word = jnp.sum(acc, axis=1)
             for s in range(len(grp)):
                 outs.append((word >> (cbits * s)) & cmask)
         return outs
@@ -397,7 +251,7 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     # possibly-garbled parity) sends the row to the oracle, which also
     # shields the parity math itself from junk input
     viol |= is_bs & outside
-    ok &= ~_row_any(viol, manual)
+    ok &= ~jnp.any(viol, axis=1)
 
     # number/literal value start: a literal-run start whose previous
     # non-ws byte is ':'
@@ -429,22 +283,22 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     # ---- per-key extraction (packed-sum words) --------------------------
     F = max_fields
     key_open_pos = extract_by_ord(is_key_open, key_ord, iota, F, L,
-                                  extract_impl, manual=manual)
+                                  extract_impl)
     key_close_pos = extract_by_ord(is_key_close, kc_ord, iota, F, L,
-                                   extract_impl, manual=manual)
+                                   extract_impl)
     # value position and class share one extraction word per slot: the
     # class rides bits above the position field (fill L keeps the class
     # field 0; classes span 1..7, exactly the 3-bit field)
     pbits = max(10, int(L + 1).bit_length())
     vs_packed = extract_by_ord(is_val_start, key_ord,
                                iota | (vclass << pbits), F, L,
-                               extract_impl, slot_bits=pbits + 3, manual=manual)
+                               extract_impl, slot_bits=pbits + 3)
     val_start_pos = vs_packed & ((1 << pbits) - 1)
     val_class1 = vs_packed >> pbits
     val_close_pos = extract_by_ord(is_val_close, key_ord, iota, F, L,
-                                   extract_impl, manual=manual)
+                                   extract_impl)
     lit_end_pos = extract_by_ord(lit_end_m, key_ord, iota, F, L,
-                                 extract_impl, manual=manual)
+                                 extract_impl)
     # exactly one value token per key: a string close, a literal run,
     # or (nested mode) a container open.  Key ordinals are constant
     # across a container's interior — quotes/commas/colons there sit at
@@ -454,21 +308,21 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     if nested:
         val_token_m = val_token_m | is_cont_val
     val_tokens = extract_counts_by_ord(val_token_m, key_ord, F,
-                                       extract_impl, manual=manual)
+                                       extract_impl)
     esc_count = extract_counts_by_ord(is_bs & inside_str, key_ord, F,
-                                      extract_impl, manual=manual)
+                                      extract_impl)
 
     field_valid = (jnp.arange(F, dtype=_I32)[None, :] < n_keys[:, None])
-    ok &= _row_all(jnp.where(field_valid, val_tokens == 1,
-                             val_tokens == 0), manual)
-    ok &= _row_all(jnp.where(field_valid, val_class1 >= 1, True), manual)
+    ok &= jnp.all(jnp.where(field_valid, val_tokens == 1,
+                            val_tokens == 0), axis=1)
+    ok &= jnp.all(jnp.where(field_valid, val_class1 >= 1, True), axis=1)
     val_type = jnp.where(field_valid, val_class1 - 1, -1)
 
     # per-key ordering sanity: open < close < value start
-    ok &= _row_all(jnp.where(field_valid,
-                             (key_open_pos < key_close_pos)
-                             & (key_close_pos < val_start_pos), True),
-                   manual)
+    ok &= jnp.all(jnp.where(field_valid,
+                            (key_open_pos < key_close_pos)
+                            & (key_close_pos < val_start_pos), True),
+                  axis=1)
     # extraction-collision guard: multiple val-starts per key would
     # corrupt the packed sums — val_tokens==1 bounds val_close/lit
     # runs/container opens, and >1 val_start implies >1 of those (the
@@ -480,14 +334,14 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     is_string = val_type == VT_STRING
     if nested:
         cont_close_pos = extract_by_ord(nested_close, key_ord, iota, F,
-                                        L, extract_impl, manual=manual)
+                                        L, extract_impl)
         is_cont = (val_type == VT_OBJECT) | (val_type == VT_ARRAY)
         val_end = jnp.where(
             is_string, val_close_pos,
             jnp.where(is_cont, cont_close_pos + 1, lit_end_pos + 1))
-        ok &= _row_all(jnp.where(field_valid & is_cont,
-                                 cont_close_pos > val_start_pos, True),
-                       manual)
+        ok &= jnp.all(jnp.where(field_valid & is_cont,
+                                cont_close_pos > val_start_pos, True),
+                      axis=1)
     else:
         val_end = jnp.where(is_string, val_close_pos, lit_end_pos + 1)
     val_end = jnp.minimum(val_end, lens[:, None])
@@ -495,12 +349,12 @@ def structural_index(batch: jnp.ndarray, lens: jnp.ndarray,
     lit_len = jnp.where(val_type == VT_TRUE, 4,
                         jnp.where(val_type == VT_FALSE, 5,
                                   jnp.where(val_type == VT_NULL, 4, -1)))
-    ok &= _row_all(jnp.where(field_valid & (lit_len > 0),
-                             val_end - val_start_pos == lit_len, True),
-                   manual)
+    ok &= jnp.all(jnp.where(field_valid & (lit_len > 0),
+                            val_end - val_start_pos == lit_len, True),
+                  axis=1)
     # string values must close after they open
-    ok &= _row_all(jnp.where(field_valid & is_string,
-                             val_close_pos > val_start_pos, True), manual)
+    ok &= jnp.all(jnp.where(field_valid & is_string,
+                            val_close_pos > val_start_pos, True), axis=1)
 
     esc_flag = (esc_count > 0) & field_valid
 

@@ -68,12 +68,6 @@ def decode_jsonl_submit(batch, lens, sharded=None):
     # zero-JIT boot: a loaded AOT artifact replaces the trace+compile
     out = decode_call("jsonl", (b, ln))
     if out is None:
-        # Pallas tier: NFA string machine + structural index in one
-        # VMEM pass; None (decline/cooldown/off) falls to the jnp jit
-        from .pallas_kernels import decode_tier
-
-        out = decode_tier("jsonl", b, ln)
-    if out is None:
         out = decode_jsonl_jit(b, ln)
     return (out, b, ln, batch, lens)
 

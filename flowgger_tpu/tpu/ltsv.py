@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 
 from .rfc5424 import (
-    _cummax,
     _days_from_civil,
     _days_in_month,
     _min_where,
@@ -108,7 +107,7 @@ def decode_ltsv(batch: jnp.ndarray, lens: jnp.ndarray,
     is_colon = (bb == ord(":")) & valid
     tag = jnp.where(is_tab, 2 * iota + 1,
                     jnp.where(is_colon, 2 * iota, -1))
-    last_tc = _shift_right(_cummax(tag, scan_impl), 1, -1)
+    last_tc = _shift_right(jax.lax.cummax(tag, axis=1), 1, -1)
     # -1 & 1 == 1, so line start (no prior tab/colon) also counts as tab
     first_colon = is_colon & ((last_tc & 1) == 1)
     # part ordinal of a (non-tab) position = tabs at or before it

@@ -68,9 +68,9 @@ ESC_RUN_CAP = 16
 _I32 = jnp.int32
 
 
-def _min_where(mask, packed, notfound, manual: bool = False):
+def _min_where(mask, packed, notfound):
     """Per-row min of ``packed`` where mask, else ``notfound``."""
-    return _row_min(jnp.where(mask, packed, notfound), manual)
+    return jnp.min(jnp.where(mask, packed, notfound), axis=1)
 
 
 def _at(iota, pos, values, default=0):
@@ -92,9 +92,9 @@ def _days_from_civil(y, m, d):
 
 
 def _days_in_month(y, m):
-    # arithmetic form (no table lookup — gathers are banned, and Mosaic
-    # can't lower them anyway): 31 for odd months through July and even
-    # months from August, 30 otherwise, February special-cased
+    # arithmetic form (no table lookup — gathers are banned): 31 for odd
+    # months through July and even months from August, 30 otherwise,
+    # February special-cased
     is31 = jnp.where(m >= 8, (m % 2) == 0, (m % 2) == 1)
     base = jnp.where(is31, 31, 30)
     leap = (y % 4 == 0) & ((y % 100 != 0) | (y % 400 == 0))
@@ -108,21 +108,6 @@ def _shift_right(arr, k, fill):
 
 def _shift_left(arr, k, fill):
     return jnp.pad(arr[:, k:], ((0, 0), (0, k)), constant_values=fill)
-
-
-def _cumsum(x, impl: str):
-    """Inclusive prefix sum along axis 1.  ``impl='manual'`` uses a
-    Hillis–Steele log-shift ladder built only from pad/slice/add, which
-    Mosaic (Pallas TPU) lowers where lax's scan-based cumsum cannot."""
-    if impl in ("lax", "mm"):
-        return jnp.cumsum(x, axis=1)
-    x = x.astype(_I32)
-    L = x.shape[1]
-    k = 1
-    while k < L:
-        x = x + _shift_right(x, k, 0)
-        k <<= 1
-    return x
 
 
 def _scan_ordinals(channels, impl: str):
@@ -141,16 +126,14 @@ def _scan_ordinals(channels, impl: str):
     accumulator keeps sums <= 2**(2*bits) <= 2**24 exact.  Packing
     applies for bits <= 12, i.e. L <= 4094; wider geometries use one
     int8 matmul per channel (i32 accumulate, exact for any mask).
-    Other impls fall back to bit-packed i32 cumsums."""
+    ``impl='lax'`` (the CPU path) is bit-packed i32 cumsums."""
     L = channels[0].shape[1]
     bits = max(10, int(L + 1).bit_length())
     # ordinal channels are re-read by every downstream extraction word,
     # so they come back as int16 where L allows (ordinals are bounded by
     # L, and the guard keeps L < 32000 < 2**15-1) — halving the HBM
-    # bytes of the hottest reads in the kernel.  The 'manual'
-    # (Pallas/Mosaic) path stays int32: 16-bit vector support inside
-    # the block kernel is not worth the risk.
-    out_t = jnp.int16 if (impl != "manual" and L < 32000) else _I32
+    # bytes of the hottest reads in the kernel.
+    out_t = jnp.int16 if L < 32000 else _I32
     if impl != "mm":
         mask = (1 << bits) - 1
         per = max(1, 31 // bits)
@@ -160,7 +143,7 @@ def _scan_ordinals(channels, impl: str):
             word = grp[0].astype(_I32)
             for s, ch in enumerate(grp[1:], 1):
                 word = word + (ch.astype(_I32) << (bits * s))
-            scanned = _cumsum(word, impl)
+            scanned = jnp.cumsum(word, axis=1)
             for s in range(len(grp)):
                 outs.append(((scanned >> (bits * s)) & mask).astype(out_t))
         return outs
@@ -189,75 +172,6 @@ def _scan_ordinals(channels, impl: str):
     return outs
 
 
-def _cummax(x, impl: str):
-    if impl in ("lax", "mm"):
-        return jax.lax.cummax(x, axis=1)
-    L = x.shape[1]
-    k = 1
-    neg = jnp.iinfo(x.dtype).min
-    while k < L:
-        x = jnp.maximum(x, _shift_right(x, k, neg))
-        k <<= 1
-    return x
-
-
-# ---- Mosaic-safe row reductions -------------------------------------
-# Mosaic (this jax's Pallas TPU lowering) implements float but not
-# integer/bool reductions, so the manual path computes every axis-1
-# reduction as a log-shift ladder (elementwise adds/min/max over the
-# VMEM-resident plane) and reads column 0.  The XLA path keeps the
-# native reductions.
-
-def _row_sum(x, manual: bool = False):
-    if not manual:
-        return jnp.sum(x, axis=1)
-    x = x.astype(_I32)
-    L = x.shape[1]
-    k = 1
-    while k < L:
-        x = x + _shift_left(x, k, 0)
-        k <<= 1
-    return x[:, 0]
-
-
-def _row_max(x, manual: bool = False):
-    if not manual:
-        return jnp.max(x, axis=1)
-    x = x.astype(_I32)
-    L = x.shape[1]
-    k = 1
-    neg = jnp.iinfo(_I32).min
-    while k < L:
-        x = jnp.maximum(x, _shift_left(x, k, neg))
-        k <<= 1
-    return x[:, 0]
-
-
-def _row_min(x, manual: bool = False):
-    if not manual:
-        return jnp.min(x, axis=1)
-    x = x.astype(_I32)
-    L = x.shape[1]
-    k = 1
-    pos = jnp.iinfo(_I32).max
-    while k < L:
-        x = jnp.minimum(x, _shift_left(x, k, pos))
-        k <<= 1
-    return x[:, 0]
-
-
-def _row_any(x, manual: bool = False):
-    if not manual:
-        return jnp.any(x, axis=1)
-    return _row_max(x.astype(_I32), True) != 0
-
-
-def _row_all(x, manual: bool = False):
-    if not manual:
-        return jnp.all(x, axis=1)
-    return ~_row_any(~x, True)
-
-
 def _bitpack32(plane):
     """[N, L] bool -> [N, ceil(L/32)] uint32, bit j of word w = plane[:,
     32w+j].  The reshape/broadcast form beats 32 strided slices on TPU:
@@ -282,32 +196,19 @@ def _bitunpack32(words, L):
     return b.reshape(N, W * 32)[:, :L]
 
 
-def _esc_parity(is_bs, impl: str):
+def _esc_parity(is_bs):
     """Backslash-run parity without a scan: ``escaped[i]`` <=> the run of
     backslashes ending at ``i-1`` has odd length (exact for runs <
     ESC_RUN_CAP).
 
-    Returns a 3-tuple ``(escaped, cap_plane, cap_words)`` — exactly one
-    of the cap channels is non-None, by path:
-    - manual (Pallas/Mosaic): ``cap_plane`` is an [N, L] bool plane of
-      positions whose run reached the cap; ``cap_words`` is None;
-    - XLA: ``cap_words`` is the [N, ceil(L/32)] packed uint32 stream
-      (same bit layout as ``_bitpack32``) for the caller to AND against
-      a packed quote plane; ``cap_plane`` is None.
+    Returns ``(escaped, cap_words)``: ``cap_words`` is the
+    [N, ceil(L/32)] packed uint32 stream (same bit layout as
+    ``_bitpack32``) of positions whose run reached the cap, for the
+    caller to AND against a packed quote plane.
 
-    The ladder XORs nested run-indicators ``a_k = bs at i-1..i-k``.  On
-    the XLA path the [N, L] bool planes are bit-packed into [N, L/32]
-    uint32 lanes first — the 15 shifted ANDs then touch 1/32nd of the
-    bytes.  The Pallas path (`impl='manual'`) keeps the plane form:
-    Mosaic has no cheap lane-crossing reshape."""
-    if impl == "manual":
-        a_k = _shift_right(is_bs, 1, False)
-        escaped = a_k
-        for k in range(2, ESC_RUN_CAP):
-            a_k = a_k & _shift_right(is_bs, k, False)
-            escaped = escaped ^ a_k
-        cap_hit = a_k & _shift_right(is_bs, ESC_RUN_CAP, False)
-        return escaped, cap_hit, None
+    The ladder XORs nested run-indicators ``a_k = bs at i-1..i-k``.  The
+    [N, L] bool planes are bit-packed into [N, L/32] uint32 lanes first
+    — the 15 shifted ANDs then touch 1/32nd of the bytes."""
     N, L = is_bs.shape
     packed = _bitpack32(is_bs)
 
@@ -325,7 +226,7 @@ def _esc_parity(is_bs, impl: str):
     assert ESC_RUN_CAP < 32  # sr() handles shifts of 1..31 only
     cap = a_k & sr(packed, ESC_RUN_CAP)
 
-    return _bitunpack32(esc, L), None, cap
+    return _bitunpack32(esc, L), cap
 
 
 def _slot_geometry(L: int):
@@ -340,7 +241,7 @@ def _slot_geometry(L: int):
 
 
 def extract_by_ord(mask, ord_, value, K, fill, extract_impl="sum",
-                   slot_bits=None, manual: bool = False):
+                   slot_bits=None):
     """out[n, k] = ``value`` at the position with ordinal k+1 (masked),
     else ``fill``.  The ordinal channel must hit each ordinal at most
     once per row.  Shared by every format kernel.
@@ -377,15 +278,14 @@ def extract_by_ord(mask, ord_, value, K, fill, extract_impl="sum",
             if base + s < K:
                 acc = acc + (jnp.where(mask & (ord_ == base + 1 + s),
                                        v1, 0) << (slot_bits * s))
-        word = _row_sum(acc, manual)
+        word = jnp.sum(acc, axis=1)
         for slot in range(min(slots, K - base)):
             v = (word >> (slot_bits * slot)) & slot_mask
             cols.append(jnp.where(v == 0, fill, v - 1))
     return jnp.stack(cols, axis=1)
 
 
-def extract_counts_by_ord(mask, ord_, K, extract_impl="sum",
-                          manual: bool = False):
+def extract_counts_by_ord(mask, ord_, K, extract_impl="sum"):
     """out[n, k] = number of masked positions with ordinal k+1 — an
     *accumulating* variant of extract_by_ord (the mask may hit many
     positions per ordinal; each per-word slot's total is bounded by
@@ -405,7 +305,7 @@ def extract_counts_by_ord(mask, ord_, K, extract_impl="sum",
             if base + s < K:
                 acc = acc + (jnp.where(mask & (ord_ == base + 1 + s),
                                        1, 0) << (slot_bits * s))
-        word = _row_sum(acc, manual)
+        word = jnp.sum(acc, axis=1)
         for slot in range(min(slots, K - base)):
             cols.append((word >> (slot_bits * slot)) & slot_mask)
     return jnp.stack(cols, axis=1)
@@ -420,9 +320,8 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
 
     ``scan_impl`` picks the prefix-scan lowering: ``"mm"`` (MXU matmul
     against a triangular ones matrix — the TPU default, ~2.4x a VPU
-    cumsum), ``"lax"`` (jnp.cumsum — the CPU default), or ``"manual"``
-    (a pad/slice/add log-shift ladder Mosaic can lower, so the same body
-    runs inside the Pallas block kernel).  None resolves by backend.
+    cumsum) or ``"lax"`` (jnp.cumsum — the CPU default).  None resolves
+    by backend.
 
     ``extract_impl`` picks how k-th-delimiter values come out:
     - ``"sum"``: bit-packed masked sums — few wide passes, no scatter;
@@ -433,22 +332,14 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     Identical outputs; differential-tested against each other."""
     if scan_impl is None:
         scan_impl = best_scan_impl()
-    manual = scan_impl == "manual"
     N, L = batch.shape
 
     def _extract(mask, ord_, value, K, fill):
-        return extract_by_ord(mask, ord_, value, K, fill, extract_impl,
-                              manual=manual)
-
-    def _extract_counts(mask, ord_, K):
-        return extract_counts_by_ord(mask, ord_, K, extract_impl,
-                                     manual=manual)
+        return extract_by_ord(mask, ord_, value, K, fill, extract_impl)
     lens = lens.astype(_I32)
     iota = jax.lax.broadcasted_iota(_I32, (N, L), 1)
     bu = batch  # uint8 view for comparisons (half the HBM traffic of i32)
     valid = iota < lens[:, None]
-    # fill follows the batch dtype: u8 on the jnp tier, i32 under the
-    # Pallas kernels (Mosaic cannot carry u8 constants)
     bb = jnp.where(valid, bu, jnp.asarray(0, bu.dtype))
     # uint8 byte plane: every mask read touches 1 byte/position; sites
     # that need arithmetic widen inside their own fusion (free VPU work
@@ -487,21 +378,18 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # (exact while run < ESC_RUN_CAP; cap hits feeding a quote send the
     # row to the scalar oracle — semantics preserved via fallback).
     is_bs = (bb == 92) & valid
-    escaped, cap_plane, cap_words = _esc_parity(is_bs, scan_impl)
+    escaped, cap_words = _esc_parity(is_bs)
 
     # ---- stage B scan: space ordinals + quote parity ----------------------
     is_sp = (bb == 32) & valid
     quote = (bb == ord('"')) & valid
     real_q_all = quote & ~escaped
-    if cap_plane is not None:
-        viol2d = cap_plane & quote
-    else:
-        # packed-ladder path: the cap-hit stream never leaves bit-packed
-        # form — AND against the packed quote plane and fold the row-wise
-        # "a quote consumed an unknown run parity" violation straight
-        # into ok (no [N, L] unpack for a channel consumed row-wise)
-        viol2d = jnp.zeros_like(quote)
-        ok &= ~jnp.any((cap_words & _bitpack32(quote)) != 0, axis=1)
+    # the cap-hit stream never leaves bit-packed form — AND against the
+    # packed quote plane and fold the row-wise "a quote consumed an
+    # unknown run parity" violation straight into ok (no [N, L] unpack
+    # for a channel consumed row-wise)
+    viol2d = jnp.zeros_like(quote)
+    ok &= ~jnp.any((cap_words & _bitpack32(quote)) != 0, axis=1)
     sp_ord, q_incl_all = _scan_ordinals([is_sp, real_q_all], scan_impl)
     sp = _extract(is_sp, sp_ord, iota, 6, L)  # [N, 6]
     ok &= sp[:, 5] < L
@@ -510,7 +398,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
 
     # ---- PRI + version (rs:74-92) ---------------------------------------
     gt = _min_where((bb == ord(">")) & (iota > start0[:, None]) & valid,
-                    iota, L, manual)
+                    iota, L)
     ndig = gt - start0 - 1
     ok &= (gt < f_end[:, 0]) & (ndig >= 1) & (ndig <= 3)
     # digits weighted by 10^(gt-1-iota); non-digit in range -> violation
@@ -540,7 +428,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
         + (jnp.where(in_ts & (r == 19) & (bb == ord(".")), 1, 0) << 28)
         + (jnp.where((iota == gt[:, None] + 1) & (bb == ord("1")), 1, 0) << 29)
     )
-    word1 = _row_sum(w1, manual)
+    word1 = jnp.sum(w1, axis=1)
     year = word1 & 0x3FFF
     month = (word1 >> 14) & 0x7F
     day = (word1 >> 21) & 0x7F
@@ -554,7 +442,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
         + (dz * ((r == 17) * 10 + (r == 18)) << 14)
         + (jnp.where(pri_zone, dig * w_pri, 0) << 21)
     )
-    word2 = _row_sum(w2, manual)
+    word2 = jnp.sum(w2, axis=1)
     hour = word2 & 0x7F
     minute = (word2 >> 7) & 0x7F
     sec = (word2 >> 14) & 0x7F
@@ -579,7 +467,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     rd = r - 20
     # first non-digit offset in [0, 10) == run length (capped)
     frac_run = _min_where(in_ts & (rd >= 0) & (rd < 10) & ~is_digit,
-                          rd, 10, manual)
+                          rd, 10)
     frac_run = jnp.minimum(frac_run, jnp.maximum(tlen - 20, 0))
     frac_len = jnp.where(has_frac, frac_run, 0)
     ok &= jnp.where(has_frac, (frac_len >= 1) & (frac_len <= 9), True)
@@ -589,7 +477,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
         + (rd == 6) * 100 + (rd == 7) * 10 + (rd == 8) * 1
     )
     in_frac = in_ts & (rd >= 0) & (rd < frac_len[:, None])
-    nanos = _row_sum(jnp.where(in_frac, dig * w_frac, 0), manual)
+    nanos = jnp.sum(jnp.where(in_frac, dig * w_frac, 0), axis=1)
 
     # offset zone at r2 = r - opos; word3 packs its digits, the
     # remaining single-position flags, and (for the common L <= 1023
@@ -612,7 +500,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     )
     if pack_high:
         w3 = w3 + (jnp.where((bb >= 128) & valid, 1, 0) << 19)
-    word3 = _row_sum(w3, manual)
+    word3 = jnp.sum(w3, axis=1)
     oh = word3 & 0x7F
     om = (word3 >> 7) & 0x7F
     is_zulu = ((word3 >> 14) & 1) == 1
@@ -644,8 +532,8 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # quotes (header fields may legally contain '"'); subtracting the
     # running count at rest_s restores the in-rest-only ordinals the
     # grammar needs — one fused reduction instead of a second scan.
-    q_before_rest = _row_max(
-        jnp.where(valid & (iota < rest_s[:, None]), q_incl_all, 0), manual)
+    q_before_rest = jnp.max(
+        jnp.where(valid & (iota < rest_s[:, None]), q_incl_all, 0), axis=1)
     q_excl = (q_incl_all - real_q_all.astype(q_incl_all.dtype)
               - q_before_rest[:, None])
     real_q = real_q_all & in_rest
@@ -689,7 +577,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     rb_sb = (((L << 3) | 7) + 1).bit_length()
     rb_word = extract_by_ord(rbrack, rb_ord, (iota << 3) | rb_payload,
                              max_sd + 1, L << 3, extract_impl,
-                             slot_bits=rb_sb, manual=manual)
+                             slot_bits=rb_sb)
     rb_pos = rb_word >> 3
     rb_flags = rb_word & 7
     rb_found = rb_pos < L
@@ -704,7 +592,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # truncated view never changes an accepted row's zone.
     term_col = rb_found & (((rb_flags & 4) != 0)
                            | (rb_pos == (lens - 1)[:, None]))
-    sd_end_zone = _row_min(jnp.where(term_col, rb_pos, L), manual)
+    sd_end_zone = jnp.min(jnp.where(term_col, rb_pos, L), axis=1)
     zone_c = in_rest & (iota <= sd_end_zone[:, None]) & is_sd[:, None]
     oq_mask = open_q & zone_c
     cq_mask = close_q & zone_c
@@ -738,8 +626,8 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # every block's ']' must be a legal terminator
     rb_legal = (rb_flags[:, :max_sd] & 1) != 0
     ok &= jnp.where(is_sd,
-                    _row_all(jnp.where(blk_idx_valid, rb_legal, True),
-                             manual), True)
+                    jnp.all(jnp.where(blk_idx_valid, rb_legal, True),
+                            axis=1), True)
 
     # sd_id span per block: blk_start+1 .. first space (must precede ']').
     # The first space of block k is the only structural space there not
@@ -754,8 +642,8 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     sid_sp_mask = is_sp & outside & zone_c & ~prev_closeq & ~prev_sp
     sid_end = _extract(sid_sp_mask, rb_ord + 1, iota, max_sd, L)
     ok &= jnp.where(is_sd,
-                    _row_all(jnp.where(blk_idx_valid, sid_end < blk_rb, True),
-                             manual), True)
+                    jnp.all(jnp.where(blk_idx_valid, sid_end < blk_rb, True),
+                            axis=1), True)
 
     # pair regions: strictly between sd_id space and block ']'
     in_pair = jnp.zeros((N, L), dtype=bool)
@@ -788,7 +676,7 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # oq_ord is parity-derived (not a cumsum), so the pair total is the
     # max ordinal over the zone's open quotes rather than a last-column
     # read of a running count
-    pair_total = _row_max(jnp.where(oq_mask, oq_ord, 0), manual)
+    pair_total = jnp.max(jnp.where(oq_mask, oq_ord, 0), axis=1)
     pair_count = jnp.where(is_sd, pair_total, 0)
     ok &= jnp.where(is_sd, pair_count <= max_pairs, True)
 
@@ -799,7 +687,8 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     # value, open-quote ordinal attributes each backslash to its pair —
     # one accumulating extract replaces the two bs-cumsum channels
     inside_val = (q_excl % 2) == 1
-    val_esc_count = _extract_counts(is_bs & inside_val, oq_ord, max_pairs)
+    val_esc_count = extract_counts_by_ord(is_bs & inside_val, oq_ord,
+                                          max_pairs, extract_impl)
 
     pair_valid = (jnp.arange(max_pairs, dtype=_I32)[None, :]
                   < pair_count[:, None])
@@ -819,10 +708,9 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
 
     # name sanity per extracted pair: a run was found and it is nonempty
     # ('=' sits at oq_pos-1, so the run spans [ns_pos, oq_pos-1)).
-    ok &= _row_all(jnp.where(pair_valid, ns_pos <= oq_pos - 2, True),
-                   manual)
+    ok &= jnp.all(jnp.where(pair_valid, ns_pos <= oq_pos - 2, True), axis=1)
 
-    ok &= _row_all(jnp.where(pair_valid, cq_pos > oq_pos, True), manual)
+    ok &= jnp.all(jnp.where(pair_valid, cq_pos > oq_pos, True), axis=1)
     name_end = oq_pos - 1  # position of '='
 
 
@@ -851,17 +739,16 @@ def decode_rfc5424(batch: jnp.ndarray, lens: jnp.ndarray,
     is_ws = ((bb >= 9) & (bb <= 13)) | ((bb >= 28) & (bb <= 32))
     non_ws = valid & ~is_ws
     trim_end = jnp.maximum(
-        _row_max(jnp.where(non_ws, iota + 1, 0), manual), start0)
-    msg_a = _min_where(non_ws & (iota >= msg_start[:, None]), iota, L,
-                       manual)
+        jnp.max(jnp.where(non_ws, iota + 1, 0), axis=1), start0)
+    msg_a = _min_where(non_ws & (iota >= msg_start[:, None]), iota, L)
     msg_trim_start = jnp.minimum(msg_a, trim_end)
     if pack_high:
         has_high = ((word3 >> 19) & 0x3FF) > 0
     else:
-        has_high = _row_any((bb >= 128) & valid, manual)
+        has_high = jnp.any((bb >= 128) & valid, axis=1)
 
     # single reduction over every accumulated 2-D violation
-    ok &= ~_row_any(viol2d, manual)
+    ok &= ~jnp.any(viol2d, axis=1)
 
     return {
         "ok": ok,
@@ -940,14 +827,6 @@ def decode_rfc5424_submit(batch, lens, max_sd: int = DEFAULT_MAX_SD,
         # (same channels, byte-identical by construction); None → jit
         out = decode_call("rfc5424", (batch_dev, lens_dev),
                           {"max_sd": max_sd, "extract_impl": impl})
-        if out is None:
-            # Pallas tier: the single-VMEM structural decode (one HBM
-            # read of the batch, one index write) — None on decline /
-            # cooldown / tier off, then the jnp jit exactly as before
-            from .pallas_kernels import decode_tier
-
-            out = decode_tier("rfc5424", batch_dev, lens_dev,
-                              max_sd=max_sd)
         if out is None:
             out = decode_rfc5424_jit(batch_dev, lens_dev,
                                      max_sd=max_sd, extract_impl=impl)
@@ -1074,8 +953,7 @@ def pack_on_device(buf: jnp.ndarray, starts: jnp.ndarray, lens: jnp.ndarray,
 
     NOTE: XLA lowers this gather poorly on TPU (near-serial); the hot
     path packs on the host instead (tpu/pack.py pack_lines_2d).  Kept
-    for the CPU backend and as the seam a Pallas DMA pack kernel will
-    replace.
+    for the CPU backend.
     """
     idx = starts[:, None].astype(_I32) + jnp.arange(max_len, dtype=_I32)[None, :]
     mask = jnp.arange(max_len, dtype=_I32)[None, :] < lens[:, None]
@@ -1090,101 +968,3 @@ def decode_chunk_jit(buf, starts, lens, max_len=DEFAULT_MAX_LEN,
     batch = pack_on_device(buf, starts, lens, max_len)
     return decode_rfc5424(batch, jnp.minimum(lens, max_len),
                           max_sd=max_sd, max_pairs=max_pairs)
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU block kernel
-# ---------------------------------------------------------------------------
-# The XLA version above materializes each masked reduction's operands in
-# HBM (~60 passes over [N, L] int32). The Pallas form tiles the batch into
-# [BLOCK_ROWS, L] VMEM blocks and runs the *same* decode body (with
-# Mosaic-lowerable manual scans) entirely on-chip: HBM traffic collapses
-# to one read of the bytes plus the compact span outputs.
-
-_KEYS_1D = (
-    "ok", "bom", "facility", "severity", "days", "sod", "off", "nanos",
-    "host_start", "host_end", "app_start", "app_end", "proc_start",
-    "proc_end", "msgid_start", "msgid_end", "msg_start", "sd_count",
-    "pair_count", "full_start", "trim_end", "msg_trim_start", "has_high",
-)
-_KEYS_SD = ("sid_start", "sid_end")
-_KEYS_PAIR = ("name_start", "name_end", "val_start", "val_end",
-              "pair_sd", "val_has_esc")
-_BOOL_KEYS = ("ok", "bom", "val_has_esc", "has_high")
-
-DEFAULT_BLOCK_ROWS = 256
-
-
-def decode_rfc5424_pallas(batch, lens, max_sd: int = DEFAULT_MAX_SD,
-                          max_pairs: int = DEFAULT_MAX_PAIRS,
-                          block_rows: int = DEFAULT_BLOCK_ROWS,
-                          interpret: bool = False) -> Dict[str, jnp.ndarray]:
-    """Same contract as decode_rfc5424, executed as a Pallas TPU kernel.
-
-    ``interpret=True`` runs the kernel in Pallas interpreter mode so the
-    CPU-backend tests can differential-check this path too.
-    """
-    from jax.experimental import pallas as pl
-
-    N_orig, L = batch.shape
-    N = N_orig
-    br = min(block_rows, N)
-    if N % br:
-        pad = br - N % br
-        batch = jnp.pad(batch, ((0, pad), (0, 0)))
-        lens = jnp.pad(lens, (0, pad))
-        N += pad
-    # widen u8 -> i32 outside the kernel: Mosaic cannot load u8 VMEM
-    # refs on this jax; one elementwise pass, and the decode body's
-    # byte compares are dtype-agnostic
-    batch = batch.astype(_I32)
-    lens2 = lens.astype(_I32).reshape(N, 1)
-
-    def kernel(b_ref, l_ref, *outs):
-        res = decode_rfc5424(b_ref[...], l_ref[...][:, 0],
-                             max_sd=max_sd, max_pairs=max_pairs,
-                             scan_impl="manual")
-        i = 0
-        for k in _KEYS_1D:
-            outs[i][...] = res[k].astype(_I32).reshape(br, 1)
-            i += 1
-        for k in _KEYS_SD:
-            outs[i][...] = res[k].astype(_I32)
-            i += 1
-        for k in _KEYS_PAIR:
-            outs[i][...] = res[k].astype(_I32)
-            i += 1
-
-    out_shape = (
-        [jax.ShapeDtypeStruct((N, 1), _I32) for _ in _KEYS_1D]
-        + [jax.ShapeDtypeStruct((N, max_sd), _I32) for _ in _KEYS_SD]
-        + [jax.ShapeDtypeStruct((N, max_pairs), _I32) for _ in _KEYS_PAIR]
-    )
-    out_specs = (
-        [pl.BlockSpec((br, 1), lambda i: (i, 0)) for _ in _KEYS_1D]
-        + [pl.BlockSpec((br, max_sd), lambda i: (i, 0)) for _ in _KEYS_SD]
-        + [pl.BlockSpec((br, max_pairs), lambda i: (i, 0)) for _ in _KEYS_PAIR]
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(N // br,),
-        in_specs=[
-            pl.BlockSpec((br, L), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(batch, lens2)
-
-    res = {}
-    i = 0
-    for k in _KEYS_1D:
-        v = outs[i][:N_orig, 0]
-        res[k] = (v != 0) if k in _BOOL_KEYS else v
-        i += 1
-    for k in _KEYS_SD + _KEYS_PAIR:
-        v = outs[i][:N_orig]
-        res[k] = (v != 0) if k in _BOOL_KEYS else v
-        i += 1
-    return res

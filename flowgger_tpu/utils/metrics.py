@@ -131,9 +131,6 @@ _COUNTERS = (
     # multi-chip mesh + fused routes + device framing
     "sharded_kernels", "fused_rows", "fused_fallbacks",
     "framing_rows", "framing_declines", "framing_span_fetch_bytes",
-    # Pallas structural-pass tier (tpu/pallas_kernels.py): rows that
-    # went through a Pallas kernel, and declines back to the jnp tier
-    "pallas_rows", "pallas_declines",
     # zero-JIT boot (tpu/aot.py): artifact-store traffic; per-reason
     # rejects ride the aot_rejects_{reason} family
     "aot_hits", "aot_misses", "aot_rejects",

@@ -395,6 +395,40 @@ def test_scan_impl_single_source():
     assert best_scan_impl() == aot._scan_impl_for(jax.default_backend())
 
 
+def test_fused_statics_and_key_recipe_name_no_kernel_tier():
+    """One decode leg per fused route: the statics that key a fused
+    artifact hold what the program is traced from and nothing that
+    selects between tiers, and are every static the program takes
+    besides ``assemble``.  An artifact keyed with the removed
+    ``pallas`` entry misses (the store's ordinary miss path)."""
+    import inspect
+
+    from flowgger_tpu.tpu import fused_routes
+
+    for route in aot.FUSED_ROUTES:
+        statics = aot.fused_statics(route, b"\n", "mm", ())
+        want = {"suffix", "impl", "extras", "demand", "elide"}
+        if route.startswith("rfc5424_"):
+            want.add("max_sd")
+        assert set(statics) == want, route
+        takes = set(inspect.signature(
+            getattr(fused_routes, f"_fused_{route}").__wrapped__).parameters)
+        assert "pallas" not in takes
+        spec = [["uint8", [ROWS, MAX_LEN]], ["int32", [ROWS]]]
+        key = aot.entry_key(f"fused_{route}", "tpu", statics, spec)
+        assert key != aot.entry_key(f"fused_{route}", "tpu",
+                                    {**statics, "pallas": "off"}, spec)
+
+
+@pytest.mark.parametrize("family", ["pallas", "nonsense"])
+def test_cli_refuses_a_family_that_is_not_there(family, tmp_path):
+    assert aot.FAMILIES == ("decode", "fused", "encode", "framing")
+    with pytest.raises(ValueError, match=f"unknown family.*{family}"):
+        aot.main(["build", "--out", str(tmp_path / "art"),
+                  "--families", family, "--rows", str(ROWS)])
+    assert not (tmp_path / "art").exists()
+
+
 def test_warm_artifacts_restores_cache_config(art_dir, tmp_path):
     """warm_artifacts must put the process-global persistent-cache
     config back (an in-process build-then-serve caller would otherwise

@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from benchmark import corpus, reference
+from benchmark.tests import test_check as harness_check
 from flowgger_tpu.config import Config
 from flowgger_tpu.decoders import RFC5424Decoder
 from flowgger_tpu.encoders import GelfEncoder
@@ -483,3 +484,15 @@ def test_a_bound_batch_gets_the_sub_span_from_whatever_thread_it_is_on():
     (rec,) = obs_trace.tracer.snapshot()
     assert [(sp["stage"], sp["parent"], sp["rows"], sp["bytes"])
             for sp in rec["sub"]] == [("splice", "encode", 2, 740)]
+
+
+def test_the_harness_reads_a_sinks_share_in_chunks_as_it_read_it_whole(
+        tmp_path):
+    """``correct`` on a sink of this cell's size rests on the harness
+    fingerprinting it 16 MiB at a time (PR 33).  The harness's own test
+    of that is not among the tests the floor counts, so it runs here:
+    every sink it names at every chunk size it names."""
+    for name in harness_check.SINKS:
+        for chunk in harness_check.CHUNKS:
+            harness_check.test_a_share_read_in_chunks_is_the_share_read_whole(
+                tmp_path, name, chunk)

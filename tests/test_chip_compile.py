@@ -8,11 +8,6 @@ at the production default batch ``[16384, 512]``, with the statics
 a TPU.  A pass says the chip's compiler accepts the program; it is not
 a chip run and gives no time.
 
-The six Pallas kernels of ``tpu/pallas_kernels.py`` are strict xfails:
-Mosaic (jax 0.9.0 / libtpu 0.0.34) refuses each of them, with one of
-the two messages quoted below.  A PR that repairs one will see its case
-XPASS and fail the suite: drop the mark then.
-
 Layout rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped fixture, which skips where it cannot
 be described; nothing touches it at import, in ``conftest.py`` or in a
@@ -30,9 +25,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under
 
 ROWS, MAX_LEN = 16384, 512          # tpu/batch.py DEFAULT_BATCH_SIZE x DEFAULT_MAX_LINE_LEN
 REGION = 4 << 20                    # tpu/batch.py _RAW_REGION_CAP: one flush's raw bytes
-
-READ1 = "cannot statically prove that index in dimension 1 is a multiple of 128"
-TRUNCI = "Unsupported target bitwidth for truncation"
 
 
 @pytest.fixture(scope="module")
@@ -78,36 +70,83 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
+@pytest.fixture(autouse=True)
+def _scans_as_on_a_tpu(monkeypatch):
+    """The decoders pick their scan lowering from ``jax.default_backend()``,
+    which is the CPU here: give them what they pick on the chip."""
+    from flowgger_tpu.tpu import aot
+
+    monkeypatch.setattr(aot, "_scan_impl_for", lambda platform: "mm")
+
+
 def _compiles(jitted, *args, **statics):
     compiled = jitted.lower(*args, **statics).compile()
     assert compiled.memory_analysis() is not None
     return compiled
 
 
-# -- the jnp tiers the default configuration serves: these must compile ------
+# -- the programs the default configuration serves: these must compile ------
 
 def test_decode_rfc5424_jit_compiles_for_v5e(spec):
     import jax.numpy as jnp
 
     from flowgger_tpu.tpu.rfc5424 import decode_rfc5424_jit
 
-    _compiles(decode_rfc5424_jit, spec(jnp.uint8, ROWS, MAX_LEN),
-              spec(jnp.int32, ROWS))
+    args = spec(jnp.uint8, ROWS, MAX_LEN), spec(jnp.int32, ROWS)
+    # the scans are the MXU's, as on the chip, not the CPU's cumsum
+    assert "dot_general" in decode_rfc5424_jit.lower(*args).as_text()
+    _compiles(decode_rfc5424_jit, *args)
+
+
+def test_decode_rfc5424_jit_rescue_tier_compiles_for_v5e(spec):
+    """The pair rescue's second round: 16 pairs over the smallest bucket."""
+    import jax.numpy as jnp
+
+    from flowgger_tpu.tpu.rfc5424 import RESCUE_MAX_PAIRS, decode_rfc5424_jit
+
+    _compiles(decode_rfc5424_jit, spec(jnp.uint8, 256, MAX_LEN),
+              spec(jnp.int32, 256), max_pairs=RESCUE_MAX_PAIRS)
+
+
+@pytest.mark.parametrize("assemble", [False, True],
+                         ids=["probe", "assemble"])
+def test_split_gelf_encode_kernel_compiles_for_v5e(spec, assemble):
+    """The split device encoder the economics probe, over the decode
+    program's channels, and for the assembled rows its compaction."""
+    import jax
+    import jax.numpy as jnp
+
+    from flowgger_tpu.tpu import aot, device_common, device_gelf
+    from flowgger_tpu.tpu.rfc5424 import DEFAULT_MAX_SD, decode_rfc5424_jit
+
+    b, ln = spec(jnp.uint8, ROWS, MAX_LEN), spec(jnp.int32, ROWS)
+    dec = {k: spec(v.dtype, *v.shape)
+           for k, v in jax.eval_shape(decode_rfc5424_jit, b, ln).items()}
+    ts = spec(jnp.uint8, ROWS, device_common.TS_W if assemble else 0)
+    statics = dict(suffix=b"\0", max_sd=DEFAULT_MAX_SD,
+                   impl=aot._scan_impl_for("tpu"), assemble=assemble,
+                   extras=(), elide=True)
+    _compiles(device_gelf._encode_kernel, b, ln, dec, ts, ln, **statics)
+    if assemble:
+        acc, out_len = jax.eval_shape(
+            lambda *a: device_gelf._encode_kernel(*a, **statics),
+            b, ln, dec, ts, ln)[:2]
+        _compiles(device_common._compact_kernel,
+                  spec(acc.dtype, *acc.shape),
+                  spec(out_len.dtype, *out_len.shape), spec(jnp.bool_, ROWS))
 
 
 @pytest.mark.parametrize("assemble", [False, True],
                          ids=["probe", "assemble"])
 def test_fused_rfc5424_gelf_compiles_for_v5e(spec, assemble):
-    """The default fused program: jnp decode leg (``pallas="off"`` is
-    what ``fused_leg_mode()`` gives with no ``input.tpu_pallas`` key),
-    MXU scans, the GELF route's demand mask, nul framing."""
+    """The default fused program: MXU scans, the GELF route's demand
+    mask, nul framing."""
     import jax.numpy as jnp
 
-    from flowgger_tpu.tpu import aot, fused_routes, pallas_kernels
+    from flowgger_tpu.tpu import aot, fused_routes
     from flowgger_tpu.tpu.device_common import TS_W
     from flowgger_tpu.tpu.rfc5424 import DEFAULT_MAX_SD
 
-    assert pallas_kernels.fused_leg_mode() == "off"
     # the probe uploads no timestamp text; the assemble the rendered
     # [N, TS_W] block (device_common.fetch_encode_driver)
     ts_w = TS_W if assemble else 0
@@ -117,8 +156,7 @@ def test_fused_rfc5424_gelf_compiles_for_v5e(spec, assemble):
         spec(jnp.uint8, ROWS, ts_w), spec(jnp.int32, ROWS),
         max_sd=DEFAULT_MAX_SD, suffix=b"\0",
         impl=aot._scan_impl_for("tpu"), assemble=assemble, extras=(),
-        demand=fused_routes.DEMAND["rfc5424_gelf"],
-        pallas=pallas_kernels.fused_leg_mode())
+        demand=fused_routes.DEMAND["rfc5424_gelf"])
 
 
 def test_frame_sep_spans_jit_compiles_for_v5e(spec):
@@ -138,81 +176,3 @@ def test_frame_gather_jit_compiles_for_v5e(spec):
     _compiles(framing.frame_gather_jit, spec(jnp.uint8, REGION),
               spec(jnp.int32, ROWS), spec(jnp.int32, ROWS),
               **aot.framing_statics("gather", MAX_LEN, REGION))
-
-
-# -- the Pallas tier: refused by Mosaic, one strict xfail per kernel ---------
-
-def _refused(msg):
-    return pytest.mark.xfail(
-        strict=True,
-        reason=f"Mosaic refuses this kernel: {msg!r} (ROADMAP D2: repair "
-               "or delete; drop this mark when the kernel compiles)")
-
-
-@_refused(READ1)
-def test_frame_sep_spans_pallas_compiles_for_v5e(spec):
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import aot, pallas_kernels
-
-    B = 1 << 16
-    _compiles(pallas_kernels.frame_sep_spans_pallas, spec(jnp.uint8, B),
-              spec(jnp.int32), **aot.pallas_statics("line", 256, B))
-
-
-@_refused(READ1)
-def test_frame_syslen_spans_pallas_compiles_for_v5e(spec):
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import aot, pallas_kernels
-
-    B = 1 << 16
-    _compiles(pallas_kernels.frame_syslen_spans_pallas,
-              spec(jnp.uint8, B), spec(jnp.int32),
-              **aot.pallas_statics("syslen", 256, B))
-
-
-@_refused(READ1)
-def test_frame_gather_pallas_compiles_for_v5e(spec):
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import aot, pallas_kernels
-
-    B = 1 << 20
-    _compiles(pallas_kernels.frame_gather_pallas, spec(jnp.uint8, B),
-              spec(jnp.int32, 4096), spec(jnp.int32, 4096),
-              **aot.pallas_statics("gather", MAX_LEN, B))
-
-
-@_refused(TRUNCI)
-def test_decode_jsonl_pallas_compiles_for_v5e(spec):
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import pallas_kernels
-
-    _compiles(pallas_kernels.decode_jsonl_pallas,
-              spec(jnp.uint8, 4096, 256), spec(jnp.int32, 4096))
-
-
-@_refused(TRUNCI)
-def test_structural_index_pallas_compiles_for_v5e(spec):
-    import jax
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import pallas_kernels
-    from flowgger_tpu.tpu.jsonl import DEFAULT_MAX_FIELDS
-
-    fn = jax.jit(lambda b, ln: pallas_kernels.structural_index_pallas(
-        b, ln, DEFAULT_MAX_FIELDS))
-    _compiles(fn, spec(jnp.uint8, 4096, 256), spec(jnp.int32, 4096))
-
-
-@_refused(TRUNCI)
-def test_decode_rfc5424_pallas_compiles_for_v5e(spec):
-    import jax
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu.rfc5424 import decode_rfc5424_pallas
-
-    _compiles(jax.jit(decode_rfc5424_pallas),
-              spec(jnp.uint8, 4096, 256), spec(jnp.int32, 4096))

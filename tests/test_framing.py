@@ -106,44 +106,102 @@ def _run(cfg, splitter_cls, stream, sizes, encoder_cls=LTSVEncoder):
 # span kernels vs the host splitters (the FC03 differential contract)
 # ---------------------------------------------------------------------------
 
-def test_frame_sep_spans_match_host_split():
+@pytest.mark.parametrize("sep,name,strip", [
+    (b"\n", "line", True), (b"\0", "nul", False)], ids=["line", "nul"])
+def test_frame_sep_spans_match_host_split(sep, name, strip):
     import random
 
     rng = random.Random(11)
-    for sep, name, strip in ((b"\n", "line", True), (b"\0", "nul", False)):
-        for trial in range(12):
-            lines = []
-            for _ in range(rng.randrange(0, 50)):
-                body = bytes(rng.randrange(1, 256)
-                             for _ in range(rng.randrange(0, 40)))
-                lines.append(body.replace(sep, b"~"))
-            if trial % 3 == 0:
-                lines += [b"", b"cr tail\r", b"\r"]
-            region = b"".join(ln + sep for ln in lines)
-            if not region:
-                continue
-            hs, hl, hn, _carry = pack._split_np(region, strip_cr=strip,
-                                                sep=sep[0])
-            p, consumed, err = framing.device_frame_region(
-                region, name, MAX_LEN, n_records=region.count(sep))
-            assert not err and consumed == len(region)
-            assert p[5] == hn
-            assert np.array_equal(p[3][:hn], hs)
-            assert np.array_equal(p[4], hl)
+    for trial in range(12):
+        lines = []
+        for _ in range(rng.randrange(0, 50)):
+            body = bytes(rng.randrange(1, 256)
+                         for _ in range(rng.randrange(0, 40)))
+            lines.append(body.replace(sep, b"~"))
+        if trial % 3 == 0:
+            lines += [b"", b"cr tail\r", b"\r"]
+        region = b"".join(ln + sep for ln in lines)
+        if not region:
+            continue
+        hs, hl, hn, _carry = pack._split_np(region, strip_cr=strip,
+                                            sep=sep[0])
+        p, consumed, err = framing.device_frame_region(
+            region, name, MAX_LEN, n_records=region.count(sep))
+        assert not err and consumed == len(region)
+        assert p[5] == hn
+        assert np.array_equal(p[3][:hn], hs)
+        assert np.array_equal(p[4], hl)
 
 
-def test_frame_syslen_spans_match_host_scan():
-    cases = [
-        b"5 hello0 14 hello world!!3 abc",
-        b"".join(b"%d %s" % (len(m), m)
-                 for m in [b"", b"x" * 200, b"mid dle"]),
-        b"5 hello7 incomp",          # incomplete body -> carry
-        b"5 helloxx junk",           # bad prefix -> err
-        b" leading space",           # empty prefix -> err
-        b"123",                      # no space yet -> carry, no err
-        b"",
-    ]
-    for region in cases:
+@pytest.mark.parametrize("case", ["split_record", "overflow"])
+def test_frame_sep_spans_kernel_at_a_regions_end(case):
+    """The span kernel itself, where ``device_frame_region``'s callers
+    never take it: a region whose last record is split across its end
+    (the tail stays unconsumed, as ``split_chunk``'s carry), bytes past
+    ``rlen`` in the bucket, and more separators than ``ncap`` slots."""
+    rng = np.random.default_rng(7)
+    if case == "overflow":
+        region = np.frombuffer((b"x\n" * 100).ljust(4096, b"\0"), np.uint8)
+        out = framing.frame_sep_spans_jit(region, 200, sep=10,
+                                          strip_cr=True, ncap=64)
+        assert bool(out["overflow"]) and int(out["n"]) == 100
+        return
+    for t in range(10):
+        lines = [bytes(rng.integers(32, 127, rng.integers(0, 60))
+                       .astype(np.uint8))
+                 for _ in range(rng.integers(1, 30))]
+        blob = b"".join(ln + (b"\r\n" if t % 3 == 0 else b"\n")
+                        for ln in lines)
+        if t % 2 == 0:
+            blob += b"partial-tail"
+        # the bucket is longer than the region and not zero past it
+        reg = np.full(len(blob) + int(rng.integers(0, 64)), 10, np.uint8)
+        reg[:len(blob)] = np.frombuffer(blob, np.uint8)
+        out = framing.frame_sep_spans_jit(reg, np.int32(len(blob)), sep=10,
+                                          strip_cr=True, ncap=64)
+        hs, hl, hn, carry = pack.split_chunk(blob, strip_cr=True)
+        assert not bool(out["overflow"])
+        assert int(out["n"]) == hn
+        assert int(out["consumed"]) == len(blob) - len(carry)
+        assert np.array_equal(np.asarray(out["starts"])[:hn], hs), t
+        assert np.array_equal(np.asarray(out["lens"])[:hn], hl), t
+
+
+def _syslen_cases(group):
+    if group == "handpicked":
+        return [
+            b"5 hello0 14 hello world!!3 abc",
+            b"".join(b"%d %s" % (len(m), m)
+                     for m in [b"", b"x" * 200, b"mid dle"]),
+            b"5 hello7 incomp",          # incomplete body -> carry
+            b"5 helloxx junk",           # bad prefix -> err
+            b" leading space",           # empty prefix -> err
+            b"123",                      # no space yet -> carry, no err
+            b"",
+        ]
+    if group == "edges":
+        return [
+            b"5 hello",                  # exactly one record
+            b"0 " * 5,                   # empty records only
+            b"3 abc12 nodigitspace",     # a chain, then garbage
+            b"03 abc",                   # leading zero
+            b"5 hello14 hello world!!3 abc12 trunc",
+        ]
+    rng = np.random.default_rng(1)
+    cases = []
+    for _ in range(12):
+        recs = [bytes(rng.integers(33, 127, size=int(rng.integers(0, 50)))
+                      .astype(np.uint8))
+                for _ in range(int(rng.integers(0, 12)))]
+        extra = [b"", b"12", b"12 abc", b"garbage no prefix",
+                 b"0 "][int(rng.integers(0, 5))]
+        cases.append(b"".join(b"%d " % len(r) + r for r in recs) + extra)
+    return cases
+
+
+@pytest.mark.parametrize("group", ["handpicked", "edges", "random"])
+def test_frame_syslen_spans_match_host_scan(group):
+    for region in _syslen_cases(group):
         hs, hl, hn, hcons, herr = _scan_syslen_region(region)
         p, c, e = framing.device_frame_region(
             region, "syslen", MAX_LEN,
@@ -173,6 +231,25 @@ def test_frame_gather_matches_host_pack_including_oversized():
     assert np.array_equal(p[3], hp[3])
     assert np.array_equal(p[4], hp[4])
     assert p[5] == hp[5]
+
+
+def test_frame_gather_of_syslen_spans_clamps_and_zero_fills():
+    """The gather behind syslen spans (starts that follow a prefix, not
+    a separator): every row is its record clipped to ``max_len``, zero
+    from there to the row's end, rows past ``n`` empty."""
+    rng = np.random.default_rng(3)
+    recs = [b"x" * int(k) for k in rng.integers(0, 300, 30)]
+    region = b"".join(b"%d " % len(r) + r for r in recs)
+    p, consumed, err = framing.device_frame_region(
+        region, "syslen", MAX_LEN, n_records=region.count(b" "))
+    assert (p[5], consumed, err) == (len(recs), len(region), False)
+    bat, lens_c = np.asarray(p[0]), np.asarray(p[1])
+    assert any(len(r) > MAX_LEN for r in recs)
+    for i, r in enumerate(recs):
+        assert bytes(bat[i][:lens_c[i]]) == r[:MAX_LEN], i
+        assert p[4][i] == len(r)
+    assert not bat[np.arange(bat.shape[1])[None, :] >= lens_c[:, None]].any()
+    assert not lens_c[len(recs):].any()
 
 
 # ---------------------------------------------------------------------------

@@ -92,8 +92,10 @@ def test_namespace_is_derived_from_code():
     from flowgger_tpu.lint import FREE_TABLES, KNOWN_KEYS
 
     for dead in ("metrics.jsonl", "input.tls_threads",
-                 "output.tls_compatibility_level", "output.tls_compression"):
+                 "output.tls_compatibility_level", "output.tls_compression",
+                 "input.tpu_pallas"):
         assert dead not in KNOWN_KEYS, dead
+    assert len(KNOWN_KEYS) == 153
     for live in ("input.format", "input.tpu_batch_size",
                  "input.tpu_breaker_fallback_ratio", "input.queue_policy",
                  "output.kafka_retry_init", "output.tls_recovery_delay_max",

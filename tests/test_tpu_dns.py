@@ -59,6 +59,23 @@ def test_corpus_differential():
             f"divergence on {ln!r}:\n  kernel: {kernel}\n  oracle: {oracle}")
 
 
+def test_mm_scan_impl_matches_lax():
+    """scan_impl='mm' (MXU tri-matmul scans, what every TPU run lowers)
+    against 'lax' (the CPU's cumsum), channel for channel."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flowgger_tpu.tpu import dns, pack
+
+    batch, lens, *_ = pack.pack_lines_2d(list(CORPUS), 256)
+    a, b = (jax.jit(lambda bt, ln, impl=impl: dns.decode_dns(
+        bt, ln, scan_impl=impl))(jnp.asarray(batch), jnp.asarray(lens))
+        for impl in ("lax", "mm"))
+    assert np.asarray(a["ok"]).any()
+    for k in a:
+        assert (np.asarray(a[k]) == np.asarray(b[k])).all(), k
+
+
 def _run_block(lines, enc_cls, merger, cfg=CFG):
     dec = DNSDecoder(cfg)
     enc = enc_cls(cfg)

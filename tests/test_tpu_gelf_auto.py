@@ -78,6 +78,19 @@ def test_corpus_differential():
     assert_identical(CORPUS)
 
 
+def test_corpus_differential_under_the_tpus_scan_lowering(monkeypatch):
+    """The oracle differential again with the scans lowered as every TPU
+    run lowers them (MXU tri-matmul), which the CPU backend never picks
+    of itself; eagerly, so that no cached CPU trace answers."""
+    import jax
+
+    from flowgger_tpu.tpu import aot
+
+    monkeypatch.setattr(aot, "_scan_impl_for", lambda platform: "mm")
+    with jax.disable_jit():
+        assert_identical(CORPUS)
+
+
 def test_fast_path_coverage():
     import jax.numpy as jnp
     import numpy as np

@@ -90,6 +90,50 @@ def test_corpus_differential():
             f"divergence on {ln!r}:\n  kernel: {kernel}\n  oracle: {oracle}")
 
 
+def test_corpus_differential_under_the_tpus_scan_lowering(monkeypatch):
+    """The oracle differential again with the scans lowered as every TPU
+    run lowers them (MXU tri-matmul), which the CPU backend never picks
+    of itself (``run_both`` is eager: no cached CPU trace answers)."""
+    from flowgger_tpu.tpu import aot
+
+    monkeypatch.setattr(aot, "_scan_impl_for", lambda platform: "mm")
+    test_corpus_differential()
+
+
+@pytest.mark.parametrize("name,nested,max_len", [
+    ("jsonl", 4, 160),      # the JSON-lines decoder's index (depth channel)
+    ("gelf", 0, 256),       # the GELF screen: flat only, no depth channel
+    ("esc_cap", 4, 64),     # backslash runs of 15, 16 and 21 before a quote
+    ("wide", 4, 4608),      # past L = 4094: one int8 matmul per channel
+])
+def test_structural_index_mm_matches_lax(name, nested, max_len):
+    """scan_impl='mm' (MXU tri-matmul scans, what every TPU run lowers)
+    against 'lax' (the CPU's cumsum), channel for channel, for the
+    structural index both JSON decoders ride."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flowgger_tpu.tpu import jsonidx, pack
+
+    if name == "gelf":
+        from test_tpu_gelf_auto import CORPUS as GELF_CORPUS
+
+        lines = [ln.encode("utf-8") for ln in GELF_CORPUS]
+    elif name == "esc_cap":
+        lines = [b'{"s":"' + b"\\" * nbs + b'q"}' for nbs in (15, 16, 21)]
+    else:
+        lines = BLOCK_CORPUS
+    batch, lens, *_ = pack.pack_lines_2d(lines, max_len)
+    a, b = (jax.jit(lambda bt, ln, impl=impl: jsonidx.structural_index(
+        bt, ln, max_fields=8, scan_impl=impl, extract_impl="sum",
+        nested=nested))(jnp.asarray(batch), jnp.asarray(lens))
+        for impl in ("lax", "mm"))
+    assert set(a) == set(b)
+    for k in a:
+        assert (np.asarray(a[k]) == np.asarray(b[k])).all(), k
+    assert np.asarray(a["ok"]).any() or name == "esc_cap"
+
+
 def test_nested_spans_on_tier():
     """Depth-capped nested containers decode as spans (ok=True), only
     beyond-cap rows fall back."""

@@ -75,6 +75,19 @@ def test_corpus_with_schema():
     assert_identical(CORPUS, _SCHEMA_CFG)
 
 
+def test_corpus_differential_under_the_tpus_scan_lowering(monkeypatch):
+    """The oracle differential again with the scans lowered as every TPU
+    run lowers them (MXU tri-matmul), which the CPU backend never picks
+    of itself; eagerly, so that no cached CPU trace answers."""
+    import jax
+
+    from flowgger_tpu.tpu import aot
+
+    monkeypatch.setattr(aot, "_scan_impl_for", lambda platform: "mm")
+    with jax.disable_jit():
+        assert_identical(CORPUS, _SCHEMA_CFG)
+
+
 def test_suffixes():
     cfg = _SCHEMA_CFG + '[input.ltsv_suffixes]\nu64 = "_u64"\ni64 = "_i64"\n'
     assert_identical(CORPUS, cfg)
@@ -92,6 +105,24 @@ def test_fast_path_coverage():
     out = ltsv.decode_ltsv_jit(jnp.asarray(batch), jnp.asarray(lens))
     okf = np.asarray(out["ok"])[:n]
     assert okf.mean() >= 0.7, list(zip(clean, okf))
+
+
+def test_mm_scan_impl_matches_lax():
+    """scan_impl='mm' (MXU tri-matmul scans, what every TPU run lowers)
+    against 'lax' (the CPU's cumsum), channel for channel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flowgger_tpu.tpu import ltsv, pack
+
+    batch, lens, *_ = pack.pack_lines_2d([ln.encode() for ln in CORPUS], 256)
+    a, b = (jax.jit(lambda bt, ln, impl=impl: ltsv.decode_ltsv(
+        bt, ln, scan_impl=impl))(jnp.asarray(batch), jnp.asarray(lens))
+        for impl in ("lax", "mm"))
+    assert np.asarray(a["ok"]).any()
+    for k in a:
+        assert (np.asarray(a[k]) == np.asarray(b[k])).all(), k
 
 
 def test_fuzz_differential():

@@ -257,6 +257,19 @@ def test_random_structured_lines():
     assert_identical(lines)
 
 
+def test_corpus_differential_under_the_tpus_scan_lowering(monkeypatch):
+    """The oracle differential again with the scans lowered as every TPU
+    run lowers them (MXU tri-matmul), which the CPU backend never picks
+    of itself; eagerly, so that no cached CPU trace answers."""
+    import jax
+
+    from flowgger_tpu.tpu import aot
+
+    monkeypatch.setattr(aot, "_scan_impl_for", lambda platform: "mm")
+    with jax.disable_jit():
+        assert_identical(CORPUS)
+
+
 def test_long_line_fallback():
     long_msg = "x" * 2000
     lines = [f"<13>1 2015-08-05T15:53:45Z h a p m - {long_msg}"]
@@ -292,57 +305,63 @@ def test_batch_handler_end_to_end():
     assert got == want
 
 
-def test_pallas_block_kernel_matches_xla():
-    """The Pallas block kernel shares the decode body (manual scans);
-    interpreter mode must agree with the XLA path on every output."""
+def test_a_toml_that_still_sets_the_removed_tier_key_starts_the_block_route():
+    """``input.tpu_pallas`` left the namespace with the tier it chose.
+    A config that still carries it is treated as any key the program
+    does not read: ``--check`` names it, and the handler starts on the
+    block route and writes what it writes without the key."""
+    import queue
+
+    from flowgger_tpu.block import EncodedBlock
+    from flowgger_tpu.config import Config
+    from flowgger_tpu.encoders import GelfEncoder
+    from flowgger_tpu.lint import lint_config
+    from flowgger_tpu.mergers import NulMerger
+    from flowgger_tpu.tpu.batch import BatchHandler
+
+    def run(toml):
+        cfg = Config.from_string(toml)
+        tx = queue.Queue()
+        h = BatchHandler(tx, ORACLE, GelfEncoder(cfg), cfg, fmt="rfc5424",
+                         start_timer=False, merger=NulMerger())
+        assert h._block_route_ok()
+        for ln in CORPUS:
+            h.handle_bytes(ln.encode("utf-8"))
+        h.flush()
+        h.close()
+        out = []
+        while not tx.empty():
+            item = tx.get_nowait()
+            assert isinstance(item, EncodedBlock)
+            out.append(bytes(item.data))
+        return cfg, b"".join(out)
+
+    cfg, got = run('[input]\ntpu_pallas = "auto"\n')
+    _, want = run("")
+    assert got and got == want
+    warns = lint_config(cfg)
+    assert len(warns) == 1 and "input.tpu_pallas" in warns[0]
+
+
+@pytest.mark.parametrize("max_len", [512, 2048, 4608])
+def test_mm_scan_impl_matches_lax(max_len):
+    """scan_impl='mm' (MXU tri-matmul scans, what every TPU run lowers)
+    must be numerically identical to the lax scans, channel for channel:
+    at the default width, at the wide-L geometry where the f32 packing
+    uses more slot bits, and past L = 4094 where two channels no longer
+    share one f32 matmul and each takes an int8 one."""
     import jax.numpy as jnp
 
     from flowgger_tpu.tpu import rfc5424
 
     lines = [ln.encode("utf-8") for ln in CORPUS]
-    batch, lens, chunk, starts, orig, n = pack.pack_lines_2d(lines, 512)
-    ref = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens))
-    pal = rfc5424.decode_rfc5424_pallas(jnp.asarray(batch), jnp.asarray(lens),
-                                        interpret=True)
-    for k in ref:
-        a = np.asarray(ref[k])
-        b = np.asarray(pal[k])[:a.shape[0]]
-        assert a.shape == b.shape and (a == b).all(), k
-
-
-def test_manual_scan_impl_matches_lax():
-    """scan_impl='manual' (the Mosaic-lowerable ladder) must be
-    numerically identical to the lax scans."""
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import rfc5424
-
-    lines = [ln.encode("utf-8") for ln in CORPUS]
-    batch, lens, chunk, starts, orig, n = pack.pack_lines_2d(lines, 512)
-    a = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens))
+    batch, lens, *_ = pack.pack_lines_2d(lines, max_len)
+    a = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens),
+                               scan_impl="lax")
     b = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens),
-                               scan_impl="manual")
+                               scan_impl="mm")
     for k in a:
         assert (np.asarray(a[k]) == np.asarray(b[k])).all(), k
-
-
-def test_mm_scan_impl_matches_lax():
-    """scan_impl='mm' (MXU tri-matmul scans, the TPU default) must be
-    numerically identical to the lax scans — including the wide-L
-    geometry where the f32 packing uses more slot bits."""
-    import jax.numpy as jnp
-
-    from flowgger_tpu.tpu import rfc5424
-
-    lines = [ln.encode("utf-8") for ln in CORPUS]
-    for max_len in (512, 2048):
-        batch, lens, *_ = pack.pack_lines_2d(lines, max_len)
-        a = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens),
-                                   scan_impl="lax")
-        b = rfc5424.decode_rfc5424(jnp.asarray(batch), jnp.asarray(lens),
-                                   scan_impl="mm")
-        for k in a:
-            assert (np.asarray(a[k]) == np.asarray(b[k])).all(), (k, max_len)
 
 
 def test_scatter_extract_impl_matches_sum():

@@ -4,7 +4,6 @@
 Usage: python tools/deep_fuzz.py [seed] [trials]
        python tools/deep_fuzz.py --routes fused [seed] [trials]
        python tools/deep_fuzz.py --routes framing [seed] [trials]
-       python tools/deep_fuzz.py --routes pallas [seed] [trials]
        python tools/deep_fuzz.py --routes jsonl,dns [seed] [trials]
 Prints per-route mismatches (none expected) and a FAILURES count.
 A bounded version runs in CI as tests/test_cross_route_fuzz.py.
@@ -21,22 +20,12 @@ route (rfc5424/rfc3164/ltsv/gelf → GELF) over line/nul/syslen framing
 against its scalar oracle, run eagerly (``jax.disable_jit()``) so the
 byte-identity claim is checked even on hosts whose XLA cannot compile
 the fused programs.  ci.sh runs a bounded pass as its slow fuzz step.
-
-``--routes pallas`` fuzzes the interpret-mode Pallas kernels
-(flowgger_tpu/tpu/pallas_kernels.py): span kernels vs the host
-splitters' scalar scans on randomized regions (malformed tails, empty
-records, mid-prefix truncation), the compiled-NFA structural
-classifier vs the jnp lax/sum screen on randomized JSON rows, and the
-end-to-end handler — tpu_pallas = "on" vs the host-framed path — over
-chunk plans that split records mid-byte and mid-syslen-prefix.
-Geometries are held fixed so each interpret program compiles once.
 """
 import os, queue, random, re, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 FUSED_MODE = False
 FRAMING_MODE = False
-PALLAS_MODE = False
 ROUTE_FILTER = None
 if "--routes" in sys.argv:
     i = sys.argv.index("--routes")
@@ -50,12 +39,10 @@ if "--routes" in sys.argv:
         FUSED_MODE = True
     elif val == "framing":
         FRAMING_MODE = True
-    elif val == "pallas":
-        PALLAS_MODE = True
     else:
         ROUTE_FILTER = set(val.split(","))
 
-if FUSED_MODE or FRAMING_MODE or PALLAS_MODE:
+if FUSED_MODE or FRAMING_MODE:
     # fused/framing modes never touch the device-encode compiles (the
     # routes they exercise have no device-encode tier engaged): inline
     # guarded calls can never hang, so the watchdog comes off entirely
@@ -604,197 +591,6 @@ if FRAMING_MODE:
                       f"trial={trial} want={len(want)} got={len(got)}")
     engaged = _registry.get("framing_rows") > 0
     print("ENGAGED:", engaged, "FAILURES:", fails)
-    sys.exit(1 if fails or not engaged else 0)
-
-if PALLAS_MODE:
-    # ---- interpret-mode Pallas kernel fuzz (tpu/pallas_kernels.py) ----
-    # Three differentials per trial: (a) the single-VMEM span kernels
-    # vs the host splitters' scalar scans on a randomized region
-    # (partial tails, bad prefixes, empty records), (b) the
-    # compiled-NFA structural classifier vs the jnp lax/sum screen on
-    # randomized JSON rows (escape runs straddling ESC_RUN_CAP,
-    # truncation, non-JSON), and (c) the end-to-end handler with
-    # tpu_pallas = "on" vs the all-host pipeline across chunk plans
-    # that split records mid-byte and mid-syslen-prefix — which also
-    # checks the Pallas decode tier against the scalar decoders, since
-    # any divergence surfaces as an output byte diff.  Kernel
-    # geometries are held fixed so each interpret program compiles
-    # exactly once; wall time then scales with trials, not shapes.
-    import numpy as np
-
-    from flowgger_tpu.splitters import (LineSplitter, NulSplitter,
-                                        SyslenSplitter,
-                                        _scan_syslen_region)
-    from flowgger_tpu.tpu import framing as _framing
-    from flowgger_tpu.tpu import jsonidx as _ji
-    from flowgger_tpu.tpu import pack as _pack
-    from flowgger_tpu.tpu import pallas_kernels as _pk
-    from flowgger_tpu.utils.metrics import registry as _registry
-
-    # interpret programs run inline (no single-flight semaphore): with
-    # FLOWGGER_COMPILE_TIMEOUT_MS=0 above nothing can decline on time
-    _framing._watchdogged = lambda slot, fn: fn()
-
-    B, NCAP = 4096, 256  # fixed span-kernel geometry
-
-    def _cfg(pallas_on, lanes):
-        return Config.from_string(
-            "[input]\n"
-            f'tpu_framing = "{"on" if pallas_on else "off"}"\n'
-            f'tpu_pallas = "{"on" if pallas_on else "off"}"\n'
-            'tpu_fuse = "off"\n'
-            "tpu_max_line_len = 192\n"
-            + (f"tpu_lanes = {lanes}\n" if lanes > 1 else ""))
-
-    class _ChunkedStream:
-        def __init__(self, data, sizes):
-            self.data, self.pos = data, 0
-            self.sizes, self.i = sizes or [len(data) or 1], 0
-
-        def read(self, n):
-            if self.pos >= len(self.data):
-                return b""
-            sz = max(1, self.sizes[self.i % len(self.sizes)])
-            self.i += 1
-            out = self.data[self.pos:self.pos + sz]
-            self.pos += len(out)
-            return out
-
-    def _sizes_from_cuts(stream, forced):
-        cuts = {c for c in forced if 0 < c < len(stream)}
-        for _ in range(rng.randrange(0, 14)):
-            if len(stream) > 1:
-                cuts.add(rng.randrange(1, len(stream)))
-        prev, sizes = 0, []
-        for c in sorted(cuts):
-            sizes.append(c - prev)
-            prev = c
-        sizes.append(max(1, len(stream) - prev))
-        return sizes
-
-    def _run(stream, splitter_cls, pallas_on, lanes, sizes):
-        tx = queue.Queue()
-        h = BatchHandler(tx, RFC5424Decoder(), LTSVEncoder(CFG),
-                         _cfg(pallas_on, lanes), fmt="rfc5424",
-                         start_timer=False, merger=None)
-        splitter_cls().run(_ChunkedStream(stream, sizes), h)
-        h.close()
-        out = []
-        while not tx.empty():
-            item = tx.get_nowait()
-            out.extend(item.iter_unframed()
-                       if isinstance(item, EncodedBlock) else [item])
-        return out
-
-    def _region(blob):
-        buf = np.zeros(B, np.uint8)
-        buf[:len(blob)] = np.frombuffer(blob, np.uint8)
-        return buf
-
-    import jax as _jax
-    _si_ref = _jax.jit(lambda b, l: _ji.structural_index(
-        b, l, max_fields=8, scan_impl="lax", extract_impl="sum",
-        nested=4))
-
-    fails = 0
-    trials = int(sys.argv[2]) if len(sys.argv) > 2 else 4
-    for trial in range(trials):
-        lines = [ln.replace(b"\n", b"~").replace(b"\0", b"~")[:160]
-                 for ln in corpus(rng.randrange(1, 24), gen_rfc5424)]
-        line_stream = b"".join(ln + b"\n" for ln in lines)
-        nul_stream = b"".join(ln + b"\0" for ln in lines)
-        sys_stream = b"".join(b"%d %s" % (len(ln), ln) for ln in lines)
-        if trial % 3 == 0:
-            line_stream += rnd_bytes(rng.randrange(0, 30)) \
-                .replace(b"\n", b"~")
-            sys_stream += rng.choice(
-                [b"9999 short", b"xx junk", b"123456789012 x", b""])
-        # (a) span kernels vs the host scalar scans
-        for blob, sep, strip in ((line_stream, 10, True),
-                                 (nul_stream, 0, False)):
-            hs, hl, hn, carry = _pack._split_np(
-                blob, strip_cr=strip, sep=sep)
-            out = _pk.frame_sep_spans_pallas(
-                _region(blob), np.int32(len(blob)), sep=sep,
-                strip_cr=strip, ncap=NCAP, interpret=True)
-            if not (int(out["n"]) == hn
-                    and int(out["consumed"]) == len(blob) - len(carry)
-                    and np.array_equal(
-                        np.asarray(out["starts"])[:hn], hs)
-                    and np.array_equal(np.asarray(out["lens"])[:hn],
-                                       hl)):
-                fails += 1
-                print(f"SPAN MISMATCH sep={sep} trial={trial}")
-        hs, hl, hn, hcons, herr = _scan_syslen_region(sys_stream)
-        out = _pk.frame_syslen_spans_pallas(
-            _region(sys_stream), np.int32(len(sys_stream)), ncap=NCAP,
-            interpret=True)
-        if bool(out["decline"]):
-            pass  # >9-digit prefix: host owns the region, by design
-        elif not (int(out["n"]) == hn and int(out["consumed"]) == hcons
-                  and bool(out["err"]) == herr
-                  and np.array_equal(np.asarray(out["starts"])[:hn], hs)
-                  and np.array_equal(np.asarray(out["lens"])[:hn], hl)):
-            fails += 1
-            print(f"SPAN MISMATCH syslen trial={trial}")
-        # (b) compiled-NFA structural classifier vs the jnp screen
-        rows, ML = 4, 64
-        bat = np.zeros((rows, ML), np.uint8)
-        blens = np.zeros(rows, np.int32)
-        for i in range(rows):
-            kind = rng.randrange(0, 5)
-            if kind == 0:
-                r = b'{"s":"' + b"\\" * rng.randrange(0, 24) + b'q"}'
-            elif kind == 1:
-                r = rnd_bytes(rng.randrange(0, ML))
-            elif kind == 2:
-                r = (b'{"k":"%s","n":%d}'
-                     % (rnd_bytes(8).replace(b'"', b"?")
-                        .replace(b"\\", b"?"), rng.randrange(0, 999)))
-            elif kind == 3:
-                r = b'{"a":{"b":[1,2,{"c":null}]},"d":true}'
-            else:
-                r = b'{"k":"v"}'[:rng.randrange(0, 10)]  # truncation
-            r = r[:ML]
-            bat[i, :len(r)] = np.frombuffer(r, np.uint8)
-            blens[i] = len(r)
-        ref = _si_ref(bat, blens)
-        got = _pk.structural_index_pallas(
-            bat, blens, max_fields=8, nested=4, block_rows=rows,
-            interpret=True)
-        for k in ref:
-            if not np.array_equal(np.asarray(ref[k]),
-                                  np.asarray(got[k])):
-                fails += 1
-                print(f"STRUCTURAL MISMATCH key={k} trial={trial}")
-        # (c) e2e byte identity: pallas tier vs the all-host pipeline,
-        # chunk plans cutting mid-record and mid-syslen-prefix
-        pos, line_cuts, sys_cuts = 0, set(), set()
-        for ln in lines[: 1 + trial % 5]:
-            pos += len(ln) + 1
-            line_cuts |= {pos, pos - 1, pos + 1}
-        pos = 0
-        for ln in lines[: 1 + trial % 5]:
-            plen = len(b"%d" % len(ln))
-            sys_cuts |= {pos + 1, pos + plen, pos + plen + 1}
-            pos += plen + 1 + len(ln)
-        cases = [
-            ("line", line_stream, LineSplitter, line_cuts),
-            ("nul", nul_stream, NulSplitter, set()),
-            ("syslen", sys_stream, SyslenSplitter, sys_cuts),
-        ]
-        for framing, stream, splitter_cls, forced in cases:
-            sizes = _sizes_from_cuts(stream, forced)
-            lanes = 2 if trial % 2 else 1
-            want = _run(stream, splitter_cls, False, lanes, sizes)
-            got = _run(stream, splitter_cls, True, lanes, sizes)
-            if want != got:
-                fails += 1
-                print(f"E2E MISMATCH {framing} lanes={lanes} "
-                      f"trial={trial} want={len(want)} got={len(got)}")
-    engaged = _registry.get("pallas_rows") > 0
-    print("ENGAGED:", engaged, "FAILURES:", fails,
-          "pallas_declines:", _registry.get("pallas_declines"))
     sys.exit(1 if fails or not engaged else 0)
 
 from flowgger_tpu.decoders.dns import DNSDecoder
