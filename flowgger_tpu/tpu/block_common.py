@@ -16,11 +16,13 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import native
 from ..block import EncodedBlock
 from ..encoders import EncodeError
 from ..mergers import LineMerger, Merger, NulMerger, SyslenMerger
 from ..obs.trace import tracer as _tracer
 from ..utils.metrics import registry as _metrics
+from ..utils.rustfmt import json_f64
 from .assemble import (
     build_source,
     concat_segments,
@@ -31,17 +33,31 @@ from .materialize import _scalar_line, compute_ts
 
 
 def vals_scratch(vals: np.ndarray, fmt_fn):
-    """Deduplicated formatted values: repetitive streams share few
-    distinct stamps, and ``fmt_fn`` (json_f64, display_f64,
-    unix_to_rfc3339_ms...) is the only per-value Python.  Returns
-    (scratch bytes, per-row offsets, per-row lengths)."""
-    uniq, inv = np.unique(vals, return_inverse=True)
-    strs = [fmt_fn(float(u)).encode("ascii") for u in uniq]
-    scratch = b"".join(strs)
-    ulen = np.fromiter((len(s) for s in strs), dtype=np.int64,
-                       count=len(strs))
-    uoff = exclusive_cumsum(ulen)[:-1]
-    return scratch, uoff[inv], ulen[inv]
+    """Formatted values as (scratch bytes, per-row offsets, per-row
+    lengths).  json_f64 text comes from the threaded native formatter
+    (one dense TS_W-wide slot a row, off the GIL) where the library is
+    loaded; any other ``fmt_fn`` (display_f64, unix_to_rfc3339_ms...)
+    has no native twin and is called once per distinct value."""
+    n = int(vals.size)
+    with _tracer.sub(_tracer.bound(), "ts_text", "encode", rows=n):
+        if fmt_fn is json_f64:
+            from .device_common import TS_W  # lazy: it imports jax
+
+            res = native.format_f64_json_native(vals, TS_W)
+            if res is not None:
+                txt, lens = res
+                _metrics.inc("ts_text_native_rows", n)
+                return (txt.tobytes(),
+                        np.arange(0, n * TS_W, TS_W, dtype=np.int64),
+                        lens.astype(np.int64))
+        uniq, inv = np.unique(vals, return_inverse=True)
+        strs = [fmt_fn(float(u)).encode("ascii") for u in uniq]
+        _metrics.inc("ts_text_python_values", len(strs))
+        scratch = b"".join(strs)
+        ulen = np.fromiter((len(s) for s in strs), dtype=np.int64,
+                           count=len(strs))
+        uoff = exclusive_cumsum(ulen)[:-1]
+        return scratch, uoff[inv], ulen[inv]
 
 
 def ts_scratch(out, n: int, ridx: np.ndarray, fmt_fn):

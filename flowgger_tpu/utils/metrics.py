@@ -201,6 +201,12 @@ _COUNTERS = (
     "overlen_rows", "overlen_bytes_clipped",
     "splice_rows", "splice_rows_overlen", "splice_bytes_out",
     "overlen_rows_kept",
+    # the host block encoders' timestamp text (tpu/block_common.py
+    # vals_scratch), once a call: the rows whose stamp the native
+    # formatter wrote (json_f64's notation), and the distinct values
+    # that went through a Python format function (every other
+    # notation, or json_f64 without the library)
+    "ts_text_native_rows", "ts_text_python_values",
 )
 
 # cumulative per-stage wall-clock accumulators (add_seconds)

@@ -711,7 +711,7 @@ def test_sub_spans_carry_parent_thread_and_batch(annotations, host_route):
     for sp in rec["sub"]:
         assert sp["t1"] >= sp["t0"]
         by_stage.setdefault(sp["stage"], []).append(sp)
-    assert set(by_stage) == {"h2d", "device_wait", "d2h"}
+    assert set(by_stage) == {"h2d", "device_wait", "d2h", "ts_text"}
     ingest = {sp["thread"] for sp in rec["spans"] if sp["stage"] == "decode"}
     fetcher = {sp["thread"] for sp in rec["spans"] if sp["stage"] == "fetch"}
     assert ingest != fetcher
@@ -720,6 +720,10 @@ def test_sub_spans_carry_parent_thread_and_batch(annotations, host_route):
     for stage in ("device_wait", "d2h"):
         assert {sp["parent"] for sp in by_stage[stage]} == {"fetch"}
         assert {sp["thread"] for sp in by_stage[stage]} == fetcher
+    # the host block encoder's timestamp text, once a batch
+    (ts_text,) = by_stage["ts_text"]
+    assert (ts_text["parent"], ts_text["rows"]) == ("encode", len(_LINES))
+    assert ts_text["thread"] in fetcher
     # every stage and sub-span held an annotation open for this batch,
     # on the thread that did the work, and every one was closed
     opened = [e[1:] for e in annotations if e[0] == "open"]
@@ -756,7 +760,8 @@ def test_chrome_events_nest_sub_spans_under_their_parent(host_route):
     events = [e for e in obs_trace.tracer.chrome_events()
               if e.get("ph") == "X"]
     subs = [e for e in events if e["cat"] == "sub"]
-    assert {e["name"] for e in subs} == {"h2d", "device_wait", "d2h"}
+    assert {e["name"] for e in subs} == {"h2d", "device_wait", "d2h",
+                                         "ts_text"}
     for e in subs:
         parents = [p for p in events if p["cat"] == "batch"
                    and p["name"] == e["args"]["parent"]
@@ -835,7 +840,8 @@ def test_the_pair_rescue_is_a_second_round_over_the_link(host_route):
         key = (sp["stage"], sp["parent"])
         count[key] = count.get(key, 0) + 1
     assert count == {("h2d", "decode"): 1, ("h2d", "fetch"): 1,
-                     ("device_wait", "fetch"): 2, ("d2h", "fetch"): 2}
+                     ("device_wait", "fetch"): 2, ("d2h", "fetch"): 2,
+                     ("ts_text", "encode"): 1}
     d2h = [sp for sp in rec["sub"] if sp["stage"] == "d2h"]
     assert [sp["note"] for sp in d2h] == ["31", "31"]
     assert sum(sp["bytes"] for sp in d2h) == snap["d2h_bytes"]
